@@ -731,122 +731,24 @@ def serve_slo_sweep():
 
 # ------------------------------------------------- multi-device scaling
 
-def _scaling_child(counts, *, n=1024, B=32, timeout=540):
-    """Run the device-scaling measurement in a CHILD python with 4
-    forced host devices (works on any host — the 1-device container
-    included), timing ``multiply_many`` over B ciphertexts through
-    ``EvalPlan(mesh=...)`` at each device count in ``counts`` with
-    paired passes, plus a bit-exactness check of the widest mesh
-    against the single-device program.  Returns the child's parsed JSON
-    record, or ``None`` when the environment cannot deliver the
-    simulated devices (sandbox spawn limits, stalls) — callers emit a
-    1-device fallback row so the smoke gate's presence check survives.
-
-    The child inherits the FULL parent env (plus the forced-device
-    XLA flag): dropping ``JAX_PLATFORMS`` historically sent jax into
-    the TPU-metadata retry loop and hung the bench."""
-    import json as _json
-    import subprocess
-    import sys as _sys
-
-    script = f"""
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=4")
-import json, time
-import numpy as np
-import jax
-from repro import compat
-from repro.fhe.ckks import CkksContext
-from repro.fhe.evalplan import EvalPlan
-
-counts = {list(counts)!r}
-if jax.device_count() < max(counts):
-    print(json.dumps({{"devices": jax.device_count()}}))
-    raise SystemExit(0)
-ctx = CkksContext(n={n}, levels=2, seed=23)
-rng = np.random.default_rng(5)
-def enc():
-    z = rng.uniform(-1, 1, ctx.slots) + 1j * rng.uniform(-1, 1, ctx.slots)
-    return ctx.encrypt(ctx.encode(z))
-cts = [enc() for _ in range({B})]
-bts = [enc() for _ in range({B})]
-plans = {{}}
-for d in counts:
-    plans[d] = (ctx.plan() if d == 1 else EvalPlan(
-        ctx, mesh=compat.make_mesh((d,), ("b",),
-                                   devices=jax.devices()[:d])))
-def run(p):
-    out = p.multiply_many(cts, bts)
-    jax.block_until_ready([x.c0.data for x in out] +
-                          [x.c1.data for x in out])
-    return out
-outs = {{d: run(p) for d, p in plans.items()}}      # compile + warm
-exact = all(
-    np.array_equal(np.asarray(a.c0.data), np.asarray(b.c0.data))
-    and np.array_equal(np.asarray(a.c1.data), np.asarray(b.c1.data))
-    for a, b in zip(outs[min(counts)], outs[max(counts)]))
-best = None                                         # paired passes
-for _ in range(3):
-    ts = {{}}
-    for d, p in plans.items():
-        t0 = time.perf_counter()
-        for _ in range(3):
-            run(p)
-        ts[d] = (time.perf_counter() - t0) / 3 * 1e6
-    if best is None or sum(ts.values()) < sum(best.values()):
-        best = ts
-print(json.dumps({{"devices": jax.device_count(), "b": {B},
-                   "exact": bool(exact),
-                   "times_us": {{str(d): t for d, t in best.items()}}}}))
-"""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        r = subprocess.run([_sys.executable, "-c", script],
-                           capture_output=True, text=True, timeout=timeout,
-                           env=env, cwd=repo)
-    except subprocess.TimeoutExpired:
-        return None
-    for line in reversed(r.stdout.strip().splitlines()):
-        try:
-            rec = _json.loads(line)
-        except ValueError:
-            continue
-        return rec if "times_us" in rec else None
-    return None
-
-
-def ckks_multiply_sharded_d4():
-    """The PR 8 headline row (gated by benchmarks/check_smoke.py):
-    batch-32 ciphertext multiply through ``EvalPlan(mesh=4 x "b")`` on
-    4 forced host devices vs the single-device program, bit-exactness
-    required always.  The derived string carries ``devices=``,
-    ``speedup=x`` and ``exact=`` for the gate: on a multi-core runner
-    with real simulated devices the sharded dispatch must reach 2x; a
-    1-core container (4 simulated devices time-share one core, nothing
-    to win) or a sandbox that cannot spawn the child reports a
-    devices=1 fallback measured in-process through a mesh of ONE — the
-    same sharded code path, so exactness is still a real check."""
-    rec = _scaling_child((1, 4))
-    if rec is not None:
-        t1 = rec["times_us"]["1"]
-        t4 = rec["times_us"]["4"]
-        return [("ckks_multiply_sharded_d4", t4,
-                 f"devices=4 B={rec['b']} n=2^10: sharded {t4:.0f}us vs "
-                 f"single {t1:.0f}us speedup=x{t1 / t4:.2f} "
-                 f"exact={'OK' if rec['exact'] else 'FAIL'} "
-                 f"({os.cpu_count() or 1} cores)")]
-    # fallback: no simulated devices — mesh-of-1 in-process, same
-    # shard_map path, real bit-exactness, presence gate satisfied
+def _scaling(counts, *, n=1024, B=32):
+    """Time ``multiply_many`` over B ciphertexts through
+    ``EvalPlan(mesh=...)`` at each device count in ``counts`` — in THIS
+    process, over the devices JAX already has (a child process could not
+    take a chip this process holds) — with paired passes, plus a
+    bit-exactness check of the widest mesh against the single-device
+    program.  Returns ``None`` when fewer than ``max(counts)`` devices
+    exist: the caller reports the measurement as not run (a CPU host
+    gets four devices from
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``)."""
     from repro import compat
     from repro.fhe.ckks import CkksContext
     from repro.fhe.evalplan import EvalPlan
 
-    ctx = CkksContext(n=1024, levels=2, seed=23)
+    if jax.device_count() < max(counts):
+        return None
+    ctx = CkksContext(n=n, levels=2, seed=23)
     rng = np.random.default_rng(5)
-    B = 32
 
     def enc():
         z = rng.uniform(-1, 1, ctx.slots) + 1j * rng.uniform(-1, 1, ctx.slots)
@@ -854,43 +756,72 @@ def ckks_multiply_sharded_d4():
 
     cts = [enc() for _ in range(B)]
     bts = [enc() for _ in range(B)]
-    plain = ctx.plan()
-    sharded = EvalPlan(ctx, mesh=compat.make_mesh((1,), ("b",)))
+    plans = {d: EvalPlan(ctx) if d == 1 else EvalPlan(
+        ctx, mesh=compat.make_mesh((d,), ("b",), devices=jax.devices()[:d]))
+        for d in counts}
 
-    def block(out):
+    def run(p):
+        out = p.multiply_many(cts, bts)
         jax.block_until_ready([x.c0.data for x in out] +
                               [x.c1.data for x in out])
         return out
 
-    ref = block(plain.multiply_many(cts, bts))
-    got = block(sharded.multiply_many(cts, bts))
+    outs = {d: run(p) for d, p in plans.items()}        # compile + warm
     exact = all(
         np.array_equal(np.asarray(a.c0.data), np.asarray(b.c0.data))
         and np.array_equal(np.asarray(a.c1.data), np.asarray(b.c1.data))
-        for a, b in zip(ref, got))
-    ts, ta = _paired_time(
-        [lambda: block(plain.multiply_many(cts, bts)),
-         lambda: block(sharded.multiply_many(cts, bts))])
-    return [("ckks_multiply_sharded_d4", ta,
-             f"devices=1 (no simulated 4-device child) B={B} n=2^10: "
-             f"mesh-of-1 {ta:.0f}us vs single {ts:.0f}us speedup=x1.00 "
-             f"exact={'OK' if exact else 'FAIL'} "
-             f"({os.cpu_count() or 1} cores)")]
+        for a, b in zip(outs[min(counts)], outs[max(counts)]))
+    best = None                                         # paired passes
+    for _ in range(3):
+        ts = {}
+        for d, p in plans.items():
+            t0 = time.perf_counter()
+            for _ in range(3):
+                run(p)
+            ts[d] = (time.perf_counter() - t0) / 3 * 1e6
+        if best is None or sum(ts.values()) < sum(best.values()):
+            best = ts
+    return {"devices": jax.device_count(), "b": B, "exact": bool(exact),
+            "times_us": best}
+
+
+def _not_run(name, counts):
+    return (name, float("nan"),
+            f"NOT RUN: needs {max(counts)} devices, this process has "
+            f"{jax.device_count()} ({jax.devices()[0].platform})")
+
+
+def ckks_multiply_sharded_d4():
+    """The sharded headline row (gated by benchmarks/check_smoke.py):
+    batch-32 ciphertext multiply through ``EvalPlan(mesh=4 x "b")`` vs
+    the single-device program, bit-exactness required always.  The
+    derived string carries ``devices=``, ``speedup=x`` and ``exact=``
+    for the gate.  With fewer than 4 devices the row says NOT RUN — no
+    mesh-of-one stand-in is measured under this name."""
+    rec = _scaling((1, 4))
+    if rec is None:
+        return [_not_run("ckks_multiply_sharded_d4", (1, 4))]
+    t1 = rec["times_us"][1]
+    t4 = rec["times_us"][4]
+    return [("ckks_multiply_sharded_d4", t4,
+             f"devices=4 B={rec['b']} n=2^10: sharded {t4:.0f}us vs "
+             f"single {t1:.0f}us speedup=x{t1 / t4:.2f} "
+             f"exact={'OK' if rec['exact'] else 'FAIL'} "
+             f"({jax.devices()[0].platform}, {os.cpu_count() or 1} cores)")]
 
 
 def scaling_table():
     """The ntt-aie ``plot_efficiency`` report shape over device counts
-    1/2/4 (simulated host devices): per-count wall, throughput, speedup
-    and parallel efficiency for the batch-32 sharded multiply.  Written
-    to ``BENCH_scaling.json`` by the CI forced-4-device job."""
-    rec = _scaling_child((1, 2, 4))
+    1/2/4: per-count wall, throughput, speedup and parallel efficiency
+    for the batch-32 sharded multiply.  Written to
+    ``BENCH_scaling.json`` by the CI forced-4-device job."""
+    rec = _scaling((1, 2, 4))
     if rec is None:
-        return [("ckks_multiply_scale_d1", 0.0,
-                 "SKIP: simulated-device child unavailable")]
-    t1 = rec["times_us"]["1"]
+        return [_not_run("ckks_multiply_scale_d1", (1, 2, 4))]
+    t1 = rec["times_us"][1]
     rows = []
     for d in (1, 2, 4):
-        td = rec["times_us"][str(d)]
+        td = rec["times_us"][d]
         rows.append((
             f"ckks_multiply_scale_d{d}", td,
             f"devices={d} B={rec['b']} n=2^10: "
@@ -898,7 +829,7 @@ def scaling_table():
             f"speedup=x{t1 / td:.2f} "
             f"efficiency={t1 / (td * d) * 100:.0f}% "
             f"exact={'OK' if rec['exact'] else 'FAIL'} "
-            f"({os.cpu_count() or 1} cores)"))
+            f"({jax.devices()[0].platform}, {os.cpu_count() or 1} cores)"))
     return rows
 
 

@@ -13,8 +13,10 @@ ckks_multiply_b{1,8,32} / ckks_rotate_b32 rows, the hoisted-rotation
 rows incl. the projected-vs-measured keyswitch_throughput datapoint,
 the serving SLO rows: async/sync drain walls over a seeded mixed
 trace plus p50/p99 request latency under Poisson arrivals, and the
-lazy-vs-eager reduction A/B rows at the paper's 2^14 ring) and exits
-nonzero on any ERROR row.  ``--json PATH`` additionally writes the
+lazy-vs-eager reduction A/B rows at the paper's 2^14 ring).  Every mode
+exits nonzero on any ERROR row.  The persistent compilation cache goes
+to ``$JAX_COMPILATION_CACHE_DIR`` or ``<repo>/.jax_cache``
+(``repro.compile_cache``).  ``--json PATH`` additionally writes the
 rows as a JSON record plus a ``*_autotune.json`` sibling snapshotting
 the batch-tile tuning state — CI uploads the smoke run's files as
 ``BENCH_*.json`` artifacts so a bench trajectory accumulates across
@@ -56,6 +58,9 @@ def main() -> None:
                          "every instrumented span (plus a *_metrics.json "
                          "sibling snapshotting the metrics registry)")
     args = ap.parse_args()
+
+    from repro import compile_cache
+    compile_cache.enable()
 
     if args.trace_out:
         # capture the whole run: the serve_obs_overhead row toggles the
@@ -110,7 +115,7 @@ def main() -> None:
         tile_path = os.path.splitext(args.json)[0] + "_autotune.json"
         autotune.dump(tile_path)
         print(f"# wrote autotune table to {tile_path}", file=sys.stderr)
-    if (args.smoke or args.scaling) and failed:
+    if failed:
         sys.exit(1)
 
     # roofline summaries from the dry-run sweep (if present)

@@ -117,4 +117,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

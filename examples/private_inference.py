@@ -138,4 +138,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

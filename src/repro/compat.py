@@ -1,55 +1,40 @@
-"""Version-portable wrappers for JAX APIs that moved between releases.
+"""Thin wrappers over the JAX mesh APIs the sharded paths use.
 
-The repo targets current ``jax[cpu]`` in CI but must also run on older
-containers (e.g. 0.4.x) where ``jax.shard_map`` still lives in
-``jax.experimental.shard_map`` and ``jax.set_mesh`` does not exist yet.
-Everything multi-device in this codebase goes through these two shims so
-the sharded paths (four-step reorder network, MoE, pipeline parallel)
-work on both.
+Everything multi-device in this codebase (the four-step reorder network,
+MoE, pipeline parallel, the sharded EvalPlan) goes through these
+helpers, written for the installed JAX (0.9).
 """
 from __future__ import annotations
 
+import math
+
 import jax
-
-try:  # jax >= 0.6 top-level API
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - exercised on old containers
-    from jax.experimental.shard_map import shard_map as _shard_map
+import numpy as np
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def pcast_varying(x, axis: str):
-    """``jax.lax.pcast(x, (axis,), to="varying")`` on new jax.  Old jax
-    has no varying-type system — every shard_map value is already
-    device-varying, so the cast is the identity there."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    return x
+    """Mark a replicated value as device-varying over ``axis``."""
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def use_mesh(mesh):
-    """``with use_mesh(mesh):`` — ``jax.set_mesh`` where available,
-    otherwise the (older) Mesh context-manager protocol."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``with use_mesh(mesh):`` — the ambient mesh context."""
+    return jax.set_mesh(mesh)
 
 
 def make_mesh(shape, axis_names, devices=None):
-    """``jax.make_mesh`` where available; otherwise (or when an explicit
-    ``devices`` subset is requested — e.g. a scaling sweep meshing over
-    the first d of the host's devices) build ``jax.sharding.Mesh`` from
-    the device list directly."""
-    import math
-
-    import numpy as np
-
-    if devices is None and hasattr(jax, "make_mesh"):
+    """``jax.make_mesh`` over all devices, or — when an explicit
+    ``devices`` subset is requested (e.g. a scaling sweep meshing over
+    the first d of the host's devices) — ``jax.sharding.Mesh`` over
+    that subset."""
+    if devices is None:
         return jax.make_mesh(tuple(shape), tuple(axis_names))
-    devs = list(devices) if devices is not None else jax.devices()
+    devs = list(devices)
     need = math.prod(shape)
     if len(devs) < need:
         raise ValueError(

@@ -21,9 +21,12 @@ implicitly, so jit-signature counts stay bounded and the PR 6
      has a registered runner, and we are outside any jit trace,
   5. the static default ``min(8, b)``.
 
-Every path clamps to ``max(1, min(tile, b))``: a 1-row input must never
-be zero-padded to an 8-row dispatch (the single-prime entry points
-historically skipped this clamp — 8x wasted butterfly work).
+Every path clamps to a tile the TPU accepts (``clamp``): the tile is the
+second-minor block dim of every kernel, so it is a multiple of 8 or the
+whole batch.  A batch of at most 8 rows runs as one whole-batch tile (a
+1-row input is never zero-padded to an 8-row dispatch); a wider batch
+takes a multiple of 8 no wider than the request, and the entry points
+pad the batch to a tile multiple.
 
 Sharded dispatch resolves against the PER-SHARD batch: a caller whose
 (B, k, n) stack is split over ``shards`` mesh devices passes
@@ -50,12 +53,18 @@ import time
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 
-CANDIDATE_TILES = (1, 2, 4, 8, 16, 32)
+# Legal batch tiles (multiples of the 8-row sublane tile).  32 bounds
+# the widest elementwise block: dyadic_inner_banks at d=8 digits holds
+# ~8.5 MiB of double-buffered VMEM at tile 32 (ntt_kernel.LANE_BLOCK).
+CANDIDATE_TILES = (8, 16, 32)
 DEFAULT_TILE = 8
+SUBLANES = 8
 
 ENV_PIN = "SCE_NTT_TILE"
 ENV_CACHE = "SCE_NTT_AUTOTUNE_CACHE"
@@ -64,7 +73,7 @@ ENV_AUTOTUNE = "SCE_NTT_AUTOTUNE"
 # (backend, family, k, n, b, dtype) -> best tile
 _MEM: dict[tuple, int] = {}
 # (same key) -> measurement evidence: {"chosen": tile, "source": how the
-# entry came to be ("measured" / "default" / "runner-error" / "disk"),
+# entry came to be ("measured" / "default-nonu32" / "disk"),
 # "candidates": {tile: median seconds}} — the tuner used to throw its
 # measurements away the moment the argmin was taken, so a surprising
 # cached tile could never be audited; now the full candidate table
@@ -75,11 +84,14 @@ _KEY_PARTS = 6      # the persisted "be|fam|k|n|b|dtype" format
 
 
 def clamp(tile: int, b: int) -> int:
-    """The universal tile rule: at least 1, never wider than the batch."""
+    """The universal tile rule, legal on TPU: the whole batch when the
+    request covers it (or the batch is at most one sublane tile), else
+    the largest multiple of 8 not above the request (at least 8)."""
     b = int(b)
     if b <= 0:
         return 1
-    return max(1, min(int(tile), b))
+    t = max(SUBLANES, int(tile) // SUBLANES * SUBLANES)
+    return b if t >= b else t
 
 
 def _backend() -> str:
@@ -93,11 +105,10 @@ def _key(family: str, k: int, n: int, b: int,
 
 def _trace_clean() -> bool:
     """True only when called outside any jit trace — measuring inside a
-    trace would time tracing, not compute, and could poison the cache."""
-    try:
-        return bool(jax.core.trace_state_clean())
-    except Exception:
-        return False
+    trace would time tracing, not compute, and could poison the cache.
+    Under a trace every jax op is staged out, so a fresh iota comes back
+    as a ``jax.core.Tracer``."""
+    return not isinstance(lax.iota(jnp.int32, 1), jax.core.Tracer)
 
 
 def _env_pin() -> int | None:
@@ -266,12 +277,12 @@ def ensure(family: str, k: int, n: int, b: int, *, shards: int = 1,
 
 def measure(family: str, k: int, n: int, b: int, *, reps: int = 3,
             dtype: str = "uint32") -> int:
-    """Time every candidate tile <= b for the family's representative
-    workload and cache the argmin.  Falls back to the static default on
-    any failure (a family that cannot run at some tile must not take
-    dispatch down with it).  The registered runners are u32 workloads;
-    a non-u32 dtype caches the static default until a same-width runner
-    exists (never a tile timed on the wrong lane width)."""
+    """Time every legal candidate tile for the family's representative
+    workload and cache the argmin.  Only legal tiles (``clamp``) are
+    tried, so a tile that fails to compile or run is a bug and raises.
+    The registered runners are u32 workloads; a non-u32 dtype caches
+    the static default until a same-width runner exists (never a tile
+    timed on the wrong lane width)."""
     key = _key(family, k, n, b, dtype)
     if dtype != "uint32":
         _MEM[key] = clamp(DEFAULT_TILE, b)
@@ -279,29 +290,20 @@ def measure(family: str, k: int, n: int, b: int, *, reps: int = 3,
                           "candidates": {}}
         _save_disk()
         return _MEM[key]
-    try:
-        run = _RUNNERS[family](int(k), int(n), int(b))
-    except Exception:
-        _MEM[key] = clamp(DEFAULT_TILE, b)
-        _EVIDENCE[key] = {"chosen": _MEM[key], "source": "runner-error",
-                          "candidates": {}}
-        return _MEM[key]
+    run = _RUNNERS[family](int(k), int(n), int(b))
     cands = sorted({clamp(t, b) for t in CANDIDATE_TILES})
     best_tile, best_t = clamp(DEFAULT_TILE, b), float("inf")
     candidates: dict[int, float] = {}
     with obs.span("autotune.measure", family=family, k=int(k), n=int(n),
                   b=int(b), dtype=dtype):
         for t in cands:
-            try:
-                jax.block_until_ready(run(t))       # compile + warm
-                times = []
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(run(t))
-                    times.append(time.perf_counter() - t0)
-                dt = min(times)
-            except Exception:
-                continue
+            jax.block_until_ready(run(t))           # compile + warm
+            times = []
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(t))
+                times.append(time.perf_counter() - t0)
+            dt = min(times)
             # selection stays argmin-of-min (noise-floor tiles win);
             # the median is the honest per-candidate summary recorded
             # as evidence (min overstates a lucky pass)
@@ -309,8 +311,7 @@ def measure(family: str, k: int, n: int, b: int, *, reps: int = 3,
             if dt < best_t:
                 best_tile, best_t = t, dt
     _MEM[key] = best_tile
-    _EVIDENCE[key] = {"chosen": best_tile,
-                      "source": "measured" if candidates else "runner-error",
+    _EVIDENCE[key] = {"chosen": best_tile, "source": "measured",
                       "candidates": candidates}
     if obs.enabled():
         obs.counter_add("autotune.measurements")
@@ -385,14 +386,6 @@ def _run_twiddle_mul_banks(k, n, b):
                                               tile=tile)
 
 
-def _run_galois_banks(k, n, b):
-    from repro.kernels import ops
-    t = _pack(k, n)
-    x = np.stack([_rng_rows((b, n), q) for q in np.asarray(t["qs"])])
-    idx = np.arange(n, dtype=np.int32)[::-1].copy()
-    return lambda tile: ops.galois_banks(x, idx, use_pallas=True, tile=tile)
-
-
 def _run_dyadic_inner_banks(k, n, b):
     from repro.kernels import ops
     t = _pack(k, n)
@@ -414,8 +407,6 @@ _RUNNERS = {
     "ntt_banks": _run_ntt_banks,
     "intt_banks": functools.partial(_run_ntt_banks, inverse=True),
     "twiddle_mul_banks": _run_twiddle_mul_banks,
-    "galois_banks": _run_galois_banks,
-    "galois_digits_banks": _run_galois_banks,   # same gather datapath
     "dyadic_inner_banks": _run_dyadic_inner_banks,
     "serve_batch": _run_ntt_banks,              # batch-shaped proxy
 }

@@ -11,17 +11,19 @@ Kernels:
     leaves VMEM)
   * ``dyadic_inner_banks`` — out[j] = sum_i ext[i, j] .* evk[i, j] mod
     q_j: the WHOLE key-switch digit inner product for one prime bank in
-    a single program.  Grid (prime, batch_tile); the digit loop is
-    unrolled inside the kernel so the accumulator stays in VMEM across
-    all digits (the paper's pipelined MM -> MA chain).
+    a single program.  Grid (prime, batch_tile, lane_chunk); the digit
+    loop is unrolled inside the kernel so the accumulator stays in VMEM
+    across all digits (the paper's pipelined MM -> MA chain).
   * ``dyadic_basemul_banks`` — the degree-1 basecase multiplication of
     an INCOMPLETE ring (``core.ringspec.RingSpec`` with block=2, e.g.
     ML-KEM): pair j of the NTT domain is (x[j], x[j+n/2]) and products
     are (a0+a1·X)(b0+b1·X) mod (X² − γ_j) with per-pair ζ factors γ.
 
-Barrett reduction follows the element dtype (see core.modmath): u32
-lanes use the limb mulhi; u16 lanes upcast to an exact u32 product with
-the (2^10, 2^12) window constants.
+Lanes are always u32; ``bits`` selects the Barrett form (see
+core.modmath): 32 uses the limb mulhi, 16 (q in (2^10, 2^12)) an exact
+u32 product with the (2^10, 2^12) window constants.  Per-prime scalars
+(q, mu) ride whole in SMEM; per-prime rows use a (k, 1, n) layout so a
+(1, 1, n) block satisfies the TPU block rule.
 """
 from __future__ import annotations
 
@@ -31,81 +33,49 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.modmath import MASK16
+from repro.core.modmath import _barrett16_lazy_u32, mulhi_u32
 from repro.kernels import resolve_interpret
-from repro.kernels.ntt_kernel import _shoup, _shoup_lazy
+from repro.kernels.ntt_kernel import U32, _shoup, _shoup_lazy, _smem, lane_block
 
 
-def _mulhi(a, b):
-    a0 = a & MASK16
-    a1 = a >> 16
-    b0 = b & MASK16
-    b1 = b >> 16
-    t = a0 * b0
-    m1 = a1 * b0 + (t >> 16)
-    m2 = a0 * b1 + (m1 & MASK16)
-    return a1 * b1 + (m1 >> 16) + (m2 >> 16)
+def _barrett_lazy(a, b, q, mu, bits):
+    """[0, 2q) Barrett product: one conditional subtract of 2q; the MAC
+    digit loop accumulates these and reduces once in its epilogue."""
+    if bits == 16:
+        r = _barrett16_lazy_u32(a, b, q, mu)
+    else:
+        lo = a * b
+        approx = (mulhi_u32(a, b) << 3) | (lo >> 29)
+        qhat = (mulhi_u32(approx, mu) << 1) | ((approx * mu) >> 31)
+        r = lo - qhat * q
+    q2 = q + q
+    return jnp.where(r >= q2, r - q2, r)
 
 
-def _barrett16_lazy(a, b, q, mu):
-    # 16-bit lane: P = a*b < 2^24 exact in u32; mu = floor(2^26/q),
-    # qhat = ((P >> 10) * mu) >> 16; r < 2q (exhaustive over the window).
-    u = jnp.uint32
-    prod = a.astype(u) * b.astype(u)
-    qhat = ((prod >> 10) * mu.astype(u)) >> 16
-    return prod - qhat * q.astype(u)
-
-
-def _barrett(a, b, q, mu):
-    if a.dtype == jnp.uint16:
-        r = _barrett16_lazy(a, b, q, mu)
-        q32 = q.astype(jnp.uint32)
-        r = jnp.where(r >= (q32 << 1), r - (q32 << 1), r)
-        return jnp.where(r >= q32, r - q32, r).astype(jnp.uint16)
-    hi = _mulhi(a, b)
-    lo = a * b
-    approx = (hi << 3) | (lo >> 29)
-    qhat = (_mulhi(approx, mu) << 1) | ((approx * mu) >> 31)
-    r = lo - qhat * q
-    r = jnp.where(r >= (q << 1), r - (q << 1), r)
+def _barrett(a, b, q, mu, bits):
+    r = _barrett_lazy(a, b, q, mu, bits)
     return jnp.where(r >= q, r - q, r)
 
 
-def _barrett_lazy(a, b, q, mu):
-    # [0, 2q) band: one conditional subtract instead of two; the MAC
-    # digit loop accumulates these and reduces once in its epilogue.
-    if a.dtype == jnp.uint16:
-        r = _barrett16_lazy(a, b, q, mu)
-        q32 = q.astype(jnp.uint32)
-        return jnp.where(r >= (q32 << 1), r - (q32 << 1), r) \
-            .astype(jnp.uint16)
-    hi = _mulhi(a, b)
-    lo = a * b
-    approx = (hi << 3) | (lo >> 29)
-    qhat = (_mulhi(approx, mu) << 1) | ((approx * mu) >> 31)
-    r = lo - qhat * q
-    return jnp.where(r >= (q << 1), r - (q << 1), r)
-
-
 def _mul_kernel(a_ref, b_ref, o_ref, *, q: int, mu: int, lazy: bool):
-    qc = jnp.uint32(q)
-    muc = jnp.uint32(mu)
+    qc = U32(q)
+    muc = U32(mu)
     if lazy:
-        r = _barrett_lazy(a_ref[...], b_ref[...], qc, muc)
+        r = _barrett_lazy(a_ref[...], b_ref[...], qc, muc, 32)
         o_ref[...] = jnp.where(r >= qc, r - qc, r)
     else:
-        o_ref[...] = _barrett(a_ref[...], b_ref[...], qc, muc)
+        o_ref[...] = _barrett(a_ref[...], b_ref[...], qc, muc, 32)
 
 
 def _mac_kernel(acc_ref, a_ref, b_ref, o_ref, *, q: int, mu: int, lazy: bool):
-    qc = jnp.uint32(q)
+    qc = U32(q)
     if lazy:
         # acc in [0, q), product in [0, 2q): sum < 3q, two-step reduce
-        p = _barrett_lazy(a_ref[...], b_ref[...], qc, jnp.uint32(mu))
+        p = _barrett_lazy(a_ref[...], b_ref[...], qc, U32(mu), 32)
         s = acc_ref[...] + p
         s = jnp.where(s >= (qc << 1), s - (qc << 1), s)
     else:
-        p = _barrett(a_ref[...], b_ref[...], qc, jnp.uint32(mu))
+        p = _barrett(a_ref[...], b_ref[...], qc, U32(mu), 32)
         s = acc_ref[...] + p
     o_ref[...] = jnp.where(s >= qc, s - qc, s)
 
@@ -143,70 +113,71 @@ def dyadic_mac(acc, a, b, *, q: int, mu: int, tile: int = 8, lazy: bool = False,
 
 def _inner_banks_kernel(ext_ref, evk_ref, q_ref, mu_ref, o_ref, *, digits: int,
                         lazy: bool):
-    """Program (p, i): acc = sum_d ext[d] .* evk[d] mod q_p over all
+    """Program (p, i, c): acc = sum_d ext[d] .* evk[d] mod q_p over all
     ``digits`` digit rows, accumulator VMEM-resident throughout.  The
-    evk block is either (d, 1, n) — one key row broadcast over the batch
-    tile — or (d, 1, tile, n) — per-batch-element key digits; both
-    broadcast against the (tile, n) ext rows.
+    evk block is either (d, 1, 1, nl) — one key row broadcast over the
+    batch tile — or (d, 1, tile, nl) — per-batch-element key digits;
+    both broadcast against the (tile, nl) ext rows.
 
     Lazy mode keeps products AND the accumulator in [0, 2q) — one
     conditional select per digit instead of two (plus the saved Barrett
     subtract) — and reduces exactly once in the epilogue."""
-    q = q_ref[0, 0]
-    mu = mu_ref[0, 0]
+    p = pl.program_id(0)
+    q = q_ref[p]
+    mu = mu_ref[p]
     if lazy:
         q2 = q << 1
-        acc = _barrett_lazy(ext_ref[0, 0], evk_ref[0, 0], q, mu)
+        acc = _barrett_lazy(ext_ref[0, 0], evk_ref[0, 0], q, mu, 32)
         for d in range(1, digits):
-            prod = _barrett_lazy(ext_ref[d, 0], evk_ref[d, 0], q, mu)
+            prod = _barrett_lazy(ext_ref[d, 0], evk_ref[d, 0], q, mu, 32)
             s = acc + prod                              # < 4q < 2^32
             acc = jnp.where(s >= q2, s - q2, s)
         acc = jnp.where(acc >= q, acc - q, acc)         # epilogue
     else:
-        acc = _barrett(ext_ref[0, 0], evk_ref[0, 0], q, mu)
+        acc = _barrett(ext_ref[0, 0], evk_ref[0, 0], q, mu, 32)
         for d in range(1, digits):
-            prod = _barrett(ext_ref[d, 0], evk_ref[d, 0], q, mu)
+            prod = _barrett(ext_ref[d, 0], evk_ref[d, 0], q, mu, 32)
             s = acc + prod
             acc = jnp.where(s >= q, s - q, s)
     o_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("digits", "tile", "lazy", "interpret"))
-def dyadic_inner_banks(ext, evk, qs2, mus2, *, digits: int, tile: int = 8,
+def dyadic_inner_banks(ext, evk, qs, mus, *, digits: int, tile: int = 8,
                        lazy: bool = False, interpret: bool | None = None):
     """ext: (d, k, batch, n) NTT-domain digit extensions; evk: (d, k, n)
     key digits shared by the whole batch, or (d, k, batch, n) per-batch
-    key digits (a ciphertext batch mixing Galois keys); qs2/mus2: (k, 1)
+    key digits (a ciphertext batch mixing Galois keys); qs/mus: (k,)
     per-prime modulus/Barrett constants.  Returns (k, batch, n): the
     key-switch accumulator over all digits."""
     interpret = resolve_interpret(interpret)
     d, k, b, n = ext.shape
     assert d == digits and b % tile == 0
+    nl = lane_block(n)
     if evk.ndim == 4:
         assert evk.shape == (d, k, b, n)
-        evk_spec = pl.BlockSpec((d, 1, tile, n), lambda p, i: (0, p, i, 0))
+        evk_spec = pl.BlockSpec((d, 1, tile, nl), lambda p, i, c: (0, p, i, c))
     else:
-        evk_spec = pl.BlockSpec((d, 1, n), lambda p, i: (0, p, 0))
+        evk = evk[:, :, None, :]                        # (d, k, 1, n)
+        evk_spec = pl.BlockSpec((d, 1, 1, nl), lambda p, i, c: (0, p, 0, c))
     kern = functools.partial(_inner_banks_kernel, digits=digits, lazy=lazy)
     return pl.pallas_call(
         kern,
-        grid=(k, b // tile),
+        grid=(k, b // tile, n // nl),
         in_specs=[
-            pl.BlockSpec((d, 1, tile, n), lambda p, i: (0, p, i, 0)),
-            evk_spec,
-            pl.BlockSpec((1, 1), lambda p, i: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, i: (p, 0)),
+            pl.BlockSpec((d, 1, tile, nl), lambda p, i, c: (0, p, i, c)),
+            evk_spec, _smem(), _smem(),
         ],
-        out_specs=pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
+        out_specs=pl.BlockSpec((1, tile, nl), lambda p, i, c: (p, i, c)),
         out_shape=jax.ShapeDtypeStruct((k, b, n), ext.dtype),
         interpret=interpret,
-    )(ext, evk, qs2, mus2)
+    )(ext, evk, qs, mus)
 
 
 # ----------------------------------------- incomplete-ring basecase mul
 
 def _basemul_banks_kernel(a_ref, b_ref, q_ref, mu_ref, g_ref, gp_ref,
-                          o_ref, *, lazy: bool):
+                          o_ref, *, lazy: bool, bits: int):
     """Program (p, i): degree-1 residue products for one batch tile.
 
     Pair j of the CG-ordered NTT domain is (x[j], x[j+n/2]); the product
@@ -216,59 +187,56 @@ def _basemul_banks_kernel(a_ref, b_ref, q_ref, mu_ref, g_ref, gp_ref,
 
     Variable×variable products use Barrett (no precomputed operand);
     the γ_j multiply is Shoup (g/gp are the precomputed per-pair rows).
-    Lazy mode accumulates in the [0, 2q) band — on u16 lanes the raw
-    sum stays < 4q < 2^16 — and the epilogue always reduces to [0, q)
-    (the basecase ends the transform, so there is no lazy consumer)."""
-    q = q_ref[0, 0]
-    mu = mu_ref[0, 0]
+    Lazy mode accumulates in the [0, 2q) band and the epilogue always
+    reduces to [0, q) (the basecase ends the transform, so there is no
+    lazy consumer)."""
+    p = pl.program_id(0)
+    q = q_ref[p]
+    mu = mu_ref[p]
     a = a_ref[0]                        # (tile, n)
     b = b_ref[0]
-    n = a.shape[-1]
-    h = n // 2
+    h = a.shape[-1] // 2
     a0, a1 = a[:, :h], a[:, h:]
     b0, b1 = b[:, :h], b[:, h:]
     g = g_ref[0]                        # (1, h) γ row
     gp = gp_ref[0]
     if lazy:
         q2 = q + q
-        t = _shoup_lazy(_barrett_lazy(a1, b1, q, mu), g, gp, q)
-        s0 = _barrett_lazy(a0, b0, q, mu) + t          # < 4q
+        t = _shoup_lazy(_barrett_lazy(a1, b1, q, mu, bits), g, gp, q, bits)
+        s0 = _barrett_lazy(a0, b0, q, mu, bits) + t      # < 4q
         c0 = jnp.where(s0 >= q2, s0 - q2, s0)
-        s1 = _barrett_lazy(a0, b1, q, mu) + _barrett_lazy(a1, b0, q, mu)
+        s1 = _barrett_lazy(a0, b1, q, mu, bits) \
+            + _barrett_lazy(a1, b0, q, mu, bits)
         c1 = jnp.where(s1 >= q2, s1 - q2, s1)
-        c0 = jnp.where(c0 >= q, c0 - q, c0)            # epilogue
+        c0 = jnp.where(c0 >= q, c0 - q, c0)              # epilogue
         c1 = jnp.where(c1 >= q, c1 - q, c1)
     else:
-        t = _shoup(_barrett(a1, b1, q, mu), g, gp, q)
-        s0 = _barrett(a0, b0, q, mu) + t
+        t = _shoup(_barrett(a1, b1, q, mu, bits), g, gp, q, bits)
+        s0 = _barrett(a0, b0, q, mu, bits) + t
         c0 = jnp.where(s0 >= q, s0 - q, s0)
-        s1 = _barrett(a0, b1, q, mu) + _barrett(a1, b0, q, mu)
+        s1 = _barrett(a0, b1, q, mu, bits) + _barrett(a1, b0, q, mu, bits)
         c1 = jnp.where(s1 >= q, s1 - q, s1)
     o_ref[0] = jnp.concatenate([c0, c1], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "lazy", "interpret"))
-def dyadic_basemul_banks(a, b, qs2, mus2, gamma, gammap, *, tile: int = 8,
-                         lazy: bool = False, interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("tile", "lazy", "bits", "interpret"))
+def dyadic_basemul_banks(a, b, qs, mus, gamma, gammap, *, tile: int = 8,
+                         lazy: bool = False, bits: int = 32,
+                         interpret: bool | None = None):
     """a, b: (k, batch, n) NTT-domain operands of an incomplete ring
-    (canonical [0, q) inputs); qs2/mus2: (k, 1); gamma/gammap: (k, n/2)
-    per-pair ζ factors + Shoup companions.  Returns (k, batch, n)."""
+    (canonical [0, q) inputs); qs/mus: (k,); gamma/gammap: (k, n/2)
+    per-pair ζ factors + Shoup companions.  Returns (k, batch, n) u32."""
     interpret = resolve_interpret(interpret)
     k, bb, n = a.shape
     assert a.shape == b.shape and bb % tile == 0
-    kern = functools.partial(_basemul_banks_kernel, lazy=lazy)
+    u = lambda x: x.astype(U32)
+    blk = pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0))
+    row = pl.BlockSpec((1, 1, n // 2), lambda p, i: (p, 0, 0))
     return pl.pallas_call(
-        kern,
+        functools.partial(_basemul_banks_kernel, lazy=lazy, bits=bits),
         grid=(k, bb // tile),
-        in_specs=[
-            pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
-            pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
-            pl.BlockSpec((1, 1), lambda p, i: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, i: (p, 0)),
-            pl.BlockSpec((1, n // 2), lambda p, i: (p, 0)),
-            pl.BlockSpec((1, n // 2), lambda p, i: (p, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, bb, n), a.dtype),
+        in_specs=[blk, blk, _smem(), _smem(), row, row],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct((k, bb, n), U32),
         interpret=interpret,
-    )(a, b, qs2, mus2, gamma, gammap)
+    )(u(a), u(b), u(qs), u(mus), u(gamma)[:, None, :], u(gammap)[:, None, :])

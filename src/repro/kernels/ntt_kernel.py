@@ -1,20 +1,33 @@
-"""Fused constant-geometry NTT / iNTT as Pallas TPU kernels.
+"""Fused NTT / iNTT as Pallas TPU kernels.
 
-The whole ``log2(n)``-stage transform runs inside ONE kernel invocation
+The whole ``stages``-stage transform runs inside ONE kernel invocation
 per (batch-tile, n) VMEM block — the TPU analogue of the paper's 7-PE
 pipeline where a polynomial streams through all stages without touching
 main memory.  The ping-pong SRM banks of the paper become the automatic
 double-buffering of the Pallas grid pipeline (HBM->VMEM block prefetch
 overlaps compute on the previous tile).
 
-Twiddles (and their Shoup TW' companions, paper §IV.A) are resident in
-VMEM for all programs; stage t reads row t — the materialized circulating
-CSRM.  Arithmetic follows the element dtype (see core.modmath): u32
-lanes use the 16-bit-limb mulhi; u16 lanes (small rings, e.g. ML-KEM's
-q=3329) upcast to an exact u32 product.  The stage loop is depth-generic:
-``stages = log2(n) − log2(block)`` rows stop the INCOMPLETE transform of
-a block>1 ``core.ringspec.RingSpec`` at its degree-(block−1) basecase,
-so the same kernels serve complete (CKKS) and incomplete (Kyber) rings.
+In-place lane layout.  The constant-geometry (Pease) network of
+``core.ntt`` re-interleaves the lanes after every stage
+(out[2j], out[2j+1] <- x[j], x[j+n/2]); Mosaic has no lane interleave
+below 128 lanes, so the kernels run the SAME butterflies on a permuted
+layout instead: at stage t the CG element at position i lives at lane
+``rotr(i, t)`` (rotation within log2(n) bits).  The butterfly pair then
+sits at lanes (p, p ^ m) with m = n >> (t+1), every stage is two lane
+rotations (``pltpu.roll``) plus selects, and no data moves between
+stages.  Each lane carries the twiddle of its pair, so the (s, n/2) CG
+tables expand to (s, n) per-lane rows (``lane_twiddles``).  Complete
+transforms end with the CG output order unchanged (a rotation by log2 n
+bits is the identity); an incomplete ring (``stages < log2 n``) ends
+rotated and ``ops`` applies the fixed lane permutation
+(``incomplete_perm``).  Values — including the lazy [0, 2q)
+representatives — are bit-identical to ``core.ntt`` element for element.
+
+Lanes are always u32.  ``bits`` (static) selects the arithmetic:
+32 for the CKKS RNS primes (16-bit-limb mulhi Shoup), 16 for small
+rings such as ML-KEM's q=3329 (a 16x16 product is exact in u32, so the
+Shoup high part is a plain shift).  Callers narrow to u16 only at their
+byte boundary.
 
 Two kernel families live here:
 
@@ -25,30 +38,22 @@ Two kernel families live here:
   ``ntt_inv_banks_pallas``, plus the four-step step-3 companion
   ``twiddle_mul_banks_pallas``): the paper's Fig 22 bank array, where 8
   NTT units process the RNS prime rows in parallel.  The grid is
-  ``(prime, batch_tile)`` and the kernels consume the stacked TablePack
-  layout produced by ``fhe.batched.build_table_pack``:
+  ``(prime, batch_tile)`` over the TablePack layout of
+  ``fhe.batched.build_table_pack``:
 
-    qs            (k,)        u32 prime moduli (passed as (k, 1) so each
-                              program reads its scalar from row p)
-    tw/twp        (k, s, n/2) forward CG twiddles + Shoup companions;
-                              program (p, i) sees only row p, stage t
-                              reads tw[p, t, :]
-    itw/itwp      (k, s, n/2) inverse twiddles
-    ninv/ninv_p   (k,)        n^-1 per prime (cyclic inverse epilogue)
-    psi/psip      (k, n)      negacyclic psi^i pre-weights
-    ipsin/ipsinp  (k, n)      psi^-i * n^-1 fused post-weights
+    qs, ninv, ninv_p (k,)       per-prime scalars, whole arrays in SMEM;
+                                program (p, i) reads entry p
+    tw/twp, itw/itwp (k, s, n/2) CG twiddles + Shoup companions, expanded
+                                to (k, s, n) lane rows; block (1, s, n)
+    psi/psip, ipsin/ipsinp (k, n) per-coefficient weight rows, laid out
+                                (k, 1, n) so the (1, 1, n) block's last
+                                two dims equal the array's
 
-  Because every per-prime table row is selected by the leading grid
-  coordinate, one ``pallas_call`` runs all k bank rows — no Python
-  per-prime loop, and on TPU the prime axis pipelines through the same
-  double-buffered VMEM machinery as the batch axis.
+  Batch tiles follow the TPU block rule: a multiple of 8 or the whole
+  (padded) batch (``kernels.autotune.clamp``).
 
-VMEM budget per program (defaults, n=8192, tile=8):
-  coeffs 8*8192*4 = 256 KiB, twiddles 2*13*4096*4 = 416 KiB,
-  weights 2*8192*4 = 64 KiB  -> well under the ~16 MiB VMEM/core.
-MXU alignment: the innermost dim stays n >= 128 (lane-dim multiple of
-128); butterflies are pure VPU work, so the tile is lane-aligned rather
-than MXU-shaped.
+VMEM per program (n=128 pass of the 2^14 four-step, tile=8): coeffs
+8*128*4 = 4 KiB, lane twiddles 2*7*128*4 = 7 KiB.
 """
 from __future__ import annotations
 
@@ -56,48 +61,31 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.modmath import MASK16
+from repro.core.modmath import _shoup16_lazy_u32, mulhi_u32
 from repro.kernels import resolve_interpret
+
+U32 = jnp.uint32
 
 
 # --------------------------------------------------- in-kernel helpers
+#
+# Every operand is a u32 array; ``bits`` is the ring's arithmetic width.
 
-def _mulhi(a, b):
-    a0 = a & MASK16
-    a1 = a >> 16
-    b0 = b & MASK16
-    b1 = b >> 16
-    t = a0 * b0
-    m1 = a1 * b0 + (t >> 16)
-    m2 = a0 * b1 + (m1 & MASK16)
-    return a1 * b1 + (m1 >> 16) + (m2 >> 16)
+def _shoup_lazy(x, w, wp, q, bits):
+    """[0, 2q) Shoup product (no final subtract); x any lazy-band value."""
+    if bits == 16:
+        return _shoup16_lazy_u32(x, w, wp, q)
+    return x * w - mulhi_u32(x, wp) * q
 
 
-def _shoup16_lazy(x, w, wp, q):
-    # 16-bit lane: a 16x16 product is exact in u32, so the Shoup hi-part
-    # is a plain shift (wp = floor(w*2^16/q)); result < 2q < 2^16.
-    u = jnp.uint32
-    r = x.astype(u) * w.astype(u) \
-        - ((x.astype(u) * wp.astype(u)) >> 16) * q.astype(u)
-    return r
-
-
-def _shoup(x, w, wp, q):
-    if x.dtype == jnp.uint16:
-        r = _shoup16_lazy(x, w, wp, q)
-        q32 = q.astype(jnp.uint32)
-        return jnp.where(r >= q32, r - q32, r).astype(jnp.uint16)
-    r = x * w - _mulhi(x, wp) * q
+def _shoup(x, w, wp, q, bits):
+    r = _shoup_lazy(x, w, wp, q, bits)
     return jnp.where(r >= q, r - q, r)
-
-
-def _shoup_lazy(x, w, wp, q):
-    # [0, 2q) Shoup product: no final subtract.  x may be any lane value.
-    if x.dtype == jnp.uint16:
-        return _shoup16_lazy(x, w, wp, q).astype(jnp.uint16)
-    return x * w - _mulhi(x, wp) * q
 
 
 def _addmod(a, b, q):
@@ -118,89 +106,142 @@ def _lazy_sub(a, b, q2):
     return jnp.where(a >= b, a - b, a + (q2 - b))
 
 
+def _partner(x, lane, m):
+    """x[..., p ^ m] for every lane p: two lane rotations and a select.
+    The select mask is derived from rotating the lane iota itself, so it
+    holds whichever direction the hardware rotate takes."""
+    n = x.shape[-1]
+    a = pltpu.roll(x, m, 1)
+    b = pltpu.roll(x, n - m, 1)
+    a_is_partner = pltpu.roll(lane, m, 1) == (lane ^ m)
+    return jnp.where(a_is_partner, a, b)
+
+
 # Shared stage loops: one codepath for the single-prime and banks
-# kernels.  ``get_row(t)`` yields the (w, wp) rows for stage t.  In lazy
-# mode values ride in [0, 2q) (see core.modmath's lazy contract) and
-# each butterfly spends 2 conditional selects instead of 3; the caller
-# owns the epilogue reduction.
+# kernels.  ``get_row(t)`` yields the (1, n) lane rows (w, wp) of stage
+# t.  In lazy mode values ride in [0, 2q) (see core.modmath's lazy
+# contract) and the caller owns the epilogue reduction.
 
-def _fwd_stages(x, get_row, stages, qc, lazy):
-    bt, n = x.shape
-    q2 = qc + qc
+def _fwd_stages(x, get_row, stages, q, lazy, bits):
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    n = x.shape[-1]
+    q2 = q + q
     for t in range(stages):
+        m = n >> (t + 1)
         w, wp = get_row(t)
-        lo = x[:, : n // 2]
-        hi = x[:, n // 2:]
+        is_lo = (lane & m) == 0
+        y = _partner(x, lane, m)
+        hi = jnp.where(is_lo, y, x)
+        lo = jnp.where(is_lo, x, y)
         if lazy:
-            tt = _shoup_lazy(hi, w, wp, qc)
-            u = _lazy_add(lo, tt, q2)
-            v = _lazy_sub(lo, tt, q2)
+            tt = _shoup_lazy(hi, w, wp, q, bits)
+            x = jnp.where(is_lo, _lazy_add(lo, tt, q2), _lazy_sub(lo, tt, q2))
         else:
-            tt = _shoup(hi, w, wp, qc)
-            u = _addmod(lo, tt, qc)
-            v = _submod(lo, tt, qc)
-        x = jnp.stack([u, v], axis=-1).reshape(bt, n)
+            tt = _shoup(hi, w, wp, q, bits)
+            x = jnp.where(is_lo, _addmod(lo, tt, q), _submod(lo, tt, q))
     return x
 
 
-def _inv_stages(x, get_row, stages, qc, lazy):
-    bt, n = x.shape
-    q2 = qc + qc
+def _inv_stages(x, get_row, stages, q, lazy, bits):
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    n = x.shape[-1]
+    q2 = q + q
     for t in range(stages - 1, -1, -1):
+        m = n >> (t + 1)
         w, wp = get_row(t)
-        pairs = x.reshape(bt, n // 2, 2)
-        e = pairs[..., 0]
-        o = pairs[..., 1]
+        is_lo = (lane & m) == 0
+        y = _partner(x, lane, m)
+        e = jnp.where(is_lo, x, y)
+        o = jnp.where(is_lo, y, x)
         if lazy:
-            u = _lazy_add(e, o, q2)
-            v = _shoup_lazy(_lazy_sub(e, o, q2), w, wp, qc)
+            x = jnp.where(is_lo, _lazy_add(e, o, q2),
+                          _shoup_lazy(_lazy_sub(e, o, q2), w, wp, q, bits))
         else:
-            u = _addmod(e, o, qc)
-            v = _shoup(_submod(e, o, qc), w, wp, qc)
-        x = jnp.concatenate([u, v], axis=-1)
+            x = jnp.where(is_lo, _addmod(e, o, q),
+                          _shoup(_submod(e, o, q), w, wp, q, bits))
     return x
+
+
+# ------------------------------------------------------ layout helpers
+
+def _rotl(i: np.ndarray, r: int, bits: int) -> np.ndarray:
+    r %= bits
+    mask = (1 << bits) - 1
+    return ((i << r) | (i >> (bits - r))) & mask if r else i
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_index(stages: int, n: int) -> np.ndarray:
+    """(stages, n) CG pair index of every lane: lane p at stage t holds
+    CG position rotl(p, t), whose pair (and twiddle) is that position
+    mod n/2."""
+    lg = n.bit_length() - 1
+    p = np.arange(n, dtype=np.int64)
+    return np.stack([_rotl(p, t, lg) % (n // 2) for t in range(stages)])
+
+
+def lane_twiddles(tw):
+    """(..., s, n/2) CG twiddle rows -> (..., s, n) per-lane rows."""
+    s, half = tw.shape[-2:]
+    idx = _lane_index(s, 2 * half)
+    return tw[..., np.arange(s)[:, None], idx]
+
+
+@functools.lru_cache(maxsize=None)
+def incomplete_perm(stages: int, n: int) -> np.ndarray:
+    """Lane permutation from the kernel's output layout to the CG order
+    after ``stages`` stages: cg = out[..., perm].  The identity for a
+    complete transform (stages == log2 n)."""
+    lg = n.bit_length() - 1
+    return _rotl(np.arange(n, dtype=np.int64), lg - stages, lg)
+
+
+def _smem():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ----------------------------------------------------------- fwd kernel
 
 def _ntt_fwd_kernel(x_ref, tw_ref, twp_ref, pre_ref, prep_ref, o_ref, *,
                     q: int, stages: int, negacyclic: bool, lazy: bool):
-    qc = jnp.uint32(q)
+    qc = U32(q)
     x = x_ref[...]                      # (bt, n)
     if negacyclic:
-        x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[...], prep_ref[...], qc)
-    x = _fwd_stages(x, lambda t: (tw_ref[t, :], twp_ref[t, :]), stages, qc, lazy)
+        x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[...], prep_ref[...],
+                                              qc, 32)
+    x = _fwd_stages(x, lambda t: (tw_ref[t:t + 1, :], twp_ref[t:t + 1, :]),
+                    stages, qc, lazy, 32)
     if lazy:
         x = jnp.where(x >= qc, x - qc, x)   # epilogue: back to [0, q)
     o_ref[...] = x
 
 
 def _ntt_inv_kernel(x_ref, itw_ref, itwp_ref, post_ref, postp_ref, o_ref, *,
-                    q: int, stages: int, negacyclic: bool, ninv: int, ninv_p: int,
-                    lazy: bool):
-    qc = jnp.uint32(q)
+                    q: int, stages: int, negacyclic: bool, ninv: int,
+                    ninv_p: int, lazy: bool):
+    qc = U32(q)
     x = x_ref[...]
-    x = _inv_stages(x, lambda t: (itw_ref[t, :], itwp_ref[t, :]), stages, qc, lazy)
+    x = _inv_stages(x, lambda t: (itw_ref[t:t + 1, :], itwp_ref[t:t + 1, :]),
+                    stages, qc, lazy, 32)
     # the epilogue multiply fully reduces either path (_shoup takes any
     # u32 representative), so lazy costs nothing extra here
     if negacyclic:
-        x = _shoup(x, post_ref[...], postp_ref[...], qc)   # psi^-i * n^-1 fused
+        x = _shoup(x, post_ref[...], postp_ref[...], qc, 32)
     else:
-        x = _shoup(x, jnp.uint32(ninv), jnp.uint32(ninv_p), qc)
+        x = _shoup(x, U32(ninv), U32(ninv_p), qc, 32)
     o_ref[...] = x
 
 
 # ------------------------------------------------------------- wrappers
 
-def _grid_call(kernel, x, tables, row_args, *, tile: int, interpret: bool | None):
-    """Common grid/BlockSpec plumbing: grid over batch tiles; twiddle
-    tables and per-coefficient weight rows fully VMEM-resident."""
+def _grid_call(kernel, x, tables, row_args, *, tile: int,
+               interpret: bool | None):
+    """Grid over batch tiles; twiddle tables and per-coefficient weight
+    rows fully VMEM-resident."""
     interpret = resolve_interpret(interpret)
     b, n = x.shape
     assert b % tile == 0
-    s_tables = [
-        pl.BlockSpec(t.shape, lambda i: (0,) * t.ndim) for t in tables
-    ]
+    s_tables = [pl.BlockSpec(t.shape, lambda i: (0, 0)) for t in tables]
     s_rows = [pl.BlockSpec((1, n), lambda i: (0, 0)) for _ in row_args]
     return pl.pallas_call(
         kernel,
@@ -216,11 +257,13 @@ def _grid_call(kernel, x, tables, row_args, *, tile: int, interpret: bool | None
 def ntt_fwd_pallas(x, tw, twp, pre, prep, *, q: int, stages: int,
                    negacyclic: bool, tile: int = 8, lazy: bool = False,
                    interpret: bool | None = None):
-    """x: (batch, n) u32.  pre/prep: (1, n) psi-power rows (ignored when
-    not negacyclic but still passed to keep one kernel signature)."""
+    """x: (batch, n) u32; tw/twp: (s, n/2) CG tables.  pre/prep: (1, n)
+    psi-power rows (ignored when not negacyclic but still passed to
+    keep one kernel signature)."""
     kern = functools.partial(_ntt_fwd_kernel, q=q, stages=stages,
                              negacyclic=negacyclic, lazy=lazy)
-    return _grid_call(kern, x, [tw, twp], [pre, prep], tile=tile, interpret=interpret)
+    return _grid_call(kern, x, [lane_twiddles(tw), lane_twiddles(twp)],
+                      [pre, prep], tile=tile, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("q", "stages", "negacyclic", "ninv", "ninv_p", "tile", "lazy", "interpret"))
@@ -231,26 +274,29 @@ def ntt_inv_pallas(x, itw, itwp, post, postp, *, q: int, stages: int,
     kern = functools.partial(_ntt_inv_kernel, q=q, stages=stages,
                              negacyclic=negacyclic, ninv=ninv, ninv_p=ninv_p,
                              lazy=lazy)
-    return _grid_call(kern, x, [itw, itwp], [post, postp], tile=tile, interpret=interpret)
+    return _grid_call(kern, x, [lane_twiddles(itw), lane_twiddles(itwp)],
+                      [post, postp], tile=tile, interpret=interpret)
 
 
 # ------------------------------------------------ multi-prime NTT banks
 
 def _ntt_fwd_banks_kernel(x_ref, q_ref, tw_ref, twp_ref, pre_ref, prep_ref,
                           o_ref, *, stages: int, negacyclic: bool, lazy: bool,
-                          reduce_out: bool):
+                          reduce_out: bool, bits: int):
     """One bank row: program (p, i) transforms batch tile i under prime
-    row p.  The modulus is a per-program scalar read from q_ref.
+    row p.  The modulus is a per-program scalar read from SMEM.
 
     lazy + reduce_out=False emits the raw [0, 2q) representatives for a
     lazy-aware consumer (the four-step twiddle pass absorbs the
     reduction in its own Shoup multiply)."""
-    qc = q_ref[0, 0]
+    qc = q_ref[pl.program_id(0)]
     x = x_ref[0]                        # (tile, n)
     if negacyclic:
-        x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[0], prep_ref[0], qc)
-    x = _fwd_stages(x, lambda t: (tw_ref[0, t, :], twp_ref[0, t, :]),
-                    stages, qc, lazy)
+        x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[0], prep_ref[0],
+                                              qc, bits)
+    x = _fwd_stages(x, lambda t: (tw_ref[0, t:t + 1, :],
+                                  twp_ref[0, t:t + 1, :]),
+                    stages, qc, lazy, bits)
     if lazy and reduce_out:
         x = jnp.where(x >= qc, x - qc, x)
     o_ref[0] = x
@@ -258,96 +304,125 @@ def _ntt_fwd_banks_kernel(x_ref, q_ref, tw_ref, twp_ref, pre_ref, prep_ref,
 
 def _ntt_inv_banks_kernel(x_ref, q_ref, ninv_ref, ninvp_ref, itw_ref, itwp_ref,
                           post_ref, postp_ref, o_ref, *, stages: int,
-                          negacyclic: bool, lazy: bool, reduce_out: bool):
-    qc = q_ref[0, 0]
+                          negacyclic: bool, lazy: bool, reduce_out: bool,
+                          bits: int):
+    p = pl.program_id(0)
+    qc = q_ref[p]
     x = x_ref[0]
-    x = _inv_stages(x, lambda t: (itw_ref[0, t, :], itwp_ref[0, t, :]),
-                    stages, qc, lazy)
+    x = _inv_stages(x, lambda t: (itw_ref[0, t:t + 1, :],
+                                  itwp_ref[0, t:t + 1, :]),
+                    stages, qc, lazy, bits)
     # epilogue multiply: full reduce unless a lazy consumer asked for the
     # [0, 2q) representative (reduce_out=False only makes sense in lazy
     # mode; the eager multiply is always exact)
     mul = _shoup_lazy if (lazy and not reduce_out) else _shoup
     if negacyclic:
-        x = mul(x, post_ref[0], postp_ref[0], qc)       # psi^-i * n^-1 fused
+        x = mul(x, post_ref[0], postp_ref[0], qc, bits)  # psi^-i * n^-1
     else:
-        x = mul(x, ninv_ref[0, 0], ninvp_ref[0, 0], qc)
+        x = mul(x, ninv_ref[p], ninvp_ref[p], qc, bits)
     o_ref[0] = x
 
 
 def _banks_grid_call(kernel, x, scalars, tables, rows, *, tile: int,
                      interpret: bool | None):
-    """Grid (prime, batch_tile).  ``scalars`` are (k, 1) per-prime values,
-    ``tables`` are (k, ...) twiddle stacks, ``rows`` are (k, n) weight
-    rows — every spec selects row p of its stack via the leading grid
-    coordinate, so each program sees exactly its bank's constants."""
+    """Grid (prime, batch_tile).  ``scalars`` are (k,) per-prime values
+    (whole arrays in SMEM), ``tables`` (k, s, n) lane-twiddle stacks,
+    ``rows`` (k, n) weight rows (re-laid out as (k, 1, n)).  Every
+    VMEM spec selects row p of its stack via the leading grid
+    coordinate, so each program sees exactly its bank's constants.  All
+    operands are widened to u32 lanes here."""
     interpret = resolve_interpret(interpret)
     k, b, n = x.shape
     assert b % tile == 0
-
-    def row_spec(tail_ndim, shape):
-        return pl.BlockSpec((1,) + shape[1:],
-                            lambda p, i, nd=tail_ndim: (p,) + (0,) * nd)
-
+    u = lambda a: a.astype(U32)
     in_specs = [pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0))]
-    in_specs += [pl.BlockSpec((1, 1), lambda p, i: (p, 0)) for _ in scalars]
-    in_specs += [row_spec(t.ndim - 1, t.shape) for t in tables]
-    in_specs += [pl.BlockSpec((1, n), lambda p, i: (p, 0)) for _ in rows]
+    in_specs += [_smem() for _ in scalars]
+    in_specs += [pl.BlockSpec((1,) + t.shape[1:], lambda p, i: (p, 0, 0))
+                 for t in tables]
+    in_specs += [pl.BlockSpec((1, 1, r.shape[-1]), lambda p, i: (p, 0, 0))
+                 for r in rows]
     return pl.pallas_call(
         kernel,
         grid=(k, b // tile),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, b, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, b, n), U32),
         interpret=interpret,
-    )(x, *scalars, *tables, *rows)
+    )(u(x), *map(u, scalars), *map(u, tables),
+      *(u(r)[:, None, :] for r in rows))
 
 
-@functools.partial(jax.jit, static_argnames=("stages", "negacyclic", "tile", "lazy", "reduce_out", "interpret"))
-def ntt_fwd_banks_pallas(x, qs2, tw, twp, pre, prep, *, stages: int,
+@functools.partial(jax.jit, static_argnames=("stages", "negacyclic", "tile", "lazy", "reduce_out", "bits", "interpret"))
+def ntt_fwd_banks_pallas(x, qs, tw, twp, pre, prep, *, stages: int,
                          negacyclic: bool, tile: int = 8, lazy: bool = False,
-                         reduce_out: bool = True,
+                         reduce_out: bool = True, bits: int = 32,
                          interpret: bool | None = None):
-    """x: (k, batch, n) u32, row i reduced mod qs2[i, 0].
-    qs2: (k, 1); tw/twp: (k, s, n/2); pre/prep: (k, n) psi rows."""
+    """x: (k, batch, n), row i reduced mod qs[i]; qs: (k,);
+    tw/twp: (k, s, n/2) CG tables; pre/prep: (k, n) psi rows.  Returns
+    u32 in the kernel's lane layout (``incomplete_perm`` maps an
+    incomplete ring to CG order)."""
     kern = functools.partial(_ntt_fwd_banks_kernel, stages=stages,
                              negacyclic=negacyclic, lazy=lazy,
-                             reduce_out=reduce_out)
-    return _banks_grid_call(kern, x, [qs2], [tw, twp], [pre, prep],
-                            tile=tile, interpret=interpret)
+                             reduce_out=reduce_out, bits=bits)
+    return _banks_grid_call(kern, x, [qs],
+                            [lane_twiddles(tw), lane_twiddles(twp)],
+                            [pre, prep], tile=tile, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("stages", "negacyclic", "tile", "lazy", "reduce_out", "interpret"))
-def ntt_inv_banks_pallas(x, qs2, ninv2, ninvp2, itw, itwp, post, postp, *,
+@functools.partial(jax.jit, static_argnames=("stages", "negacyclic", "tile", "lazy", "reduce_out", "bits", "interpret"))
+def ntt_inv_banks_pallas(x, qs, ninv, ninvp, itw, itwp, post, postp, *,
                          stages: int, negacyclic: bool, tile: int = 8,
                          lazy: bool = False, reduce_out: bool = True,
-                         interpret: bool | None = None):
+                         bits: int = 32, interpret: bool | None = None):
     kern = functools.partial(_ntt_inv_banks_kernel, stages=stages,
                              negacyclic=negacyclic, lazy=lazy,
-                             reduce_out=reduce_out)
-    return _banks_grid_call(kern, x, [qs2, ninv2, ninvp2], [itw, itwp],
+                             reduce_out=reduce_out, bits=bits)
+    return _banks_grid_call(kern, x, [qs, ninv, ninvp],
+                            [lane_twiddles(itw), lane_twiddles(itwp)],
                             [post, postp], tile=tile, interpret=interpret)
 
 
 # ------------------------------------------- four-step twiddle multiply
 
-def _twiddle_mul_banks_kernel(x_ref, q_ref, w_ref, wp_ref, o_ref, *, lazy: bool):
+# Lane block of the elementwise banks kernels: a 2^14 ring row is split
+# into 2048-lane chunks so a program's double-buffered blocks stay far
+# below the scoped VMEM limit at any legal batch tile.
+LANE_BLOCK = 2048
+
+
+def lane_block(n: int) -> int:
+    return min(n, LANE_BLOCK)
+
+
+def _twiddle_mul_banks_kernel(x_ref, q_ref, w_ref, wp_ref, o_ref, *,
+                              lazy: bool):
     """Step 3 of the four-step schedule (paper §IX): the pointwise
     w^(j2*k1) correction between the column and row NTT passes, fused as
-    one (prime, batch_tile) Shoup multiply.  The same kernel applies the
-    negacyclic psi^i pre-weights / psi^-i post-weights, which share the
-    per-prime (k, n) weight-row layout.  lazy=True emits the [0, 2q)
-    Shoup representative (the consumer owns the reduction); either way
-    any u32 input representative is accepted."""
+    one (prime, batch_tile, lane_chunk) Shoup multiply.  The same kernel
+    applies the negacyclic psi^i pre-weights / psi^-i post-weights.
+    lazy=True emits the [0, 2q) Shoup representative; either way any
+    u32 input representative is accepted."""
     mul = _shoup_lazy if lazy else _shoup
-    o_ref[0] = mul(x_ref[0], w_ref[0], wp_ref[0], q_ref[0, 0])
+    o_ref[0] = mul(x_ref[0], w_ref[0], wp_ref[0], q_ref[pl.program_id(0)], 32)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "lazy", "interpret"))
-def twiddle_mul_banks_pallas(x, qs2, w, wp, *, tile: int = 8,
+def twiddle_mul_banks_pallas(x, qs, w, wp, *, tile: int = 8,
                              lazy: bool = False,
                              interpret: bool | None = None):
-    """x: (k, batch, n) u32; qs2: (k, 1); w/wp: (k, n) weight rows +
-    Shoup companions.  out[p, i, :] = x[p, i, :] * w[p, :] mod qs[p]."""
-    kern = functools.partial(_twiddle_mul_banks_kernel, lazy=lazy)
-    return _banks_grid_call(kern, x, [qs2], [], [w, wp],
-                            tile=tile, interpret=interpret)
+    """x: (k, batch, n) u32; qs: (k,); w/wp: (k, n) weight rows + Shoup
+    companions.  out[p, i, :] = x[p, i, :] * w[p, :] mod qs[p]."""
+    interpret = resolve_interpret(interpret)
+    k, b, n = x.shape
+    assert b % tile == 0
+    nl = lane_block(n)
+    row = pl.BlockSpec((1, 1, nl), lambda p, i, c: (p, 0, c))
+    blk = pl.BlockSpec((1, tile, nl), lambda p, i, c: (p, i, c))
+    return pl.pallas_call(
+        functools.partial(_twiddle_mul_banks_kernel, lazy=lazy),
+        grid=(k, b // tile, n // nl),
+        in_specs=[blk, _smem(), row, row],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct((k, b, n), U32),
+        interpret=interpret,
+    )(x, qs, w[:, None, :], wp[:, None, :])

@@ -1,9 +1,16 @@
 """Public jit'd entry points for the NTT/dyadic compute layer.
 
-Dispatch policy: Pallas kernels target TPU; on CPU (this container) the
+Dispatch policy: Pallas kernels target TPU; on other backends the
 kernels run in interpret mode for validation, but the *default* hot path
-on non-TPU backends is the pure-jnp reference (same math, faster under
-XLA:CPU).  ``use_pallas=True`` forces the kernel path (tests do this).
+there is the pure-jnp reference (same math, faster under XLA:CPU).
+``use_pallas=True`` forces the kernel path (tests do this).  The Galois
+automorphism is one XLA gather on every backend (``galois_banks``):
+Mosaic has no lane gather past 128 lanes.
+
+Ring element dtypes: CKKS rows are u32; a small ring (ML-KEM's u16
+``RingSpec``) may pass u16 or u32 rows.  The kernels always compute in
+u32 lanes with the ring's arithmetic width (``bits``, read from the
+pack's dtype); outputs come back in the input's dtype.
 
 Two entry-point families:
 
@@ -39,8 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.modmath import dtype_bits
 from repro.core.params import NTTParams, bitrev_perm
-from repro.kernels import autotune, ntt_kernel, dyadic_kernel, galois_kernel, ref
+from repro.kernels import autotune, ntt_kernel, dyadic_kernel, ref
 
 # Single-kernel tile budget: below this ring size the whole log2(n)-stage
 # transform runs as ONE fused banks kernel; at or above it the large-N
@@ -148,6 +156,12 @@ def _pad_mid(x3, tile):
     return x3, b
 
 
+def _ring_bits(t: dict) -> int:
+    """Arithmetic width of a pack's ring (32 for CKKS RNS primes, 16 for
+    small rings), read from its static table dtype."""
+    return dtype_bits(t["qs"].dtype)
+
+
 def _rows(t: dict, k: int, *names):
     """First-k prime rows of the named TablePack entries (so a pack for
     a superset basis, e.g. basis+special, works on k-row inputs)."""
@@ -219,18 +233,21 @@ def ntt_banks(x, t: dict, *, negacyclic: bool = True,
     k, n = x.shape[0], x.shape[-1]
     qs, tw, twp, psi, psip = _rows(t, k, "qs", "tw", "twp", "psi", "psip")
     if not use_pallas:
-        return ref.ntt_fwd_banks_ref(x, qs, tw, twp, psi, psip, negacyclic,
-                                     lazy=lazy, reduce_out=reduce_out)
+        return ref.ntt_fwd_banks_ref(x.astype(qs.dtype), qs, tw, twp, psi,
+                                     psip, negacyclic, lazy=lazy,
+                                     reduce_out=reduce_out).astype(x.dtype)
     shape = x.shape
     x3 = x.reshape(k, -1, n)
     tile = autotune.resolve_tile("ntt_banks", k, n, x3.shape[1], tile,
-                                 dtype=x.dtype.name)
+                                 dtype=qs.dtype.name)
     x3, b = _pad_mid(x3, tile)
+    stages = tw.shape[1]
     out = ntt_kernel.ntt_fwd_banks_pallas(
-        x3, qs[:, None], tw, twp, psi, psip,
-        stages=tw.shape[1], negacyclic=negacyclic, tile=tile, lazy=lazy,
-        reduce_out=reduce_out)
-    return out[:, :b].reshape(shape)
+        x3, qs, tw, twp, psi, psip, stages=stages, negacyclic=negacyclic,
+        tile=tile, lazy=lazy, reduce_out=reduce_out, bits=_ring_bits(t))
+    if stages != n.bit_length() - 1:
+        out = out[..., ntt_kernel.incomplete_perm(stages, n)]
+    return out[:, :b].reshape(shape).astype(x.dtype)
 
 
 @_spanned
@@ -244,20 +261,23 @@ def intt_banks(x, t: dict, *, negacyclic: bool = True,
     qs, ninv, ninv_p, itw, itwp, ipsin, ipsinp = _rows(
         t, k, "qs", "ninv", "ninv_p", "itw", "itwp", "ipsin", "ipsinp")
     if not use_pallas:
-        return ref.ntt_inv_banks_ref(x, qs, ninv, ninv_p, itw, itwp,
-                                     ipsin, ipsinp, negacyclic,
-                                     lazy=lazy, reduce_out=reduce_out)
+        return ref.ntt_inv_banks_ref(x.astype(qs.dtype), qs, ninv, ninv_p,
+                                     itw, itwp, ipsin, ipsinp, negacyclic,
+                                     lazy=lazy, reduce_out=reduce_out
+                                     ).astype(x.dtype)
     shape = x.shape
     x3 = x.reshape(k, -1, n)
     tile = autotune.resolve_tile("intt_banks", k, n, x3.shape[1], tile,
-                                 dtype=x.dtype.name)
+                                 dtype=qs.dtype.name)
     x3, b = _pad_mid(x3, tile)
+    stages = itw.shape[1]
+    if stages != n.bit_length() - 1:
+        x3 = x3[..., np.argsort(ntt_kernel.incomplete_perm(stages, n))]
     out = ntt_kernel.ntt_inv_banks_pallas(
-        x3, qs[:, None], ninv[:, None], ninv_p[:, None],
-        itw, itwp, ipsin, ipsinp,
-        stages=itw.shape[1], negacyclic=negacyclic, tile=tile, lazy=lazy,
-        reduce_out=reduce_out)
-    return out[:, :b].reshape(shape)
+        x3, qs, ninv, ninv_p, itw, itwp, ipsin, ipsinp, stages=stages,
+        negacyclic=negacyclic, tile=tile, lazy=lazy, reduce_out=reduce_out,
+        bits=_ring_bits(t))
+    return out[:, :b].reshape(shape).astype(x.dtype)
 
 
 @_spanned
@@ -282,63 +302,39 @@ def twiddle_mul_banks(x, w, wp, qs, *, use_pallas: bool | None = None,
     tile = autotune.resolve_tile("twiddle_mul_banks", k, n, x3.shape[1], tile,
                                  dtype=x.dtype.name)
     x3, b = _pad_mid(x3, tile)
-    out = ntt_kernel.twiddle_mul_banks_pallas(x3, qs[:, None], w, wp,
+    out = ntt_kernel.twiddle_mul_banks_pallas(x3, qs, w, wp,
                                               tile=tile, lazy=lazy)
     return out[:, :b].reshape(shape)
 
 
 @_spanned
 @_ct_batch_axis
-def galois_banks(x, idx, *, use_pallas: bool | None = None,
-                 tile: int | None = None):
+def galois_banks(x, idx):
     """Galois automorphism in the NTT domain: out[..., j] = x[..., idx[j]].
 
     x: (k, ..., n) u32 NTT-form residue rows; idx: (n,) int32 slot
     permutation from ``core.params.galois_eval_perm`` (the same row for
-    every prime — root-exponent arithmetic never touches q).  One fused
-    (prime, batch_tile) gather kernel on the Pallas path; a single jnp
-    gather on the reference path.  This replaces the host
-    iNTT -> permute -> NTT round trip for rotate/conjugate.
+    every prime — root-exponent arithmetic never touches q).  One XLA
+    gather on every backend (Mosaic has no lane gather past 128 lanes);
+    this replaces the host iNTT -> permute -> NTT round trip for
+    rotate/conjugate.
 
     A (B, n) ``idx`` applies gather row j to batch row j (B must equal
     the product of x's middle dims), so one dispatch can mix rotation
     amounts across a ciphertext batch; ``batch_leading=True`` reads x as
     a (b, k, ..., n) ciphertext-batch stack as in ``ntt_banks``."""
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
     x = jnp.asarray(x)
     idx = jnp.asarray(idx, jnp.int32)
-    k, n = x.shape[0], x.shape[-1]
     if idx.ndim == 2:
+        k, n = x.shape[0], x.shape[-1]
         assert idx.shape[0] == int(np.prod(x.shape[1:-1], dtype=np.int64)), \
             (idx.shape, x.shape)
-    if not use_pallas:
-        if idx.ndim == 2:
-            out = ref.galois_banks_ref(x.reshape(k, -1, n), idx)
-            return out.reshape(x.shape)
-        return ref.galois_banks_ref(x, idx)
-    shape = x.shape
-    x3 = x.reshape(k, -1, n)
-    tile = autotune.resolve_tile("galois_banks", k, n, x3.shape[1], tile,
-                                 dtype=x.dtype.name)
-    x3, b = _pad_mid(x3, tile)
-    if idx.ndim == 2:
-        pad = x3.shape[1] - b
-        if pad:
-            # padded batch rows gather through a true identity (iota) row:
-            # an all-zeros row would be a constant-0 gather, and the pad
-            # rows must stay a plain in-bounds passthrough of whatever
-            # (possibly unreduced) values the pad carries
-            iota = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (pad, n))
-            idx = jnp.concatenate([idx, iota], axis=0)
-        out = galois_kernel.galois_banks_multi_pallas(x3, idx, tile=tile)
-    else:
-        out = galois_kernel.galois_banks_pallas(x3, idx[None, :], tile=tile)
-    return out[:, :b].reshape(shape)
+        return ref.galois_banks_ref(x.reshape(k, -1, n), idx).reshape(x.shape)
+    return ref.galois_banks_ref(x, idx)
 
 
 @_spanned
-def galois_digits_banks(ext, idx, *, use_pallas: bool | None = None,
-                        tile: int | None = None):
+def galois_digits_banks(ext, idx):
     """Galois gather over key-switch digit extensions — the hoisted-
     rotation move: apply per-batch gather rows to a SHARED digit
     decomposition instead of re-decomposing per rotation.
@@ -348,39 +344,19 @@ def galois_digits_banks(ext, idx, *, use_pallas: bool | None = None,
     hoisted batch fold into the B axis); idx: (B, n) int32 gather rows,
     row b applied to batch column b of EVERY digit and prime row (the
     automorphism permutation never depends on the digit or the modulus).
-    Returns (d, k, B, n).  One fused (prime, batch_tile) kernel with the
-    digit loop unrolled inside on the Pallas path; a single
-    take_along_axis on the reference path.
+    Returns (d, k, B, n), as one XLA gather.
 
     A (d, k, 1, n) ext against a (B, n) idx with B > 1 runs in SHARED
     mode — the hoisted decompose-once layout: every gather row reads
     the one shared digit stack (out[d, p, b, j] = ext[d, p, 0,
-    idx[b, j]]), which is never replicated B-fold in HBM (the kernel
-    pins its batch block to column 0)."""
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
+    idx[b, j]]), which is never replicated B-fold in HBM."""
     ext = jnp.asarray(ext)
     idx = jnp.asarray(idx, jnp.int32)
     d, k, b, n = ext.shape
     bi = idx.shape[0]
-    shared = b == 1 and bi != 1
-    assert idx.shape == (bi, n) and (shared or bi == b), \
+    assert idx.shape == (bi, n) and (b == 1 or bi == b), \
         (idx.shape, ext.shape)
-    if not use_pallas:
-        return ref.galois_digits_banks_ref(ext, idx)
-    tile = autotune.resolve_tile("galois_digits_banks", k, n, bi, tile,
-                                 dtype=ext.dtype.name)
-    pad = (-bi) % tile
-    if pad:
-        # padded batch rows gather through a true identity (iota) row —
-        # see ``galois_banks``; zeros would be a constant-0 gather
-        iota = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (pad, n))
-        idx = jnp.concatenate([idx, iota], axis=0)
-        if not shared:
-            ext = jnp.concatenate(
-                [ext, jnp.zeros((d, k, pad, n), ext.dtype)], axis=2)
-    out = galois_kernel.galois_digits_pallas(ext, idx, digits=d,
-                                             shared=shared, tile=tile)
-    return out[:, :, :bi]
+    return ref.galois_digits_banks_ref(ext, idx)
 
 
 # ------------------------------------------- large-N four-step pipeline
@@ -520,8 +496,7 @@ def dyadic_inner_banks(ext, evk, t: dict, *, use_pallas: bool | None = None,
         if evk.ndim == 4:
             evk = jnp.concatenate([evk, z], axis=2)
     out = dyadic_kernel.dyadic_inner_banks(
-        ext, evk, t["qs"][:, None], t["mu"][:, None], digits=d, tile=tile,
-        lazy=lazy)
+        ext, evk, t["qs"], t["mu"], digits=d, tile=tile, lazy=lazy)
     return out[:, :b]
 
 
@@ -552,16 +527,18 @@ def dyadic_basemul_banks(a, b, t: dict, *, batch_leading: bool = False,
     k, n = a.shape[0], a.shape[-1]
     qs, mus, gamma, gammap = _rows(t, k, "qs", "mu", "gamma", "gammap")
     if not use_pallas:
-        return ref.dyadic_basemul_banks_ref(a, b, qs, mus, gamma, gammap,
-                                            lazy=lazy)
+        rd = qs.dtype
+        return ref.dyadic_basemul_banks_ref(a.astype(rd), b.astype(rd), qs,
+                                            mus, gamma, gammap, lazy=lazy
+                                            ).astype(a.dtype)
     shape = a.shape
     a3 = a.reshape(k, -1, n)
     b3 = b.reshape(k, -1, n)
     tile = autotune.resolve_tile("dyadic_basemul_banks", k, n, a3.shape[1],
-                                 tile, dtype=a.dtype.name)
+                                 tile, dtype=qs.dtype.name)
     a3, bsz = _pad_mid(a3, tile)
     b3, _ = _pad_mid(b3, tile)
     out = dyadic_kernel.dyadic_basemul_banks(
-        a3, b3, qs[:, None], mus[:, None], gamma, gammap, tile=tile,
-        lazy=lazy)
-    return out[:, :bsz].reshape(shape)
+        a3, b3, qs, mus, gamma, gammap, tile=tile, lazy=lazy,
+        bits=_ring_bits(t))
+    return out[:, :bsz].reshape(shape).astype(a.dtype)

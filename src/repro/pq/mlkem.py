@@ -3,10 +3,13 @@
 The second scheme of the repo: every polynomial transform and product
 routes through the SAME kernel entry points the CKKS stack uses —
 ``ops.ntt_banks`` / ``ops.intt_banks`` for the incomplete n=256/q=3329
-transform (7 stages, u16 lanes) and ``ops.dyadic_basemul_banks`` for
+transform (7 stages, the u16 ring's 16-bit arithmetic on u32 lanes) and
+``ops.dyadic_basemul_banks`` for
 the degree-1 basecase products — under the ``core.ringspec.MLKEM_RING``
 descriptor.  There is no scheme-private NTT anywhere in this module;
-host numpy handles only byte codecs, samplers and hashing.
+host numpy handles only byte codecs, samplers and hashing.  Polynomial
+rows are u32 on the host and on the device; values narrow to bytes
+only in ByteEncode.
 
 Batching: every public entry point is batched over a leading ``(b,)``
 axis of independent requests (the serving convention).  All the
@@ -103,7 +106,7 @@ def _sample_ntt(rho: bytes, j: int, i: int) -> np.ndarray:
         cand = np.stack([d1, d2], axis=-1).reshape(-1)
         acc = cand[cand < Q]
         if len(acc) >= N:
-            return acc[:N].astype(np.uint16)
+            return acc[:N].astype(np.uint32)
         need *= 2
 
 
@@ -113,7 +116,7 @@ def _cbd(eta: int, buf: bytes) -> np.ndarray:
                          bitorder="little").reshape(N, 2 * eta)
     x = bits[:, :eta].sum(axis=1, dtype=np.int64)
     y = bits[:, eta:].sum(axis=1, dtype=np.int64)
-    return ((x - y) % Q).astype(np.uint16)
+    return ((x - y) % Q).astype(np.uint32)
 
 
 # ---------------------------------------------------------- byte codecs
@@ -157,7 +160,7 @@ def _ntt_rows(x: np.ndarray) -> np.ndarray:
     """Forward incomplete NTT of every (..., 256) row in ONE banks
     dispatch (natural coefficients in, CG NTT domain out)."""
     sh = x.shape
-    rows = np.ascontiguousarray(x.reshape(-1, N)).astype(np.uint16)
+    rows = np.ascontiguousarray(x.reshape(-1, N)).astype(np.uint32)
     out = ops.ntt_banks(rows[None], _pack(), negacyclic=False)
     return np.asarray(out)[0].reshape(sh)
 
@@ -165,7 +168,7 @@ def _ntt_rows(x: np.ndarray) -> np.ndarray:
 def _intt_rows(x: np.ndarray) -> np.ndarray:
     """Inverse incomplete NTT (CG NTT domain in, natural coeffs out)."""
     sh = x.shape
-    rows = np.ascontiguousarray(x.reshape(-1, N)).astype(np.uint16)
+    rows = np.ascontiguousarray(x.reshape(-1, N)).astype(np.uint32)
     out = ops.intt_banks(rows[None], _pack(), negacyclic=False)
     return np.asarray(out)[0].reshape(sh)
 
@@ -173,8 +176,8 @@ def _intt_rows(x: np.ndarray) -> np.ndarray:
 def _basemul_rows(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Degree-1 basecase products of every row pair in ONE dispatch."""
     sh = a.shape
-    ar = np.ascontiguousarray(a.reshape(-1, N)).astype(np.uint16)
-    br = np.ascontiguousarray(b.reshape(-1, N)).astype(np.uint16)
+    ar = np.ascontiguousarray(a.reshape(-1, N)).astype(np.uint32)
+    br = np.ascontiguousarray(b.reshape(-1, N)).astype(np.uint32)
     out = ops.dyadic_basemul_banks(ar[None], br[None], _pack())
     return np.asarray(out)[0].reshape(sh)
 
@@ -188,14 +191,14 @@ def _matvec_hat(a_hat: np.ndarray, y_hat: np.ndarray) -> np.ndarray:
     bsz = a_hat.shape[0]
     rhs = np.broadcast_to(y_hat[:, None], (bsz, K, K, N))
     prods = _basemul_rows(a_hat, rhs).astype(np.int64)
-    return (prods.sum(axis=2) % Q).astype(np.uint16)
+    return (prods.sum(axis=2) % Q).astype(np.uint32)
 
 
 def _dot_hat(t_hat: np.ndarray, y_hat: np.ndarray) -> np.ndarray:
     """(t̂ᵀ ∘ ŷ) = Σ_j t̂[j] ⊛ ŷ[j]: (b, K, 256) x (b, K, 256) ->
     (b, 256), one basemul dispatch + host sum."""
     prods = _basemul_rows(t_hat, y_hat).astype(np.int64)
-    return (prods.sum(axis=1) % Q).astype(np.uint16)
+    return (prods.sum(axis=1) % Q).astype(np.uint32)
 
 
 # --------------------------------------------------------- K-PKE layers
@@ -203,7 +206,7 @@ def _dot_hat(t_hat: np.ndarray, y_hat: np.ndarray) -> np.ndarray:
 def _expand_a(rhos: list[bytes]) -> np.ndarray:
     """Matrix Â per batch item, SampleNTT(ρ ‖ j ‖ i) converted to CG
     order: (b, K, K, 256) uint16."""
-    a = np.empty((len(rhos), K, K, N), dtype=np.uint16)
+    a = np.empty((len(rhos), K, K, N), dtype=np.uint32)
     for bi, rho in enumerate(rhos):
         for i in range(K):
             for j in range(K):
@@ -213,7 +216,7 @@ def _expand_a(rhos: list[bytes]) -> np.ndarray:
 
 def _cbd_vector(eta: int, seeds: list[bytes], n0: int) -> np.ndarray:
     """(b, K, 256) of CBD_eta(PRF(seed, n0 + i)) rows."""
-    out = np.empty((len(seeds), K, N), dtype=np.uint16)
+    out = np.empty((len(seeds), K, N), dtype=np.uint32)
     for bi, s in enumerate(seeds):
         for i in range(K):
             out[bi, i] = _cbd(eta, _prf(eta, s, n0 + i))
@@ -226,7 +229,7 @@ def _k_pke_encrypt(ek: np.ndarray, m: np.ndarray,
     r per-item randomness seeds.  Returns ct (b, 1088) u8."""
     bsz = ek.shape[0]
     t_hat = (byte_decode(12, ek[:, :384 * K].reshape(bsz, K, 384))
-             % Q).astype(np.uint16)[..., _TO_CG]
+             % Q).astype(np.uint32)[..., _TO_CG]
     a_hat = _expand_a([ek[i, 384 * K:].tobytes() for i in range(bsz)])
     y = _cbd_vector(ETA1, r, 0)
     e1 = _cbd_vector(ETA2, r, K)
@@ -252,8 +255,8 @@ def _k_pke_decrypt(dk_pke: np.ndarray, ct: np.ndarray) -> np.ndarray:
         DU, ct[:, :32 * DU * K].reshape(bsz, K, 32 * DU)))
     v = decompress(DV, byte_decode(DV, ct[:, 32 * DU * K:]))
     s_hat = (byte_decode(12, dk_pke.reshape(bsz, K, 384))
-             % Q).astype(np.uint16)[..., _TO_CG]
-    w_hat = _dot_hat(s_hat, _ntt_rows(u.astype(np.uint16)))
+             % Q).astype(np.uint32)[..., _TO_CG]
+    w_hat = _dot_hat(s_hat, _ntt_rows(u.astype(np.uint32)))
     w = (v - _intt_rows(w_hat).astype(np.int64)) % Q
     return byte_encode(1, compress(1, w))
 
@@ -280,7 +283,7 @@ def _keygen_batch(d, z, bsz):
     se_hat = _ntt_rows(np.concatenate([s, e], axis=1))  # one dispatch
     s_hat, e_hat = se_hat[:, :K], se_hat[:, K:]
     t_hat = ((_matvec_hat(a_hat, s_hat).astype(np.int64) + e_hat)
-             % Q).astype(np.uint16)
+             % Q).astype(np.uint32)
     rho_rows = np.stack([np.frombuffer(r, dtype=np.uint8) for r in rhos])
     ek = np.concatenate(
         [byte_encode(12, t_hat[..., _TO_FIPS]).reshape(bsz, 384 * K),

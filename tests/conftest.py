@@ -15,6 +15,16 @@ for _p in (_HERE, os.path.join(os.path.dirname(_HERE), "src")):
 
 import numpy as np  # noqa: E402  (after the path bootstrap)
 
+try:
+    from hypothesis import settings as _hyp_settings
+except ImportError:       # hypcompat's deterministic sweep has no deadline
+    pass
+else:
+    # one profile for every property test: the first example of a sweep
+    # pays a JIT compile, which no per-example deadline can budget for
+    _hyp_settings.register_profile("repro", deadline=None)
+    _hyp_settings.load_profile("repro")
+
 
 def ct_equal(a, b) -> bool:
     """Full ciphertext equality: both residue stacks bit-identical AND

@@ -26,12 +26,21 @@ def _clean(monkeypatch):
 
 
 def test_clamp_rule():
+    """Every clamped tile is legal on TPU: the whole batch, or a
+    multiple of 8 no wider than the request."""
     assert autotune.clamp(8, 1) == 1
     assert autotune.clamp(8, 5) == 5
     assert autotune.clamp(8, 100) == 8
-    assert autotune.clamp(0, 4) == 1
+    assert autotune.clamp(0, 4) == 4          # sub-sublane batch: whole
+    assert autotune.clamp(4, 100) == 8        # below one sublane tile
+    assert autotune.clamp(20, 100) == 16      # rounded down to 8k
+    assert autotune.clamp(64, 40) == 40       # request covers the batch
     assert autotune.clamp(8, 0) == 1
     assert autotune.clamp(8, -3) == 1
+    for b in range(1, 70):
+        for t in (1, 2, 4, 8, 12, 16, 32, 64):
+            c = autotune.clamp(t, b)
+            assert c == b or (c % 8 == 0 and c < b), (t, b, c)
 
 
 def test_resolve_precedence():
@@ -44,8 +53,8 @@ def test_resolve_precedence():
 
 
 def test_env_pin(monkeypatch):
-    monkeypatch.setenv(autotune.ENV_PIN, "4")
-    assert autotune.resolve_tile("ntt", 1, 256, 100) == 4
+    monkeypatch.setenv(autotune.ENV_PIN, "32")
+    assert autotune.resolve_tile("ntt", 1, 256, 100) == 32
     assert autotune.resolve_tile("ntt", 1, 256, 2) == 2    # still clamped
     # explicit argument outranks the pin
     assert autotune.resolve_tile("ntt", 1, 256, 100, tile=16) == 16
@@ -59,8 +68,8 @@ def test_cache_hit_beats_default(monkeypatch):
     monkeypatch.setitem(autotune._MEM, key, 16)
     assert autotune.resolve_tile("ntt", 1, 256, 100) == 16
     # pin still outranks the cache
-    monkeypatch.setenv(autotune.ENV_PIN, "2")
-    assert autotune.resolve_tile("ntt", 1, 256, 100) == 2
+    monkeypatch.setenv(autotune.ENV_PIN, "32")
+    assert autotune.resolve_tile("ntt", 1, 256, 100) == 32
 
 
 def test_no_measurement_without_flag(monkeypatch):
@@ -104,8 +113,7 @@ def test_measure_gated_flag_runs_fake_runner(monkeypatch):
             return np.zeros((1,), np.uint32)
         return run
 
-    fake_clock = iter(range(1000))
-    times = {1: 9.0, 2: 5.0, 4: 1.0, 8: 7.0}
+    times = {8: 9.0, 16: 1.0, 32: 5.0}
 
     def fake_measure_time(run, tile):
         run(tile)
@@ -128,11 +136,11 @@ def test_measure_gated_flag_runs_fake_runner(monkeypatch):
         return best
 
     monkeypatch.setattr(autotune, "measure", shim)
-    got = autotune.resolve_tile("fake_fam", 1, 128, 8)
-    assert got == 4 and calls == [1, 2, 4, 8]
+    got = autotune.resolve_tile("fake_fam", 1, 128, 64)
+    assert got == 16 and calls == [8, 16, 32]
     calls.clear()
     monkeypatch.setattr(autotune, "measure", real_measure)
-    assert autotune.resolve_tile("fake_fam", 1, 128, 8) == 4
+    assert autotune.resolve_tile("fake_fam", 1, 128, 64) == 16
     assert calls == []      # cache hit, no re-measure
 
 
@@ -197,9 +205,9 @@ def test_resolve_uses_per_shard_cache_entry(monkeypatch):
     monkeypatch.setitem(autotune._MEM,
                         (be, "serve_batch", k, n, 8, "uint32"), 16)
     monkeypatch.setitem(autotune._MEM,
-                        (be, "serve_batch", k, n, 32, "uint32"), 2)
+                        (be, "serve_batch", k, n, 32, "uint32"), 24)
     # unsharded resolve sees the global-batch entry...
-    assert autotune.resolve_tile("serve_batch", k, n, 32) == 2
+    assert autotune.resolve_tile("serve_batch", k, n, 32) == 24
     # ...the 4-shard resolve sees the per-shard one (clamped to b=8)
     assert autotune.resolve_tile("serve_batch", k, n, 32, shards=4) == 8
     # ensure() follows the same funnel
@@ -223,16 +231,16 @@ def test_serve_engine_resolves_per_shard_tile(monkeypatch):
     k = len(plan.ctx.qs)
     be = jax.default_backend()
     monkeypatch.setitem(autotune._MEM,
-                        (be, "serve_batch", k, plan.n, 8, "uint32"), 2)
+                        (be, "serve_batch", k, plan.n, 8, "uint32"), 8)
     monkeypatch.setitem(autotune._MEM,
-                        (be, "serve_batch", k, plan.n, 32, "uint32"), 8)
+                        (be, "serve_batch", k, plan.n, 32, "uint32"), 16)
     monkeypatch.setattr(type(plan), "mesh_devices",
                         property(lambda self: 4))
     eng = serve.CkksServeEngine(plan)
     assert eng.devices == 4
-    assert eng.batch_tile == 2          # the b=8 per-shard entry, not b=32
-    assert eng.group_tile == 8          # tile x devices
-    assert eng.max_batch == 32          # 4 x group_tile default
+    assert eng.batch_tile == 8          # the b=8 per-shard entry, not b=32
+    assert eng.group_tile == 32         # tile x devices
+    assert eng.max_batch == 128         # 4 x group_tile default
 
 
 def test_dtype_keys_do_not_collide(monkeypatch):
@@ -243,14 +251,14 @@ def test_dtype_keys_do_not_collide(monkeypatch):
     monkeypatch.setitem(autotune._MEM,
                         (be, "ntt_banks", 1, 256, 64, "uint32"), 32)
     monkeypatch.setitem(autotune._MEM,
-                        (be, "ntt_banks", 1, 256, 64, "uint16"), 4)
+                        (be, "ntt_banks", 1, 256, 64, "uint16"), 16)
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64) == 32
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64,
-                                 dtype="uint16") == 4
+                                 dtype="uint16") == 16
     # a u16 entry alone must NOT satisfy a u32 lookup (or vice versa)
     autotune.clear()
     monkeypatch.setitem(autotune._MEM,
-                        (be, "ntt_banks", 1, 256, 64, "uint16"), 4)
+                        (be, "ntt_banks", 1, 256, 64, "uint16"), 16)
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64) == \
         autotune.DEFAULT_TILE
 
@@ -261,16 +269,16 @@ def test_disk_cache_roundtrips_dtype(tmp_path, monkeypatch):
     monkeypatch.setenv(autotune.ENV_CACHE, str(path))
     be = jax.default_backend()
     autotune._MEM[(be, "ntt_banks", 1, 256, 64, "uint32")] = 16
-    autotune._MEM[(be, "ntt_banks", 1, 256, 64, "uint16")] = 2
+    autotune._MEM[(be, "ntt_banks", 1, 256, 64, "uint16")] = 32
     autotune._save_disk()
     entries = json.loads(path.read_text())["entries"]
     assert entries[f"{be}|ntt_banks|1|256|64|uint32"] == 16
-    assert entries[f"{be}|ntt_banks|1|256|64|uint16"] == 2
+    assert entries[f"{be}|ntt_banks|1|256|64|uint16"] == 32
     autotune.clear()
     autotune._DISK_LOADED = False
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64) == 16
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64,
-                                 dtype="uint16") == 2
+                                 dtype="uint16") == 32
 
 
 def test_disk_cache_old_format_ignored_with_warning(tmp_path, monkeypatch):
@@ -280,7 +288,7 @@ def test_disk_cache_old_format_ignored_with_warning(tmp_path, monkeypatch):
     be = jax.default_backend()
     path.write_text(json.dumps({"entries": {
         f"{be}|ntt_banks|1|256|64": 32,              # old 5-part key
-        f"{be}|ntt_banks|1|256|64|uint16": 2,        # current format
+        f"{be}|ntt_banks|1|256|64|uint16": 16,       # current format
     }}))
     monkeypatch.setenv(autotune.ENV_CACHE, str(path))
     autotune.clear()
@@ -291,7 +299,7 @@ def test_disk_cache_old_format_ignored_with_warning(tmp_path, monkeypatch):
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64) == \
         autotune.DEFAULT_TILE
     assert autotune.resolve_tile("ntt_banks", 1, 256, 64,
-                                 dtype="uint16") == 2
+                                 dtype="uint16") == 16
 
 
 def test_measure_non_u32_caches_default_without_timing(monkeypatch):
@@ -320,8 +328,10 @@ def test_ops_honors_env_pin(monkeypatch):
         return jnp.zeros_like(x2)
 
     monkeypatch.setattr(ntt_kernel, "ntt_fwd_pallas", fake_fwd)
-    monkeypatch.setenv(autotune.ENV_PIN, "2")
+    monkeypatch.setenv(autotune.ENV_PIN, "16")
     rng = np.random.default_rng(7)
-    x = rng.integers(0, p.q, size=(8, 256), dtype=np.uint32)
+    x = rng.integers(0, p.q, size=(32, 256), dtype=np.uint32)
     ops.ntt(x, p, use_pallas=True)
-    assert seen["tile"] == 2
+    assert seen["tile"] == 16
+    ops.ntt(x[:5], p, use_pallas=True)          # pin clamped to the batch
+    assert seen["tile"] == 5

@@ -12,6 +12,7 @@ from jax.experimental import pallas as pl
 
 from repro import kernels
 from repro.core.params import galois_eval_perm, gen_ntt_primes, make_ntt_params
+from repro.core.ringspec import MLKEM_RING, ring_table_pack
 from repro.fhe import batched as FB
 from repro.kernels import ops
 
@@ -62,6 +63,9 @@ def test_all_ops_pallas_paths_compile_on_tpu(monkeypatch):
                                for q in primes]))
     idx = jnp.asarray(galois_eval_perm(5, n, False), jnp.int32)
     idx2 = jnp.stack([idx] * 4)
+    rp = ring_table_pack(MLKEM_RING)
+    xr = jnp.asarray(RNG.integers(0, MLKEM_RING.q, (1, 4, MLKEM_RING.n),
+                                  dtype=np.uint32))
     ext = jnp.asarray(np.stack([np.asarray(xk)] * k))        # (d, k, 4, n)
     evk3 = jnp.asarray(np.stack([np.asarray(xk)[:, 0]] * k))  # (d, k, n)
     w = t["psi"][:k]
@@ -75,16 +79,25 @@ def test_all_ops_pallas_paths_compile_on_tpu(monkeypatch):
         lambda: ops.ntt_banks(xk, t),
         lambda: ops.intt_banks(xk, t),
         lambda: ops.twiddle_mul_banks(xk, w, wp, t["qs"][:k]),
+        lambda: ops.dyadic_inner_banks(ext, evk3, t),
+        lambda: ops.dyadic_inner_banks(ext, ext, t),      # per-batch evk
+        lambda: ops.ntt_banks(xr, rp, negacyclic=False),  # u16 ring
+        lambda: ops.intt_banks(xr, rp, negacyclic=False),
+        lambda: ops.dyadic_basemul_banks(xr, xr, rp),
+    ]
+    # the Galois automorphism is one XLA gather on every backend: it
+    # must run to completion without reaching any Pallas kernel
+    gathers = [
         lambda: ops.galois_banks(xk, idx),
         lambda: ops.galois_banks(xk, idx2),               # per-batch rows
         lambda: ops.galois_digits_banks(ext, idx2),       # hoisted gather
         lambda: ops.galois_digits_banks(ext[:, :, :1], idx2),  # shared mode
-        lambda: ops.dyadic_inner_banks(ext, evk3, t),
-        lambda: ops.dyadic_inner_banks(ext, ext, t),      # per-batch evk
     ]
     for call in calls:
         with pytest.raises(_Captured):
             call()
+    for call in gathers:
+        jax.block_until_ready(call())
     jax.clear_caches()      # drop the poisoned traces before other tests
     assert len(seen) == len(calls)
     assert all(v is False for v in seen), seen

@@ -108,7 +108,7 @@ def test_plan_pallas_equals_ref():
     ref_plan = EvalPlan(ctx, use_pallas=False)
     pal_plan = EvalPlan(ctx, use_pallas=True)
     # multiply covers dyadic + fused keyswitch kernels, rotate adds the
-    # galois gather kernel; rescale's mod_down is pinned pallas-vs-ref in
+    # galois gather; rescale's mod_down is pinned pallas-vs-ref in
     # test_mod_down_banks_matches_host_oracle and conjugate is the same
     # program as rotate (interpret mode is slow — keep this lean)
     for op in (lambda p: p.multiply(ct, ct),
@@ -119,14 +119,17 @@ def test_plan_pallas_equals_ref():
 # ------------------------------------------------- galois_banks kernel
 
 def test_galois_banks_pallas_equals_ref():
+    """The Galois gather is one XLA gather on every backend (no Pallas
+    kernel): shared and per-batch rows == the numpy gather."""
     n, k = 256, 3
     primes = gen_ntt_primes(k, n, bits=30)
     x = np.stack([RNG.integers(0, q, (5, n), dtype=np.uint32) for q in primes])
     idx = galois_eval_perm(5, n, False)
-    got = np.asarray(ops.galois_banks(jnp.asarray(x), idx, use_pallas=True))
-    want = np.asarray(ops.galois_banks(jnp.asarray(x), idx, use_pallas=False))
-    assert np.array_equal(got, want)
-    assert np.array_equal(want, x[:, :, idx])
+    got = np.asarray(ops.galois_banks(jnp.asarray(x), idx))
+    assert np.array_equal(got, x[:, :, idx])
+    rows = np.stack([galois_eval_perm(g, n, False) for g in (5, 25, 7, 5, 3)])
+    got = np.asarray(ops.galois_banks(jnp.asarray(x), rows))
+    assert np.array_equal(got, np.take_along_axis(x, rows[None], axis=-1))
 
 
 @pytest.mark.parametrize("n,natural", [(1 << 10, False), (1 << 13, True)])

@@ -138,7 +138,7 @@ def test_twiddle_mul_banks_kernel():
 
 # ------------------------------------------------------------ properties
 
-@settings(max_examples=6, deadline=None)
+@settings(max_examples=6)
 @given(seed=st.integers(0, 2**31 - 1), n_idx=st.integers(0, len(SIZES) - 1))
 def test_prop_roundtrip(seed, n_idx):
     """Property: intt(ntt(x)) == x for random x, every size and prime."""
@@ -149,7 +149,7 @@ def test_prop_roundtrip(seed, n_idx):
     assert np.array_equal(back, x)
 
 
-@settings(max_examples=6, deadline=None)
+@settings(max_examples=6)
 @given(seed=st.integers(0, 2**31 - 1), c1=st.integers(1, 2**29),
        c2=st.integers(1, 2**29), n_idx=st.integers(0, len(SIZES) - 1))
 def test_prop_linearity(seed, c1, c2, n_idx):
@@ -172,7 +172,7 @@ def test_prop_linearity(seed, c1, c2, n_idx):
     assert np.array_equal(lhs, lin(fx, fy))
 
 
-@settings(max_examples=1, deadline=None)
+@settings(max_examples=1)
 @given(seed=st.integers(0, 2**31 - 1))
 def test_prop_polymul_schoolbook(seed):
     """Property: negacyclic polymul through the four-step pipeline ==

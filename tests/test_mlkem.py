@@ -174,6 +174,25 @@ def test_u16_banks_pallas_equals_ref():
         assert bp.dtype == np.uint16 and np.array_equal(bp, br), f"{lazy=}"
 
 
+def test_u16_ring_on_u32_lanes_matches_u16_rows():
+    """The ML-KEM host path hands the kernels u32 rows of the u16 ring:
+    every banks entry point returns the same values as on u16 rows, in
+    the caller's u32 dtype, on both dispatch paths."""
+    t = ring_table_pack(MLKEM_RING)
+    x16, y16 = _rows(9)[None], _rows(9)[None]
+    x32, y32 = x16.astype(np.uint32), y16.astype(np.uint32)
+    for up in (False, True):
+        fns = [lambda v, w: ops.ntt_banks(v, t, negacyclic=False,
+                                          use_pallas=up),
+               lambda v, w: ops.intt_banks(v, t, negacyclic=False,
+                                           use_pallas=up),
+               lambda v, w: ops.dyadic_basemul_banks(v, w, t, use_pallas=up)]
+        for fn in fns:
+            got = np.asarray(fn(jnp.asarray(x32), jnp.asarray(y32)))
+            want = np.asarray(fn(jnp.asarray(x16), jnp.asarray(y16)))
+            assert got.dtype == np.uint32 and np.array_equal(got, want), up
+
+
 # --------------------------------------------- 16-bit modmath lane contract
 
 _EDGES = np.array([0, 1, Q - 1, Q, 2 * Q - 1], dtype=np.uint16)

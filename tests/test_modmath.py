@@ -57,7 +57,7 @@ def test_montgomery_mulmod():
     assert np.array_equal(got, mm.mulmod_np(a, b, Q))
 
 
-@settings(max_examples=200, deadline=None)
+@settings(max_examples=200)
 @given(x=st.integers(0, 2**32 - 1), w=st.integers(0, Q - 1))
 def test_shoup_property(x, w):
     """Shoup accepts ANY u32 x (lazy inputs), result fully reduced."""
@@ -66,7 +66,7 @@ def test_shoup_property(x, w):
     assert got == (x * w) % Q
 
 
-@settings(max_examples=100, deadline=None)
+@settings(max_examples=100)
 @given(a=st.integers(0, Q - 1), b=st.integers(0, Q - 1))
 def test_all_multipliers_agree(a, b):
     mu = mm.barrett_precompute(Q)
@@ -96,7 +96,7 @@ def test_barrett_other_primes(bits):
 # hand unreduced values across stage boundaries, so the representative
 # itself is part of the pinned behavior.
 
-@settings(max_examples=200, deadline=None)
+@settings(max_examples=200)
 @given(a=st.integers(0, 2 * Q - 1), b=st.integers(0, 2 * Q - 1))
 def test_lazy_addsub_property(a, b):
     ga = int(mm.lazy_addmod(jnp.uint32(a), jnp.uint32(b), jnp.uint32(Q)))
@@ -107,7 +107,7 @@ def test_lazy_addsub_property(a, b):
     assert gs == int(mm.lazy_submod_np(a, b, Q))
 
 
-@settings(max_examples=200, deadline=None)
+@settings(max_examples=200)
 @given(x=st.integers(0, 2**32 - 1), w=st.integers(0, Q - 1))
 def test_shoup_lazy_property(x, w):
     """mulmod_shoup_lazy accepts ANY u32 x and lands in [0, 2q)."""
@@ -118,7 +118,7 @@ def test_shoup_lazy_property(x, w):
     assert got == int(mm.mulmod_shoup_lazy_np(x, w, Q))
 
 
-@settings(max_examples=200, deadline=None)
+@settings(max_examples=200)
 @given(a=st.integers(0, Q - 1), b=st.integers(0, Q - 1))
 def test_barrett_lazy_property(a, b):
     mu = mm.barrett_precompute(Q)

@@ -88,7 +88,7 @@ def test_multiple_primes(bits):
         assert np.array_equal(back, a)
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(st.integers(0, 2**31))
 def test_property_impulse(seed):
     """NTT of a scaled unit impulse at 0 is constant; property holds for

@@ -334,13 +334,14 @@ def test_autotune_measure_records_evidence(monkeypatch):
     monkeypatch.delenv(autotune.ENV_PIN, raising=False)
     monkeypatch.delenv(autotune.ENV_CACHE, raising=False)
     autotune.clear()
-    got = autotune.measure("ntt", 1, 64, 4, reps=1)
-    key = (autotune._backend(), "ntt", 1, 64, 4, "uint32")
+    got = autotune.measure("ntt", 1, 64, 32, reps=1)
+    key = (autotune._backend(), "ntt", 1, 64, 32, "uint32")
     ev = autotune._EVIDENCE[key]
     assert ev["chosen"] == got
     assert ev["source"] == "measured"
-    # every runnable candidate tile <= b carries a median-seconds entry
-    assert set(ev["candidates"]) == {1, 2, 4}
+    # every legal candidate tile (multiple of 8, <= b) carries a
+    # median-seconds entry
+    assert set(ev["candidates"]) == {8, 16, 32}
     assert all(s > 0 for s in ev["candidates"].values())
     tab = autotune.table()
     ks = "|".join(str(p) for p in key)

@@ -68,6 +68,24 @@ def test_mesh_of_one_is_sharded_and_counts_as_one_device(plans):
     assert len(EV._SHARDED_PROGRAMS) >= 5
 
 
+def test_mesh_of_one_pallas_kernels_inside_shard_map(ctx, plans):
+    """The kernel path inside the shard_map twins (what a TPU mesh runs):
+    pallas_calls that mix replicated tables with batch-sharded blocks
+    must trace there.  Interpret-mode kernels under a mesh of one == the
+    plain plan, bit for bit."""
+    plain, _ = plans
+    sharded = EvalPlan(ctx, use_pallas=True,
+                       mesh=compat.make_mesh((1,), ("b",)))
+    cts = [_enc(ctx) for _ in range(2)]
+    bts = [_enc(ctx) for _ in range(2)]
+    for a, b in zip(plain.multiply_many(cts, bts),
+                    sharded.multiply_many(cts, bts)):
+        _same_ct(a, b)
+    for a, b in zip(plain.rotate_many(cts, [1, 2]),
+                    sharded.rotate_many(cts, [1, 2])):
+        _same_ct(a, b)
+
+
 def test_mesh_of_one_batched_ops_bit_exact(ctx, plans):
     plain, sharded = plans
     cts = [_enc(ctx) for _ in range(5)]
