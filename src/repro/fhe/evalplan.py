@@ -741,19 +741,30 @@ class EvalPlan:
         """(k, k+1, B, n) per-ciphertext key stacks + (B, n) gather rows
         for a mixed-automorphism batch, cached per (gs, basis) — a
         steady-state serving pattern re-dispatches the same g sequence,
-        and restacking B full key tensors per call is pure waste."""
+        and restacking B full key tensors per call is pure waste.
+
+        Each lookup is a ``plan.batch_key`` span (``hit``, ``b``; on a
+        miss ``resident_bytes``, the cache's summed ``nbytes`` after the
+        insert, from the arrays' shapes), read by the benchmark's
+        ``plan.batch_key_miss_pct``; a miss's span times the restack."""
         key = (gs, basis)
-        if key in self._batch_keys:
-            # LRU touch: steady-state patterns stay resident
-            self._batch_keys[key] = self._batch_keys.pop(key)
-        else:
-            if len(self._batch_keys) >= self._BATCH_KEY_CACHE_MAX:
-                self._batch_keys.pop(next(iter(self._batch_keys)))
-            keys = [self.galois_key(g, basis) for g in gs]
-            self._batch_keys[key] = (
-                jnp.stack([kb for kb, _ in keys], axis=2),   # (k, k+1, B, n)
-                jnp.stack([ka for _, ka in keys], axis=2),
-                jnp.stack([self.eval_idx(g) for g in gs]))
+        hit = key in self._batch_keys
+        with obs.span("plan.batch_key", hit=hit, b=len(gs)) as sp:
+            if hit:
+                # LRU touch: steady-state patterns stay resident
+                self._batch_keys[key] = self._batch_keys.pop(key)
+            else:
+                if len(self._batch_keys) >= self._BATCH_KEY_CACHE_MAX:
+                    self._batch_keys.pop(next(iter(self._batch_keys)))
+                keys = [self.galois_key(g, basis) for g in gs]
+                self._batch_keys[key] = (
+                    jnp.stack([kb for kb, _ in keys], axis=2),  # (k, k+1, B, n)
+                    jnp.stack([ka for _, ka in keys], axis=2),
+                    jnp.stack([self.eval_idx(g) for g in gs]))
+                if obs.enabled():
+                    sp.annotate(resident_bytes=sum(
+                        x.nbytes for entry in self._batch_keys.values()
+                        for x in entry))
         return self._batch_keys[key]
 
     def eval_idx(self, g: int) -> jnp.ndarray:

@@ -240,13 +240,29 @@ class CkksServeEngine:
     drain so consumers never KeyError), and the async drain adds
     ``max_queue`` (peak pending depth).
 
-    With ``repro.obs`` enabled, every drain additionally records phase
-    spans (``serve.screen`` / ``serve.group`` / ``serve.dispatch`` /
-    ``serve.block`` nested under ``serve.run``), queue-depth gauge
-    samples per admission cycle, and per-request lifecycle histograms
-    (arrival -> admitted -> grouped -> dispatched -> drained) into the
-    global metrics registry; disabled (the default), each probe is a
-    single flag check."""
+    With ``repro.obs`` enabled, every drain additionally records:
+
+      * phase spans ``serve.screen`` / ``serve.group`` /
+        ``serve.dispatch`` / ``serve.block`` nested under ``serve.run``
+        (the benchmark's trace reduction names device idle gaps by
+        them).  Each ``serve.dispatch`` carries ``batch=``, an id unique
+        per engine; a matvec group's composites run inside one too.
+      * one ``serve.request`` event per resolved request, back-dated
+        from its due time (``t0`` + its arrival, ``t0`` for a backlog)
+        to its answer being ready (``_block_outs`` returned for its
+        group), with args ``rid``, ``kind`` (the op), ``admitted_us``
+        and ``dispatched_us`` (offsets from due: admission by the loop,
+        return of its group's dispatch) and ``batch``.  A request
+        resolved at admission (identity or failed) has all four times at
+        its admission and ``batch`` None; a matvec that fails inside its
+        group is ready when the group returns.  Read by the benchmark's
+        ``serve.admit_lag_p99_ms``, ``serve.queue_ms`` and
+        ``serve.inflight_ms``, and by its clock module, which lays
+        [due, dispatched) over the device's idle stretches.
+      * counters mirroring ``stats`` (``serve.*``).
+
+    Disabled (the default), each probe is one flag check per drain
+    cycle, and no per-request record is kept."""
 
     def __init__(self, plan: EvalPlan, batch_tile: int | None = None,
                  max_batch: int | None = None):
@@ -275,6 +291,10 @@ class CkksServeEngine:
             raise ValueError(f"max_batch {self.max_batch} < batch_tile "
                              f"{batch_tile} x {self.devices} device(s)")
         self.stats: dict = {}
+        self._batches = 0       # serve.dispatch batch ids
+        # rid -> [due, admitted, dispatched, batch] while a traced drain
+        # runs, None otherwise
+        self._life: dict | None = None
 
     # ------------------------------------------------------------ policy
 
@@ -349,7 +369,12 @@ class CkksServeEngine:
         return (2 * self.plan.n - 1 if req.op == "conjugate"
                 else self.plan.rotation_group_element(req.r))
 
-    def _dispatch(self, kind: str, reqs: list) -> list:
+    def _next_batch(self) -> int:
+        self._batches += 1
+        return self._batches
+
+    def _dispatch(self, kind: str, reqs: list,
+                  batch: int | None = None) -> list:
         plan = self.plan
         schemes = {"mlkem" if r.op in MLKEM_OPS else "ckks" for r in reqs}
         if len(schemes) > 1:
@@ -360,7 +385,7 @@ class CkksServeEngine:
             raise ValueError(
                 f"_dispatch: cross-scheme batch {sorted(schemes)} — "
                 f"CKKS and ML-KEM requests never batch together")
-        with obs.span("serve.dispatch", kind=kind, n=len(reqs)):
+        with obs.span("serve.dispatch", kind=kind, n=len(reqs), batch=batch):
             reqs = _pad(reqs, self.group_tile)
             if kind in MLKEM_OPS:
                 return self._mlkem_dispatch(kind, reqs)
@@ -407,7 +432,8 @@ class CkksServeEngine:
                                    if isinstance(ct, Ciphertext)
                                    for x in (ct.c0.data, ct.c1.data)])
 
-    def _matvec_group(self, reqs: list, failed: dict):
+    def _matvec_group(self, reqs: list, failed: dict,
+                      batch: int | None = None):
         """Per-request matvec composites (no tile padding).  ANY
         exception a request raises — the documented ValueErrors
         (basis-validity, empty pack) but also a poisoned pack's
@@ -416,15 +442,54 @@ class CkksServeEngine:
         ``PtMatrix`` sank the whole batch and discarded every other
         client's answer."""
         kept, outs = [], []
-        for req in reqs:
-            try:
-                outs.append(linalg.matvec(self.plan, req.matrix, req.ct))
-                kept.append(req)
-            except ValueError as e:
-                failed[req.rid] = str(e)
-            except Exception as e:       # noqa: BLE001 — isolate the batch
-                failed[req.rid] = f"{type(e).__name__}: {e}"
+        with obs.span("serve.dispatch", kind="matvec", n=len(reqs),
+                      batch=batch):
+            for req in reqs:
+                try:
+                    outs.append(linalg.matvec(self.plan, req.matrix, req.ct))
+                    kept.append(req)
+                except ValueError as e:
+                    failed[req.rid] = str(e)
+                except Exception as e:   # noqa: BLE001 — isolate the batch
+                    failed[req.rid] = f"{type(e).__name__}: {e}"
         return kept, outs
+
+    # -------------------------------------------------------- lifecycle
+    # Only called while a traced drain keeps ``self._life``: once per
+    # admission cycle, dispatched group and drained group.
+
+    @staticmethod
+    def _emit(rid: int, op: str, due: float, admitted: float,
+              dispatched: float, ready: float, batch) -> None:
+        obs.record("serve.request", due, ready, rid=rid, kind=op,
+                   admitted_us=(admitted - due) * 1e6,
+                   dispatched_us=(dispatched - due) * 1e6, batch=batch)
+
+    def _admit(self, arrived, t0: float, t_adm: float, resolved) -> None:
+        """Stamp one admission cycle's ``(arrival, request)`` pairs; a
+        request already in ``resolved`` is over at its admission."""
+        for a, req in arrived:
+            if req.rid in resolved:
+                self._emit(req.rid, req.op, t_adm, t_adm, t_adm, t_adm, None)
+            else:
+                self._life[req.rid] = [t0 + a, t_adm]
+
+    def _dispatched(self, taken: list, failed: dict, batch: int) -> None:
+        """Stamp a group's dispatch; its requests that failed inside the
+        group are ready at the same moment."""
+        t = time.perf_counter()
+        for req in taken:
+            rec = self._life[req.rid]
+            rec += (t, batch)
+            if req.rid in failed:
+                self._emit(req.rid, req.op, *rec[:3], t, batch)
+                del self._life[req.rid]
+
+    def _ready(self, reqs: list) -> None:
+        t = time.perf_counter()
+        for req in reqs:
+            due, adm, disp, batch = self._life.pop(req.rid)
+            self._emit(req.rid, req.op, due, adm, disp, t, batch)
 
     # ------------------------------------------------------- accounting
 
@@ -463,9 +528,6 @@ class CkksServeEngine:
         lats = [(done_t[rid] - arr_t.get(rid, 0.0)) * 1e6 for rid in done_t]
         if not lats:
             return {}
-        if obs.enabled():
-            for v in lats:
-                obs.observe("serve.lifecycle.drained_us", v)
         q = np.percentile(lats, (50, 99))
         return {
             "p50": float(q[0]), "p99": float(q[1]),
@@ -494,7 +556,6 @@ class CkksServeEngine:
                 obs.counter_add(f"serve.{c}", stats[c])
             obs.counter_add("serve.failed", len(stats["failed"]))
             obs.counter_add("serve.drains")
-            obs.observe("serve.drain.wall_us", stats["wall_s"] * 1e6)
         # everything is drained now, so parked donated stacks (see
         # evalplan.retire_donated) can be dropped without blocking
         release_retired()
@@ -520,6 +581,7 @@ class CkksServeEngine:
         t0 = time.perf_counter()
         before = dict(self.plan.stats)
         traces_before = self.plan.trace_count()
+        self._life = {} if obs.enabled() else None
         with obs.span("serve.run", mode="sync", n=len(requests)):
             groups, out, failed = self._group(requests)
             stats = self._init_stats("sync", failed)
@@ -530,6 +592,9 @@ class CkksServeEngine:
             # on a backlog trace — serve.py S1)
             now = time.perf_counter() - t0
             done_t = {rid: now for rid in (*out, *failed)}
+            if self._life is not None:
+                self._admit([(0.0, r) for r in requests], t0, t0 + now,
+                            done_t)
             for (kind, basis), reqs in sorted(
                     groups.items(), key=lambda kv: -len(kv[1])):
                 if kind == "galois":
@@ -539,15 +604,20 @@ class CkksServeEngine:
                     # arrival order — arrival-ordered patterns would
                     # miss that cache almost every dispatch
                     reqs = sorted(reqs, key=self._g_of)
+                taken, batch = reqs, self._next_batch()
                 if kind == "matvec":
-                    reqs, outs = self._matvec_group(reqs, failed)
-                    if not reqs:
-                        continue   # every request failed: nothing dispatched
+                    reqs, outs = self._matvec_group(reqs, failed, batch)
                 else:
-                    outs = self._dispatch(kind, reqs)
+                    outs = self._dispatch(kind, reqs, batch)
+                if self._life is not None:
+                    self._dispatched(taken, failed, batch)
+                if not reqs:
+                    continue       # every matvec failed: nothing dispatched
                 # the drain discipline: fully synchronize this group
                 # before staging the next (run_async defers exactly this)
                 self._block_outs(outs)
+                if self._life is not None:
+                    self._ready(reqs)
                 done = time.perf_counter() - t0
                 for req, ct in zip(reqs, outs):  # zip drops pad rows
                     out[req.rid] = ct
@@ -557,6 +627,7 @@ class CkksServeEngine:
             for rid in failed:     # matvec failures surface mid-drain
                 done_t.setdefault(rid, now)
             stats["latency_us"] = self._latency_summary({}, done_t)
+        self._life = None
         self._finish_stats(stats, before, traces_before, t0)
         return out
 
@@ -588,6 +659,8 @@ class CkksServeEngine:
         """Block on an in-flight batch and deliver its answers."""
         kind, reqs, outs = batch
         self._block_outs(outs)
+        if self._life is not None:
+            self._ready(reqs)
         done = time.perf_counter() - t0
         for req, ct in zip(reqs, outs):          # zip drops pad rows
             out[req.rid] = ct
@@ -637,49 +710,38 @@ class CkksServeEngine:
         pending: deque = deque()
         inflight = None                 # (kind, reqs, outs) — ONE batch
         i = 0                           # next unadmitted arrival
-        # per-request lifecycle timestamps (arrival -> admitted ->
-        # grouped -> dispatched -> drained) feed the obs registry's
-        # histograms; tracked only when observability is on
-        track = obs.enabled()
-        adm_t: dict[int, float] = {}
-        grp_t: dict[int, float] = {}
-        disp_t: dict[int, float] = {}
+        self._life = {} if obs.enabled() else None
 
         run_span = obs.span("serve.run", mode="async", n=n)
         with run_span:
             while i < n or pending or inflight:
                 now = time.perf_counter() - t0
                 if i < n and sched[i][0] <= now:
+                    i0 = i
                     with obs.span("serve.screen"):
                         while i < n and sched[i][0] <= now:
                             a, req = sched[i]
                             i += 1
                             if self._screen(req, out, failed):
                                 pending.append(req)
-                                if track:
-                                    adm_t[req.rid] = now
                             else:       # resolved at admission
                                 done_t[req.rid] = now
                                 if req.rid in out:
                                     stats["identity"] += 1
+                    if self._life is not None:
+                        self._admit(sched[i0:i], t0, t0 + now, done_t)
                 stats["max_queue"] = max(stats["max_queue"], len(pending))
-                obs.gauge_set("serve.queue_depth", len(pending))
                 if pending:
                     kind, reqs = self._take_group(pending)
-                    if track:
-                        tg = time.perf_counter() - t0
-                        for req in reqs:
-                            grp_t[req.rid] = tg
                     if kind == "galois":
                         reqs = sorted(reqs, key=self._g_of)  # canonical g
+                    taken, batch = reqs, self._next_batch()
                     if kind == "matvec":
-                        reqs, outs = self._matvec_group(reqs, failed)
+                        reqs, outs = self._matvec_group(reqs, failed, batch)
                     else:
-                        outs = self._dispatch(kind, reqs)
-                    if track and reqs:
-                        td = time.perf_counter() - t0
-                        for req in reqs:
-                            disp_t[req.rid] = td
+                        outs = self._dispatch(kind, reqs, batch)
+                    if self._life is not None:
+                        self._dispatched(taken, failed, batch)
                     # ping-pong: the new batch is in flight BEFORE we
                     # block on the old one — its compute hides this
                     # cycle's host screening/stacking, the next cycle's
@@ -698,21 +760,7 @@ class CkksServeEngine:
                     wait = sched[i][0] - (time.perf_counter() - t0)
                     if wait > 0:
                         time.sleep(min(wait, 5e-4))
-            if track:
-                for rid, td in done_t.items():
-                    a = arr_t.get(rid, 0.0)
-                    ta = adm_t.get(rid)
-                    if ta is not None:
-                        obs.observe("serve.lifecycle.admitted_us",
-                                    (ta - a) * 1e6)
-                        tg = grp_t.get(rid)
-                        if tg is not None:
-                            obs.observe("serve.lifecycle.grouped_us",
-                                        (tg - ta) * 1e6)
-                            td2 = disp_t.get(rid)
-                            if td2 is not None:
-                                obs.observe("serve.lifecycle.dispatched_us",
-                                            (td2 - tg) * 1e6)
             stats["latency_us"] = self._latency_summary(arr_t, done_t)
+        self._life = None
         self._finish_stats(stats, before, traces_before, t0)
         return out

@@ -182,7 +182,8 @@ def _spanned(fn):
     span records trace-time host work, not device compute — still the
     right thing to see on the timeline, since retracing inside a
     latency window IS the cost being hunted.  Disabled, the wrapper is
-    one flag check (the overhead CI gates)."""
+    one flag check.  The benchmark's ``mlkem.host_us_per_req`` subtracts
+    these spans from their ``mlkem.*`` parents."""
     name = f"ops.{fn.__name__}"
 
     @functools.wraps(fn)

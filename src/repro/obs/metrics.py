@@ -1,51 +1,33 @@
-"""Process-wide metrics registry: counters, gauges, log-bucketed
-latency histograms.
+"""Process-wide metrics registry: counters and gauges.
 
 The registry *backs* the serve / EvalPlan ``stats`` dicts rather than
 replacing them: the dicts keep their exact keys and values (dozens of
 tests pin them bit-for-bit), and the instrumented layers mirror the
-same increments here — plus the things a flat dict cannot hold:
-per-phase latency histograms (every ``obs.span`` feeds one on exit),
-queue-depth gauge samples over the async drain, per-request lifecycle
-deltas, and the autotuner's candidate evidence.
+same increments here as counters (``serve.*``, ``plan.*``,
+``autotune.resolve.*``); the autotuner records its candidate evidence
+as gauges.  ``write_metrics`` writes the snapshot beside a Perfetto
+trace.
 
 Like the tracer, everything is gated on ``obs.enabled()`` — a disabled
 registry call is one flag check and a return, so mirroring can live
-permanently on the hot paths (CI gates the enabled overhead).
+permanently on the hot paths.
 
-Histogram buckets are powers of two with an INCLUSIVE upper bound: a
-value ``v`` lands in the smallest bucket ``2**m >= v`` (4.0 -> bucket
-4.0, 4.0001 -> bucket 8.0; v <= 0 -> bucket 0.0).  Log buckets keep the
-registry allocation-bounded under any latency distribution — serve
-latencies span ~6 decades between a host-side identity short-circuit
-and a cold matvec composite.
-
-All operations take one coarse lock; these are µs-granularity phase
-metrics, not per-sample nanosecond counters, so contention is nil.
+All operations take one coarse lock; these are per-drain counters, not
+per-sample nanosecond counters, so contention is nil.
 """
 from __future__ import annotations
 
-import math
 import threading
 from collections import deque
 
 from repro.obs import trace as _trace
 
-# gauge sample history per gauge (timestamped; the async drain samples
-# queue depth once per admission cycle, so bound it)
+# gauge sample history per gauge (timestamped, bounded)
 MAX_GAUGE_SAMPLES = 4096
 
 _LOCK = threading.Lock()
 _COUNTERS: dict[str, float] = {}
 _GAUGES: dict[str, dict] = {}       # name -> {"value": v, "samples": deque}
-_HISTS: dict[str, dict] = {}        # name -> {"buckets", "count", "sum", ...}
-
-
-def bucket_le(v: float) -> float:
-    """The inclusive upper bound of the log2 bucket ``v`` falls in."""
-    if v <= 0.0:
-        return 0.0
-    return 2.0 ** math.ceil(math.log2(v))
 
 
 def counter_add(name: str, n: float = 1) -> None:
@@ -71,41 +53,6 @@ def gauge_set(name: str, value: float) -> None:
         g["samples"].append((ts_us, value))
 
 
-def observe(name: str, v: float) -> None:
-    """Record one sample into the log-bucketed histogram ``name``."""
-    if not _trace._ENABLED:
-        return
-    with _LOCK:
-        h = _HISTS.get(name)
-        if h is None:
-            h = _HISTS[name] = {"buckets": {}, "count": 0, "sum": 0.0,
-                                "min": float("inf"), "max": float("-inf")}
-        le = bucket_le(v)
-        h["buckets"][le] = h["buckets"].get(le, 0) + 1
-        h["count"] += 1
-        h["sum"] += v
-        if v < h["min"]:
-            h["min"] = v
-        if v > h["max"]:
-            h["max"] = v
-
-
-def histogram_quantile(name: str, q: float) -> float | None:
-    """Bucket-resolution quantile estimate (returns the upper bound of
-    the bucket holding the q-quantile sample), or None if empty."""
-    with _LOCK:
-        h = _HISTS.get(name)
-        if h is None or h["count"] == 0:
-            return None
-        target = q * h["count"]
-        seen = 0
-        for le in sorted(h["buckets"]):
-            seen += h["buckets"][le]
-            if seen >= target:
-                return le
-        return max(h["buckets"])
-
-
 def snapshot() -> dict:
     """JSON-ready copy of the whole registry (the metrics artifact
     ``benchmarks/run.py --trace-out`` writes next to the trace)."""
@@ -116,20 +63,7 @@ def snapshot() -> dict:
                    "samples": [list(s) for s in g["samples"]]}
             for name, g in _GAUGES.items()
         }
-        hists = {}
-        for name, h in _HISTS.items():
-            n = h["count"]
-            hists[name] = {
-                "count": n,
-                "sum": h["sum"],
-                "mean": (h["sum"] / n) if n else 0.0,
-                "min": h["min"] if n else None,
-                "max": h["max"] if n else None,
-                # string keys: JSON objects cannot key on floats
-                "buckets": {repr(le): c
-                            for le, c in sorted(h["buckets"].items())},
-            }
-    return {"counters": counters, "gauges": gauges, "histograms": hists}
+    return {"counters": counters, "gauges": gauges}
 
 
 def reset() -> None:
@@ -137,4 +71,3 @@ def reset() -> None:
     with _LOCK:
         _COUNTERS.clear()
         _GAUGES.clear()
-        _HISTS.clear()

@@ -1,22 +1,19 @@
 """Nestable, thread-safe span tracer on monotonic clocks.
 
-The paper validates SCE-NTT with cycle-accurate JoSIM/Verilog traces of
-every pipeline stage; this module is the software reproduction's
-equivalent instrument: ``span("serve.dispatch", kind=..., n=...)`` is a
-context manager that records a (name, start, duration, thread, depth,
-args) event into a bounded global buffer, which ``obs.export`` renders
-as Chrome trace-event JSON (Perfetto-loadable) so a serve drain's wall
-time decomposes into its screen / group / stack / dispatch / block
-phases on a real timeline.
+``span("serve.dispatch", kind=..., n=...)`` is a context manager that
+records a (name, start, duration, thread, depth, args) event into a
+bounded global buffer; ``record(name, start_s, end_s, ...)`` records
+one back-dated event whose start and end the caller took earlier on the
+same clock (``time.perf_counter``).  ``obs.export`` renders the buffer
+as Chrome trace-event JSON (Perfetto-loadable); the benchmark
+(``bench/metrics``) reads it through ``events()``.
 
 Design constraints, in order:
 
-  * **Near-zero cost when disabled (the default).**  ``span()`` checks
+  * **One flag check when disabled (the default).**  ``span()`` checks
     one module-level flag and returns a shared no-op singleton — no
-    object allocation, no clock read, no lock.  CI gates the enabled
-    path at >= 0.95x disabled throughput on the serve bench
-    (``benchmarks/check_smoke.py``), so instrumentation can stay on the
-    hot paths permanently instead of rotting behind #ifdefs.
+    object allocation, no clock read, no lock; ``record()`` returns at
+    the same check.  So the probes live on the hot paths permanently.
   * **Thread-safe nesting.**  Each thread keeps its own span stack
     (depth makes the Perfetto rows nest); the event buffer is a
     ``deque(maxlen=...)`` appended under a lock only at span EXIT, so
@@ -28,15 +25,13 @@ Design constraints, in order:
   * **Monotonic clocks.**  ``time.perf_counter_ns`` throughout;
     timestamps are microseconds relative to the module's load epoch
     (Chrome trace-event ``ts``/``dur`` are µs).
-  * **Dependency-free, jax-optional.**  When ``enable(forward_to_jax=
-    True)`` is set and jax is importable, each span also enters a
-    ``jax.profiler.TraceAnnotation`` so host spans correlate with XLA
-    device traces when a profiler session is active; the import is
-    guarded and the default is off (TraceAnnotation costs ~µs/span).
-
-Spans double as latency samples: on exit, the duration is fed to the
-metrics registry's log-bucketed histogram ``<name>.us`` — every
-instrumented phase gets a per-phase histogram for free.
+  * **Dependency-free, jax-optional.**  ``enable(forward_to_jax=True)``
+    resolves ``jax.profiler.TraceAnnotation`` once; every span then also
+    enters one, so host spans land in an active profiler session beside
+    the device ops.  Each event says whether it was forwarded
+    (``forwarded``): those are the spans that both clocks saw, from which
+    ``bench/harness/clock.py`` puts the buffer on the profile's clock.
+    Back-dated events are never forwarded.
 """
 from __future__ import annotations
 
@@ -49,7 +44,7 @@ from collections import deque
 MAX_EVENTS = 262_144
 
 _ENABLED = False
-_FORWARD_TO_JAX = False
+_ANNOTATION = None      # jax.profiler.TraceAnnotation while forwarding
 _EVENTS: deque = deque(maxlen=MAX_EVENTS)
 _LOCK = threading.Lock()
 _TLS = threading.local()
@@ -64,16 +59,23 @@ def enabled() -> bool:
 def enable(*, forward_to_jax: bool = False) -> None:
     """Turn span recording on process-wide.  ``forward_to_jax=True``
     additionally wraps every span in ``jax.profiler.TraceAnnotation``
-    so host spans show up inside an active XLA device profile."""
-    global _ENABLED, _FORWARD_TO_JAX
-    _FORWARD_TO_JAX = bool(forward_to_jax)
+    (resolved here, once) so host spans show up inside an active XLA
+    device profile; without jax the spans are recorded unforwarded."""
+    global _ENABLED, _ANNOTATION
+    _ANNOTATION = None
+    if forward_to_jax:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:         # jax absent / profiler API moved
+            pass
     _ENABLED = True
 
 
 def disable() -> None:
-    global _ENABLED, _FORWARD_TO_JAX
+    global _ENABLED, _ANNOTATION
     _ENABLED = False
-    _FORWARD_TO_JAX = False
+    _ANNOTATION = None
 
 
 def clear() -> None:
@@ -86,12 +88,13 @@ def clear() -> None:
 
 def events() -> list[dict]:
     """Snapshot the event buffer as a list of plain dicts (oldest
-    first): name, cat, ts_us, dur_us, tid, depth, args."""
+    first): name, cat, ts_us, dur_us, tid, depth, args, forwarded (the
+    span also entered a profiler ``TraceAnnotation``)."""
     with _LOCK:
         return [
             {"name": name, "cat": cat, "ts_us": ts, "dur_us": dur,
-             "tid": tid, "depth": depth, "args": args}
-            for (name, cat, ts, dur, tid, depth, args) in _EVENTS
+             "tid": tid, "depth": depth, "args": args, "forwarded": fwd}
+            for (name, cat, ts, dur, tid, depth, args, fwd) in _EVENTS
         ]
 
 
@@ -119,8 +122,20 @@ class _NoopSpan:
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def annotate(self, **args):
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
+
+
+def _append(name, cat, ts_us, dur_us, depth, args, forwarded) -> None:
+    global _DROPPED
+    with _LOCK:
+        if len(_EVENTS) == MAX_EVENTS:
+            _DROPPED += 1
+        _EVENTS.append((name, cat, ts_us, dur_us, threading.get_ident(),
+                        depth, args, forwarded))
 
 
 class _Span:
@@ -138,40 +153,29 @@ class _Span:
         stack = _stack()
         self.depth = len(stack)
         stack.append(self)
-        if _FORWARD_TO_JAX:
-            try:
-                import jax
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:       # jax absent / profiler API moved
-                self._jax_ctx = None
+        if _ANNOTATION is not None:
+            self._jax_ctx = _ANNOTATION(self.name)
+            self._jax_ctx.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        global _DROPPED
         t1 = time.perf_counter_ns()
         if self._jax_ctx is not None:
-            try:
-                self._jax_ctx.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
+            self._jax_ctx.__exit__(exc_type, exc, tb)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
         if exc_type is not None:
             self.args = dict(self.args, error=exc_type.__name__)
-        ts_us = (self.t0 - _EPOCH_NS) / 1e3
-        dur_us = (t1 - self.t0) / 1e3
-        with _LOCK:
-            if len(_EVENTS) == MAX_EVENTS:
-                _DROPPED += 1
-            _EVENTS.append((self.name, self.cat, ts_us, dur_us,
-                            threading.get_ident(), self.depth, self.args))
-        # every span is also a latency sample for its phase histogram
-        from repro.obs import metrics
-        metrics.observe(f"{self.name}.us", dur_us)
+        _append(self.name, self.cat, (self.t0 - _EPOCH_NS) / 1e3,
+                (t1 - self.t0) / 1e3, self.depth, self.args,
+                self._jax_ctx is not None)
         return False                # never swallow the exception
+
+    def annotate(self, **args):
+        """Add args learned inside the span (its event carries them)."""
+        self.args = dict(self.args, **args)
 
 
 def span(name: str, cat: str = "repro", **args):
@@ -181,3 +185,16 @@ def span(name: str, cat: str = "repro", **args):
     if not _ENABLED:
         return NOOP_SPAN
     return _Span(name, cat, args)
+
+
+def record(name: str, start_s: float, end_s: float, cat: str = "repro",
+           **args) -> None:
+    """Record one complete event that started at ``start_s`` and ended
+    at ``end_s``, both ``time.perf_counter()`` readings taken earlier:
+    for phases that are only known to be over after the fact (a served
+    request's lifecycle).  Nothing happens when tracing is disabled."""
+    if not _ENABLED:
+        return
+    ts_us = (start_s * 1e9 - _EPOCH_NS) / 1e3
+    _append(name, cat, ts_us, (end_s - start_s) * 1e6, len(_stack()), args,
+            False)

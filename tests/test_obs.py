@@ -1,15 +1,15 @@
 """The observability layer (``repro.obs``): span tracer semantics,
-metrics-registry bucket rules, exporter golden format, the disabled
-fast path, and the serve-stats compatibility contract the registry
-mirrors (never replaces).
+back-dated events, forwarding to the profiler, exporter golden format,
+the disabled fast path, and the serve-stats compatibility contract the
+registry mirrors (never replaces).
 
 The key invariants:
 
   * spans nest per thread (depth), survive exceptions (the event is
     recorded WITH an error tag and the exception propagates), and
     interleave safely across threads;
-  * log2 histogram buckets have an INCLUSIVE upper bound (4.0 lands in
-    bucket 4.0; 4.0001 in 8.0);
+  * ``record`` lays a back-dated event on the spans' clock, and every
+    event says whether it also reached the profiler (``forwarded``);
   * the Chrome trace export round-trips through ``json.loads`` with the
     ``ph``/``ts``/``dur``/``name`` fields Perfetto requires, and a
     nested span's interval is contained in its parent's;
@@ -58,9 +58,10 @@ def test_disabled_span_is_shared_noop_singleton():
 def test_disabled_metrics_record_nothing():
     obs.counter_add("c", 5)
     obs.gauge_set("g", 1.0)
-    obs.observe("h", 2.0)
+    obs.record("h", 0.0, 2.0)
     snap = obs.snapshot()
-    assert snap == {"counters": {}, "gauges": {}, "histograms": {}}
+    assert snap == {"counters": {}, "gauges": {}}
+    assert obs.events() == []
 
 
 def test_span_nesting_depth_and_order():
@@ -136,34 +137,83 @@ def test_event_buffer_is_bounded():
         obs_trace._EVENTS = old_events
 
 
-# ------------------------------------------------------------- histograms
+# ------------------------------------------ back-dated events, forwarding
 
-def test_histogram_bucket_boundaries():
-    # inclusive upper bound: 2**m lands in bucket 2**m, the next float
-    # up spills into 2**(m+1); non-positive values pool in bucket 0
-    assert obs.bucket_le(4.0) == 4.0
-    assert obs.bucket_le(4.0001) == 8.0
-    assert obs.bucket_le(1.0) == 1.0
-    assert obs.bucket_le(0.75) == 1.0
-    assert obs.bucket_le(0.5) == 0.5
-    assert obs.bucket_le(0.0) == 0.0
-    assert obs.bucket_le(-3.0) == 0.0
-    assert obs.bucket_le(1023.9) == 1024.0
-
-
-def test_histogram_stats_and_quantile():
+def test_record_lands_on_the_span_clock():
+    import time
     obs.enable()
-    for v in (1.0, 2.0, 3.0, 100.0):
-        obs.observe("lat", v)
-    h = obs.snapshot()["histograms"]["lat"]
-    assert h["count"] == 4
-    assert h["min"] == 1.0 and h["max"] == 100.0
-    assert h["mean"] == pytest.approx(26.5)
-    assert h["buckets"] == {"1.0": 1, "2.0": 1, "4.0": 1, "128.0": 1}
-    # bucket-resolution quantiles: p50 within the 2.0 bucket, p100 at 128
-    assert obs.histogram_quantile("lat", 0.5) == 2.0
-    assert obs.histogram_quantile("lat", 1.0) == 128.0
-    assert obs.histogram_quantile("absent", 0.5) is None
+    t0 = time.perf_counter()
+    with obs.span("live"):
+        pass
+    t1 = t0 + 0.0125
+    obs.record("late", t0, t1, rid=7)
+    live, late = obs.events()
+    assert late["name"] == "late" and late["args"] == {"rid": 7}
+    assert late["dur_us"] == pytest.approx(12_500.0, abs=1e-3)
+    # same clock: the span opened after t0, within a millisecond
+    assert 0.0 <= live["ts_us"] - late["ts_us"] < 1e3
+    assert late["forwarded"] is False and late["depth"] == 0
+
+
+def test_record_inside_a_span_takes_its_depth():
+    import time
+    obs.enable()
+    with obs.span("outer"):
+        t = time.perf_counter()
+        obs.record("inner", t, t)
+    inner, outer = obs.events()
+    assert (inner["depth"], outer["depth"]) == (1, 0)
+    assert inner["dur_us"] == 0.0
+
+
+def test_annotate_adds_args_and_is_noop_when_disabled():
+    with obs.span("off") as sp:
+        sp.annotate(x=1)            # the shared singleton ignores it
+    assert obs.events() == []
+    obs.enable()
+    with obs.span("on", a=1) as sp:
+        sp.annotate(b=2)
+    (ev,) = obs.events()
+    assert ev["args"] == {"a": 1, "b": 2}
+
+
+def test_forwarded_flag_follows_enable():
+    obs.enable()
+    with obs.span("plain"):
+        pass
+    obs.enable(forward_to_jax=True)
+    with obs.span("fwd"):
+        pass
+    plain, fwd = obs.events()
+    assert plain["forwarded"] is False
+    assert fwd["forwarded"] is True
+
+
+def test_enable_resolves_trace_annotation_once(monkeypatch):
+    import jax.profiler
+
+    made = []
+
+    class Fake:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Fake)
+    obs.enable(forward_to_jax=True)
+    # a later change to the attribute is not looked up again per span
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", None)
+    with obs.span("a"):
+        with obs.span("b"):
+            pass
+    assert made == ["a", "b"]
+    obs.disable()
+    assert obs_trace._ANNOTATION is None
 
 
 def test_gauge_samples_are_timestamped_and_bounded():
@@ -285,10 +335,17 @@ def test_serve_stats_contract_with_obs_enabled():
     assert counters["serve.batched_ops"] == (sync_stats["batched_ops"]
                                              + async_stats["batched_ops"])
     assert counters["serve.drains"] == 2
-    # per-phase histograms came along for free (span exit feeds them)
-    hists = obs.snapshot()["histograms"]
-    assert hists["serve.dispatch.us"]["count"] >= 2
-    assert "serve.lifecycle.drained_us" in hists
+    # the per-request record: one serve.request per request and drain,
+    # each naming a batch that a serve.dispatch span carries
+    evs = obs.events()
+    reqs_evs = [e for e in evs if e["name"] == "serve.request"]
+    assert sorted(e["args"]["rid"] for e in reqs_evs) == sorted(
+        [r.rid for r in reqs] * 2)
+    batches = {e["args"]["batch"] for e in evs
+               if e["name"] == "serve.dispatch"}
+    assert len(batches) == (sync_stats["dispatches"]
+                            + async_stats["dispatches"])
+    assert {e["args"]["batch"] for e in reqs_evs} - {None} <= batches
 
 
 def test_zero_request_drains_report_empty_latency():
