@@ -38,7 +38,7 @@ Two kernel families live here:
   ``ntt_inv_banks_pallas``, plus the four-step step-3 companion
   ``twiddle_mul_banks_pallas``): the paper's Fig 22 bank array, where 8
   NTT units process the RNS prime rows in parallel.  The grid is
-  ``(prime, batch_tile)`` over the TablePack layout of
+  ``(prime, row_block)`` over the TablePack layout of
   ``fhe.batched.build_table_pack``:
 
     qs, ninv, ninv_p (k,)       per-prime scalars, whole arrays in SMEM;
@@ -49,11 +49,21 @@ Two kernel families live here:
                                 (k, 1, n) so the (1, 1, n) block's last
                                 two dims equal the array's
 
-  Batch tiles follow the TPU block rule: a multiple of 8 or the whole
-  (padded) batch (``kernels.autotune.clamp``).
+  The batch tile (``kernels.autotune.clamp``: a multiple of 8 or the
+  whole batch) is only the padding unit.  A program covers a block of
+  ``block_rows(b, n, tile)`` rows chosen from the input's shape: the
+  whole (padded) batch when its (b, n) block fits ``BLOCK_BYTES``, else
+  the largest multiple of the tile that divides b and fits.  The
+  program runs its block as one (g, 8, n) stack of independent slabs
+  whose stage chains interleave, so the VLIW slots are not left waiting
+  on one dependent chain of one vreg.  The lane iota, the per-stage
+  masks and the broadcast twiddle rows are built once per program at
+  (8, n) and broadcast over the stack.
 
-VMEM per program (n=128 pass of the 2^14 four-step, tile=8): coeffs
-8*128*4 = 4 KiB, lane twiddles 2*7*128*4 = 7 KiB.
+VMEM per program (n=128 pass of the 2^14 four-step): a 512-row block
+of 256 KiB in and 256 KiB out, each double-buffered (1 MiB), plus lane
+twiddles 2*7*128*4 = 7 KiB; a [9, 32768, 128] pass is a (9, 64) grid
+instead of (9, 4096) steps of one 4 KiB vreg.
 """
 from __future__ import annotations
 
@@ -66,6 +76,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.modmath import _shoup16_lazy_u32, mulhi_u32
 from repro.kernels import resolve_interpret
 
@@ -106,31 +117,45 @@ def _lazy_sub(a, b, q2):
     return jnp.where(a >= b, a - b, a + (q2 - b))
 
 
-def _partner(x, lane, m):
-    """x[..., p ^ m] for every lane p: two lane rotations and a select.
-    The select mask is derived from rotating the lane iota itself, so it
-    holds whichever direction the hardware rotate takes."""
-    n = x.shape[-1]
-    a = pltpu.roll(x, m, 1)
-    b = pltpu.roll(x, n - m, 1)
-    a_is_partner = pltpu.roll(lane, m, 1) == (lane ^ m)
-    return jnp.where(a_is_partner, a, b)
-
-
-# Shared stage loops: one codepath for the single-prime and banks
-# kernels.  ``get_row(t)`` yields the (1, n) lane rows (w, wp) of stage
-# t.  In lazy mode values ride in [0, 2q) (see core.modmath's lazy
-# contract) and the caller owns the epilogue reduction.
-
-def _fwd_stages(x, get_row, stages, q, lazy, bits):
-    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    n = x.shape[-1]
-    q2 = q + q
+def _stage_consts(get_row, stages, shape):
+    """Per-stage constants of the in-place stage loop at ``shape`` (the
+    trailing (rows, n) of the values they meet), built once per program:
+    the pair distance m, the stage's lane rows (w, wp) broadcast, the
+    mask of lanes holding the low element of their pair, and the mask of
+    lanes whose rotation by +m brings the partner (derived from rotating
+    the lane iota itself, so it holds whichever direction the hardware
+    rotate takes).  ``get_row(t)`` yields the (1, n) lane rows of stage
+    t."""
+    n = shape[-1]
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    consts = []
     for t in range(stages):
         m = n >> (t + 1)
         w, wp = get_row(t)
-        is_lo = (lane & m) == 0
-        y = _partner(x, lane, m)
+        consts.append((m, jnp.broadcast_to(w, shape),
+                       jnp.broadcast_to(wp, shape), (lane & m) == 0,
+                       pltpu.roll(lane, m, 1) == (lane ^ m)))
+    return consts
+
+
+def _partner(x, m, a_is_partner):
+    """x[..., p ^ m] for every lane p: two lane rotations and a select."""
+    n, ax = x.shape[-1], x.ndim - 1
+    return jnp.where(a_is_partner, pltpu.roll(x, m, ax),
+                     pltpu.roll(x, n - m, ax))
+
+
+# Shared stage loops: one codepath for the single-prime and banks
+# kernels.  ``x`` is (rows, n) or a stack (g, rows, n) of independent
+# slabs that the constants broadcast over, so one traced op covers
+# every slab and their chains interleave on the chip.  In lazy mode
+# values ride in [0, 2q) (see core.modmath's lazy contract) and the
+# caller owns the epilogue reduction.
+
+def _fwd_stages(x, consts, q, lazy, bits):
+    q2 = q + q
+    for m, w, wp, is_lo, a_is_partner in consts:
+        y = _partner(x, m, a_is_partner)
         hi = jnp.where(is_lo, y, x)
         lo = jnp.where(is_lo, x, y)
         if lazy:
@@ -142,15 +167,10 @@ def _fwd_stages(x, get_row, stages, q, lazy, bits):
     return x
 
 
-def _inv_stages(x, get_row, stages, q, lazy, bits):
-    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    n = x.shape[-1]
+def _inv_stages(x, consts, q, lazy, bits):
     q2 = q + q
-    for t in range(stages - 1, -1, -1):
-        m = n >> (t + 1)
-        w, wp = get_row(t)
-        is_lo = (lane & m) == 0
-        y = _partner(x, lane, m)
+    for m, w, wp, is_lo, a_is_partner in reversed(consts):
+        y = _partner(x, m, a_is_partner)
         e = jnp.where(is_lo, x, y)
         o = jnp.where(is_lo, y, x)
         if lazy:
@@ -209,8 +229,9 @@ def _ntt_fwd_kernel(x_ref, tw_ref, twp_ref, pre_ref, prep_ref, o_ref, *,
     if negacyclic:
         x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[...], prep_ref[...],
                                               qc, 32)
-    x = _fwd_stages(x, lambda t: (tw_ref[t:t + 1, :], twp_ref[t:t + 1, :]),
-                    stages, qc, lazy, 32)
+    consts = _stage_consts(lambda t: (tw_ref[t:t + 1, :],
+                                      twp_ref[t:t + 1, :]), stages, x.shape)
+    x = _fwd_stages(x, consts, qc, lazy, 32)
     if lazy:
         x = jnp.where(x >= qc, x - qc, x)   # epilogue: back to [0, q)
     o_ref[...] = x
@@ -221,8 +242,9 @@ def _ntt_inv_kernel(x_ref, itw_ref, itwp_ref, post_ref, postp_ref, o_ref, *,
                     ninv_p: int, lazy: bool):
     qc = U32(q)
     x = x_ref[...]
-    x = _inv_stages(x, lambda t: (itw_ref[t:t + 1, :], itwp_ref[t:t + 1, :]),
-                    stages, qc, lazy, 32)
+    consts = _stage_consts(lambda t: (itw_ref[t:t + 1, :],
+                                      itwp_ref[t:t + 1, :]), stages, x.shape)
+    x = _inv_stages(x, consts, qc, lazy, 32)
     # the epilogue multiply fully reduces either path (_shoup takes any
     # u32 representative), so lazy costs nothing extra here
     if negacyclic:
@@ -280,26 +302,56 @@ def ntt_inv_pallas(x, itw, itwp, post, postp, *, q: int, stages: int,
 
 # ------------------------------------------------ multi-prime NTT banks
 
+# Geometry of the banks NTT programs, from the input's (b, n) alone: a
+# program's u32 block stays within BLOCK_BYTES and runs as one stack of
+# independent (8, n) slabs.  Chosen on a TPU v5e (PERF.md, Findings): at
+# n = 128 the time per vreg falls with the slabs that run side by side
+# (1 slab 0.68 us, 8 slabs 0.10 us, 64 slabs 0.05 us) and hardly moves
+# past 64.
+BLOCK_BYTES = 256 * 1024
+SUBLANES = 8
+
+
+def block_rows(b: int, n: int, tile: int) -> int:
+    """Rows per grid step for a (k, b, n) input whose batch is padded to
+    a multiple of ``tile``: the whole batch when its block fits
+    ``BLOCK_BYTES``, else the largest multiple of ``tile`` that divides
+    b and fits (at least ``tile``)."""
+    cap = BLOCK_BYTES // (4 * n)
+    if b <= cap:
+        return b
+    return max(r for r in range(tile, max(cap, tile) + 1, tile) if b % r == 0)
+
+
+def _slabs(x_ref):
+    """The (1, rows, n) block as a (g, 8, n) stack of independent slabs
+    (one (rows, n) slab when rows is not a multiple of 8), and the slab
+    shape."""
+    rows, n = x_ref.shape[1:]
+    slab = (SUBLANES if rows % SUBLANES == 0 else rows, n)
+    return x_ref[0].reshape(rows // slab[0], *slab), slab
+
+
 def _ntt_fwd_banks_kernel(x_ref, q_ref, tw_ref, twp_ref, pre_ref, prep_ref,
                           o_ref, *, stages: int, negacyclic: bool, lazy: bool,
                           reduce_out: bool, bits: int):
-    """One bank row: program (p, i) transforms batch tile i under prime
+    """One bank row: program (p, i) transforms row block i under prime
     row p.  The modulus is a per-program scalar read from SMEM.
 
     lazy + reduce_out=False emits the raw [0, 2q) representatives for a
     lazy-aware consumer (the four-step twiddle pass absorbs the
     reduction in its own Shoup multiply)."""
     qc = q_ref[pl.program_id(0)]
-    x = x_ref[0]                        # (tile, n)
+    x, slab = _slabs(x_ref)
     if negacyclic:
         x = (_shoup_lazy if lazy else _shoup)(x, pre_ref[0], prep_ref[0],
                                               qc, bits)
-    x = _fwd_stages(x, lambda t: (tw_ref[0, t:t + 1, :],
-                                  twp_ref[0, t:t + 1, :]),
-                    stages, qc, lazy, bits)
+    consts = _stage_consts(lambda t: (tw_ref[0, t:t + 1, :],
+                                      twp_ref[0, t:t + 1, :]), stages, slab)
+    x = _fwd_stages(x, consts, qc, lazy, bits)
     if lazy and reduce_out:
         x = jnp.where(x >= qc, x - qc, x)
-    o_ref[0] = x
+    o_ref[0] = x.reshape(o_ref.shape[1:])
 
 
 def _ntt_inv_banks_kernel(x_ref, q_ref, ninv_ref, ninvp_ref, itw_ref, itwp_ref,
@@ -308,10 +360,10 @@ def _ntt_inv_banks_kernel(x_ref, q_ref, ninv_ref, ninvp_ref, itw_ref, itwp_ref,
                           bits: int):
     p = pl.program_id(0)
     qc = q_ref[p]
-    x = x_ref[0]
-    x = _inv_stages(x, lambda t: (itw_ref[0, t:t + 1, :],
-                                  itwp_ref[0, t:t + 1, :]),
-                    stages, qc, lazy, bits)
+    x, slab = _slabs(x_ref)
+    consts = _stage_consts(lambda t: (itw_ref[0, t:t + 1, :],
+                                      itwp_ref[0, t:t + 1, :]), stages, slab)
+    x = _inv_stages(x, consts, qc, lazy, bits)
     # epilogue multiply: full reduce unless a lazy consumer asked for the
     # [0, 2q) representative (reduce_out=False only makes sense in lazy
     # mode; the eager multiply is always exact)
@@ -320,36 +372,42 @@ def _ntt_inv_banks_kernel(x_ref, q_ref, ninv_ref, ninvp_ref, itw_ref, itwp_ref,
         x = mul(x, post_ref[0], postp_ref[0], qc, bits)  # psi^-i * n^-1
     else:
         x = mul(x, ninv_ref[p], ninvp_ref[p], qc, bits)
-    o_ref[0] = x
+    o_ref[0] = x.reshape(o_ref.shape[1:])
 
 
 def _banks_grid_call(kernel, x, scalars, tables, rows, *, tile: int,
                      interpret: bool | None):
-    """Grid (prime, batch_tile).  ``scalars`` are (k,) per-prime values
+    """Grid (prime, row_block).  ``scalars`` are (k,) per-prime values
     (whole arrays in SMEM), ``tables`` (k, s, n) lane-twiddle stacks,
     ``rows`` (k, n) weight rows (re-laid out as (k, 1, n)).  Every
     VMEM spec selects row p of its stack via the leading grid
     coordinate, so each program sees exactly its bank's constants.  All
-    operands are widened to u32 lanes here."""
+    operands are widened to u32 lanes here.  The block is
+    ``block_rows(b, n, tile)`` rows; when tracing is on, building the
+    program (tracing the kernel) is a ``kernels.ntt_banks.block`` span
+    carrying the geometry."""
     interpret = resolve_interpret(interpret)
     k, b, n = x.shape
     assert b % tile == 0
+    blk = block_rows(b, n, tile)
     u = lambda a: a.astype(U32)
-    in_specs = [pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0))]
+    in_specs = [pl.BlockSpec((1, blk, n), lambda p, i: (p, i, 0))]
     in_specs += [_smem() for _ in scalars]
     in_specs += [pl.BlockSpec((1,) + t.shape[1:], lambda p, i: (p, 0, 0))
                  for t in tables]
     in_specs += [pl.BlockSpec((1, 1, r.shape[-1]), lambda p, i: (p, 0, 0))
                  for r in rows]
-    return pl.pallas_call(
-        kernel,
-        grid=(k, b // tile),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile, n), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, b, n), U32),
-        interpret=interpret,
-    )(u(x), *map(u, scalars), *map(u, tables),
-      *(u(r)[:, None, :] for r in rows))
+    with obs.span("kernels.ntt_banks.block", cat="kernel", k=k, b=b, n=n,
+                  rows=blk, steps=k * (b // blk)):
+        return pl.pallas_call(
+            kernel,
+            grid=(k, b // blk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, blk, n), lambda p, i: (p, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((k, b, n), U32),
+            interpret=interpret,
+        )(u(x), *map(u, scalars), *map(u, tables),
+          *(u(r)[:, None, :] for r in rows))
 
 
 @functools.partial(jax.jit, static_argnames=("stages", "negacyclic", "tile", "lazy", "reduce_out", "bits", "interpret"))

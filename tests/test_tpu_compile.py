@@ -8,7 +8,9 @@ described ``v5e:2x2`` topology — the TPU compiler is installed, no chip
 is needed, and nothing runs.  The widths:
 
   * banks fwd/inv NTT at the four-step pass shape of the 2^14 ring
-    (n = 128 = ``fourstep_split(2^14)[0]``), 8 RNS limbs, batch 128*B;
+    (n = 128 = ``fourstep_split(2^14)[0]``), 8 RNS limbs, batch 128*B,
+    and at the key switch's digit-extended passes (9 primes, 32,768 and
+    8,192 rows), whose blocks span many vregs;
   * ``twiddle_mul_banks`` on the 2^14 ring, 8 limbs;
   * ``dyadic_inner_banks``: 8 digits over 9 primes (8 limbs + the
     special prime), shared-key and per-batch-key layouts;
@@ -75,20 +77,43 @@ def _banks_tables(s, k, n, stages):
         ninv=_sds(s, (k,)))
 
 
-@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
-def test_ntt_banks_fourstep_pass(one_chip, inverse):
-    s, k, n = one_chip, LIMBS, N_PASS
+def _compile_pass(s, k, rows, inverse):
+    """Compile one lazy 128-point banks NTT pass over (k, rows, 128)."""
+    n = N_PASS
     stages = n.bit_length() - 1
     t = _banks_tables(s, k, n, stages)
-    x = _sds(s, (k, N_PASS * B, n))
+    x = _sds(s, (k, rows, n))
     common = dict(stages=stages, negacyclic=False, tile=TILE, lazy=True,
                   reduce_out=False, interpret=False)
     if inverse:
-        c = _compile(ntt_kernel.ntt_inv_banks_pallas, x, t["qs"], t["ninv"],
-                     t["ninv"], t["tw"], t["twp"], t["w"], t["w"], **common)
-    else:
-        c = _compile(ntt_kernel.ntt_fwd_banks_pallas, x, t["qs"], t["tw"],
-                     t["twp"], t["w"], t["w"], **common)
+        return _compile(ntt_kernel.ntt_inv_banks_pallas, x, t["qs"],
+                        t["ninv"], t["ninv"], t["tw"], t["twp"], t["w"],
+                        t["w"], **common)
+    return _compile(ntt_kernel.ntt_fwd_banks_pallas, x, t["qs"], t["tw"],
+                    t["twp"], t["w"], t["w"], **common)
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+def test_ntt_banks_fourstep_pass(one_chip, inverse):
+    c = _compile_pass(one_chip, LIMBS, N_PASS * B, inverse)
+    assert "tpu_custom_call" in c.as_text()
+
+
+# (primes, rows) of the four-step passes in the key switch: 9 primes
+# (8 limbs + the special prime) over 8 digits x B ciphertexts x 128
+# columns, at the key-switch batch (B = 32) and the serve tile (B = 8).
+PASS_GEOMETRY = {"keyswitch_b32": (LIMBS + 1, LIMBS * 32 * N_PASS),
+                 "serve_b8": (LIMBS + 1, LIMBS * 8 * N_PASS)}
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+@pytest.mark.parametrize("shape", list(PASS_GEOMETRY))
+def test_ntt_banks_pass_block_geometry(one_chip, shape, inverse):
+    """The many-row blocks the banks NTT takes from these shapes fit
+    the chip's scoped VMEM (the compile refuses a block that does not)."""
+    k, rows = PASS_GEOMETRY[shape]
+    assert ntt_kernel.block_rows(rows, N_PASS, TILE) > TILE
+    c = _compile_pass(one_chip, k, rows, inverse)
     assert "tpu_custom_call" in c.as_text()
 
 
