@@ -40,22 +40,52 @@ def ntt_butterflies(n: int) -> int:
     return n // 2 * (n.bit_length() - 1)
 
 
-def keyswitch_mulmods(n: int, k: int) -> int:
-    """Modular multiplications a hybrid key switch with k single-prime
-    digits over k primes plus one special prime needs, from its shapes:
-    butterflies of every residue polynomial transformed (k inverse, k*(k+1)
-    forward for the digit extension, 2 inverse and 2*k forward for the two
-    divisions by P) plus the pointwise products (2*k*(k+1) digit-key
-    products, 2*k multiplications by P^-1)."""
-    polys = k + k * (k + 1) + 2 + 2 * k
-    pointwise = 2 * k * (k + 1) + 2 * k
-    return polys * ntt_butterflies(n) + pointwise * n
+def digit_sizes(k: int, dnum: int | None = None) -> list[int]:
+    """Primes per digit of a basis of k primes cut into consecutive
+    groups of g = ceil(k/dnum) (``dnum`` None: k single-prime digits)."""
+    g = -(-k // (k if dnum is None else dnum))
+    return [min(g, k - i) for i in range(0, k, g)]
 
 
-def relin_multiply_bytes(n: int, k: int, batch: int, word: int = 4) -> int:
-    """Least HBM bytes of ``batch`` relinearising multiplies sharing one
-    relin key: read four input halves, write two output halves per
-    multiply, read the (k, k+1, n) key pair once."""
+def conversion_mulmods(m: int, targets: int) -> int:
+    """Modular multiplications per coefficient of a fast base conversion
+    from m primes to ``targets`` primes: m by (M/m_i)^-1, then m per
+    target by M/m_i.  From one prime both factors are 1: none."""
+    return 0 if m == 1 else m * (1 + targets)
+
+
+def keyswitch_mulmods(n: int, k: int, dnum: int | None = None,
+                      alpha: int = 1) -> int:
+    """Modular multiplications a hybrid key switch over k primes needs,
+    from its shapes: the digits of ``digit_sizes(k, dnum)``, ``alpha``
+    special primes.  Butterflies of every residue polynomial transformed,
+    plus the pointwise products of n; with d digits:
+
+    * k inverse transforms of the input's rows (its digits);
+    * d*(k+alpha) forward transforms of the digits' extensions;
+    * the mod-up's base conversions (none for one-prime digits);
+    * 2*d*(k+alpha) products with the key pair;
+    * two mod-downs, one per half: alpha inverse transforms of the
+      special rows, their conversion to the k primes (none for one
+      special prime), k forward transforms, k multiplications by P^-1.
+
+    At dnum = k, alpha = 1 (single-prime digits, one special prime):
+    k + k*(k+1) + 2 + 2*k transforms and 2*k*(k+1) + 2*k products."""
+    sizes = digit_sizes(k, dnum)
+    ext = k + alpha
+    transforms = k + len(sizes) * ext + 2 * (alpha + k)
+    pointwise = (sum(conversion_mulmods(m, ext) for m in sizes)
+                 + 2 * len(sizes) * ext
+                 + 2 * (conversion_mulmods(alpha, k) + k))
+    return transforms * ntt_butterflies(n) + pointwise * n
+
+
+def relin_multiply_bytes(n: int, k: int, batch: int, word: int = 4,
+                         dnum: int | None = None, alpha: int = 1) -> int:
+    """Least HBM bytes of ``batch`` relinearising multiplies over k
+    primes sharing one relin key: read four input halves, write two
+    output halves per multiply, read the key pair once: two
+    (d, k+alpha, n) tensors, d the digits of ``digit_sizes(k, dnum)``."""
     halves = 6 * k * n * word * batch
-    key = 2 * k * (k + 1) * n * word
+    key = 2 * len(digit_sizes(k, dnum)) * (k + alpha) * n * word
     return halves + key

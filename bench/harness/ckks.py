@@ -2,21 +2,40 @@
 timed paths, and the bit-exact check against ``reference/ckks_ref.py``.
 
 Configuration keys: ``n``, ``levels`` (L; the chain holds L+1 primes plus
-the special prime), ``scale_bits``, ``ntt_order`` (slot order of the
+the special primes), ``scale_bits``, ``ntt_order`` (slot order of the
 evaluation form), ``rotations`` and ``conjugate`` (the tenant's Galois
 keys), ``matrix`` ([d_in, d_out] of the tenant's BSGS plaintext matrix),
 ``batch_tile`` and ``max_batch`` (the serve engine's).
+
+The hybrid key switch's shape, both keys optional:
+
+* ``dnum``: the number of digits, 1..L+1; absent, L+1 (one prime each);
+* ``special_primes``: alpha, the number of special primes whose product
+  P divides the key switch; absent, 1.
+
+With g = ceil((L+1)/dnum) primes per digit, a configuration is refused
+(``spec.SpecError``) where alpha < max(1, g), which would leave P below a
+digit's modulus, or where fewer than L+1+alpha primes = 1 (mod 2n) lie
+below 2^30.  ``reference/ckks_ref.py`` states what the shape means.  The
+program's ``CkksContext`` is given ``dnum=`` and ``special_primes=`` only
+where the configuration names them; a configuration that names a key
+the program's ``CkksContext`` does not take is refused (``SpecError``)
+before the program is built.
 """
 from __future__ import annotations
 
+import inspect
 import time
 from collections import deque
 
 import jax
 import numpy as np
 
-from harness import serving, traffic as gen
+from harness import serving, spec, traffic as gen
 from reference.ckks_ref import CkksRef
+
+# The configuration keys that shape the hybrid key switch (module doc).
+KEYSWITCH_KEYS = ("dnum", "special_primes")
 
 # Largest key noise a sound key may read (``CkksRef.key_noise``): sound
 # keys read about six standard deviations of the program's sigma 3.2
@@ -26,6 +45,31 @@ KEY_NOISE_LIMIT = 4096.0
 
 def _arr(ct):
     return np.asarray(ct.c0.data), np.asarray(ct.c1.data)
+
+
+def keyswitch_shape(config: dict) -> dict:
+    """The key-switch keys the configuration names, checked against the
+    rules of the module doc (``spec.SpecError`` where one is broken)."""
+    named = {k: config[k] for k in KEYSWITCH_KEYS if k in config}
+    for k, v in named.items():
+        if isinstance(v, bool) or not isinstance(v, int):
+            raise spec.SpecError(f"configuration key {k!r}: {v!r} is not "
+                                 "a whole number")
+    try:
+        CkksRef(config["n"], config["levels"], config["ntt_order"], **named)
+    except ValueError as e:
+        raise spec.SpecError(f"configuration: {e}") from None
+    return named
+
+
+def untaken_keys(build, named) -> list:
+    """The keys of ``named`` that ``build`` takes no keyword for."""
+    params = inspect.signature(build).parameters.values()
+    if any(p.kind is p.VAR_KEYWORD for p in params):
+        return []
+    return [k for k in named if k not in {
+        p.name for p in params
+        if p.kind in (p.POSITIONAL_OR_KEYWORD, p.KEYWORD_ONLY)}]
 
 
 def _keys(evk):
@@ -42,9 +86,14 @@ class CkksCell:
 
         self.cfg, self.traffic, self.seed = config, traffic, seed
         self.loop = traffic["loop"]
+        self.ks = keyswitch_shape(config)
+        untaken = untaken_keys(CkksContext, self.ks)
+        if untaken:
+            raise spec.SpecError(f"configuration keys {untaken}: the "
+                                 "program's CkksContext does not take them")
         ctx = self.ctx = CkksContext(n=config["n"], levels=config["levels"],
                                      scale_bits=config["scale_bits"],
-                                     seed=seed)
+                                     seed=seed, **self.ks)
         plan = self.plan = ctx.plan()
         rng = np.random.default_rng([seed, 1])
         slots = lambda: (rng.uniform(-1, 1, ctx.slots)
@@ -146,7 +195,8 @@ class CkksCell:
 
     def _ref(self, control: bool) -> CkksRef:
         return CkksRef(self.cfg["n"], self.cfg["levels"],
-                       self.cfg["ntt_order"], round_mod_down=not control)
+                       self.cfg["ntt_order"], round_mod_down=not control,
+                       **self.ks)
 
     def expected(self, ref: CkksRef, op: str, pool: int, key, r: int = 0,
                  other: int | None = None, cache: dict | None = None):

@@ -13,5 +13,6 @@ def read(obs):
     cfg = obs["config"]
     k = cfg["levels"] + 1
     moved = win["calls"] * arith.relin_multiply_bytes(
-        cfg["n"], k, int(obs["traffic"]["batch"]))
+        cfg["n"], k, int(obs["traffic"]["batch"]), dnum=cfg.get("dnum"),
+        alpha=cfg.get("special_primes", 1))
     return 100.0 * moved / (tr["busy_s"] * peaks["hbm_bytes_per_s"])

@@ -9,5 +9,7 @@ def read(obs):
     if not win.get("calls") or not tr or not tr["busy_s"]:
         return None
     cfg = obs["config"]
-    per_ks = arith.keyswitch_mulmods(cfg["n"], cfg["levels"] + 1)
+    per_ks = arith.keyswitch_mulmods(cfg["n"], cfg["levels"] + 1,
+                                     cfg.get("dnum"),
+                                     cfg.get("special_primes", 1))
     return win["plan"]["key_switches"] * per_ks / (tr["busy_s"] * 1e9)

@@ -1,28 +1,48 @@
 """Plain reference of the CKKS ring operations the CKKS cells time.
 
 Straight numpy over uint64 residues, written from the scheme's
-definitions and sharing no code with the system under test:
+definitions and sharing no code with the system under test; Python
+integers appear only in per-prime constants.
 
 * ring Z_q[X]/(X^n + 1) per prime q, ciphertexts in evaluation form:
   slot j of a row holds the evaluation at psi^(1 + 2*ord(j)), psi the
   primitive 2n-th root g^((q-1)/2n) of the smallest generator g of Z_q*,
   ord(j) = j ("natural" order) or bitrev(j) ("bitrev" order);
-* the prime chain: the ``levels + 2`` largest primes q = 1 (mod 2n)
-  below 2^30, the largest of them the special prime P;
-* hybrid key switching with single-prime digits: digit i of d is the
-  centred residue [d]_{q_i}, lifted exactly to every prime of Q*P,
-  multiplied into the digit key, summed, then divided by P with rounding
-  (the RNS floor of the centred residue mod P);
+* the prime chain: the ``levels + 1 + special_primes`` largest primes
+  q = 1 (mod 2n) below 2^30, in descending order; the ``special_primes``
+  (alpha) largest are the special primes p_1..p_alpha, P their product,
+  the rest q_0..q_L.  Key rows are the basis rows, then the special rows
+  in chain order;
+* hybrid key switching over ``dnum`` digits: the top-level primes
+  q_0..q_L fall into consecutive groups of g = ceil((L+1)/dnum), group j
+  being q_{jg}..q_{jg+g-1} (the top level so holds ceil((L+1)/g) digits,
+  which is dnum where g divides L+1 evenly).  At a lower basis a group
+  keeps the primes the basis still holds; empty groups drop out;
+* mod-up of digit j, Q_j the product of its primes: the centred fast base
+  conversion y = sum_{i in j} [x_i * (Q_j/q_i)^-1]_{q_i, centred} *
+  (Q_j/q_i), reduced mod every prime of basis + specials;
+* digit key j: b_j + a_j*s = e_j + P*T_j*s' with T_j = (Q/Q_j) *
+  [(Q/Q_j)^-1]_{Q_j} over the top-level Q, so T_j = 1 mod the primes of
+  group j and 0 mod the other q's; a key at a lower basis is the
+  top-level key's rows at that basis and P;
+* mod-down: (x - Conv_{P->basis}([x]_P)) * P^-1 per q_i, the conversion
+  with centred terms, which rounds the division by P;
 * Galois automorphism sigma_g as the permutation of evaluation points
   psi^e -> psi^(g*e);
-* rescale: the same rounded division by the last prime of the basis;
+* rescale: the same rounded division, by the last prime of the basis;
 * the BSGS diagonal matvec of y = x @ W with n1 = ceil(sqrt(d_in))
   baby steps, each diagonal encoded by the canonical embedding.
 
+``dnum`` None and ``special_primes`` 1 (the defaults) give one
+single-prime digit per limb and one special prime; every rule above then
+reads as the plain centred lift of a residue and the rounded division by
+one prime.
+
 Every result is an exact integer, so the comparison with the program is
-bit for bit.  ``round_mod_down=False`` gives the control: the division by
-P floors instead of rounding, which breaks the configuration's stated
-"rounded RNS floor" guarantee.
+bit for bit.  ``round_mod_down=False`` gives the control: the
+conversions of the divisions (mod-down and rescale) keep non-centred
+terms, which floors, and breaks the configuration's stated "rounded RNS
+floor" guarantee.
 """
 from __future__ import annotations
 
@@ -56,17 +76,23 @@ def is_prime(m: int) -> bool:
     return True
 
 
-def chain(n: int, levels: int, bits: int = 30) -> tuple[int, tuple[int, ...]]:
-    """(P, (q_0..q_L)): the levels+2 largest primes = 1 (mod 2n) below
-    2^bits, in descending order; the largest is the special prime."""
+def chain(n: int, levels: int, special: int = 1,
+          bits: int = 30) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """((p_1..p_alpha), (q_0..q_L)): the levels+1+special largest primes
+    = 1 (mod 2n) below 2^bits, in descending order; the ``special``
+    largest are the special primes.  ValueError where there are fewer."""
     step = 2 * n
+    want = levels + 1 + special
     p = ((1 << bits) - 1) // step * step + 1
     out = []
-    while len(out) < levels + 2:
+    while len(out) < want and p > step:
         if is_prime(p):
             out.append(p)
         p -= step
-    return out[0], tuple(out[1:])
+    if len(out) < want:
+        raise ValueError(f"{len(out)} primes = 1 (mod {step}) below "
+                         f"2^{bits}, where {want} are needed")
+    return tuple(out[:special]), tuple(out[special:])
 
 
 def _generator(q: int) -> int:
@@ -109,11 +135,23 @@ class CkksRef:
     """Exact CKKS operations over one prime chain (see module doc)."""
 
     def __init__(self, n: int, levels: int, order: str, *,
+                 dnum: int | None = None, special_primes: int = 1,
                  round_mod_down: bool = True):
         if order not in ("natural", "bitrev"):
             raise ValueError(f"unknown NTT order {order!r}")
+        limbs = levels + 1
+        dnum = limbs if dnum is None else dnum
+        if not 1 <= dnum <= limbs:
+            raise ValueError(f"dnum {dnum} outside 1..levels+1 = 1..{limbs}")
+        # primes per digit; P must be at least a digit's modulus
+        self.group = -(-limbs // dnum)
+        if special_primes < max(1, self.group):
+            raise ValueError(
+                f"special_primes {special_primes} under max(1, g) = "
+                f"{max(1, self.group)}: P below a digit's modulus")
         self.n = n
-        self.special, self.qs = chain(n, levels)
+        self.specials, self.qs = chain(n, levels, special_primes)
+        self.P = math.prod(self.specials)
         self.order = order
         self.round_mod_down = round_mod_down
         self._br = _bitrev(n)
@@ -179,41 +217,70 @@ class CkksRef:
     def _col(primes) -> np.ndarray:
         return np.array(primes, dtype=np.uint64).reshape(-1, 1)
 
-    @staticmethod
-    def _lift(row: np.ndarray, src: int, primes, centred: bool = True):
-        """Residue row mod src -> rows mod each prime (exact lift of the
-        centred, or with ``centred=False`` the plain, representative)."""
-        c = row.astype(np.int64)
-        if centred:
-            c = np.where(c > src // 2, c - src, c)
-        return np.stack([(c % p).astype(np.uint64) for p in primes])
+    def _convert(self, rows: np.ndarray, src, dst, centred: bool = True):
+        """Fast base conversion of coefficient rows (len(src), n), row i
+        mod src[i], to rows mod each prime of ``dst``: the integer
+        sum_i [x_i * (M/m_i)^-1]_{m_i} * (M/m_i), M the product of src,
+        with each bracket's representative centred or, with
+        ``centred=False``, in [0, m_i).  From one prime it is the exact
+        lift of the centred (or plain) residue."""
+        M = math.prod(src)
+        d = self._col(dst)
+        acc = np.zeros((len(dst), rows.shape[1]), dtype=np.uint64)
+        for x, m in zip(rows, src):
+            t = x.astype(np.uint64) * np.uint64(pow(M // m % m, -1, m)) \
+                % np.uint64(m)
+            tt = t[None, :] % d
+            if centred:
+                tt = np.where(t > m // 2, (tt + d - np.uint64(m) % d) % d, tt)
+            f = np.array([M // m % p for p in dst],
+                         dtype=np.uint64).reshape(-1, 1)
+            acc = (acc + tt * f % d) % d
+        return acc
 
-    def _divide_last(self, x: np.ndarray, full) -> np.ndarray:
-        """Evaluation rows over ``full`` -> division by its last prime,
-        rounded (or floored, for the control), over the rest."""
-        rest, p_last = tuple(full[:-1]), full[-1]
-        last = self.intt(x[-1:], (p_last,))[0]
-        ext = self.ntt(self._lift(last, p_last, rest, self.round_mod_down),
-                       rest)
-        q = self._col(rest)
-        inv = np.array([pow(p_last, -1, p) for p in rest],
+    def _mod_down(self, x: np.ndarray, keep, drop) -> np.ndarray:
+        """Evaluation rows over keep + drop -> (x - Conv_{drop->keep}(
+        [x]_drop)) * D^-1 over keep, D the product of drop: the division
+        by D, rounded (centred terms) or, for the control, floored."""
+        keep, drop = tuple(keep), tuple(drop)
+        low = self.intt(x[len(keep):], drop)
+        ext = self.ntt(self._convert(low, drop, keep, self.round_mod_down),
+                       keep)
+        q = self._col(keep)
+        D = math.prod(drop)
+        inv = np.array([pow(D, -1, p) for p in keep],
                        dtype=np.uint64).reshape(-1, 1)
-        return (x[:-1] + q - ext) % q * inv % q
+        return (x[:len(keep)] + q - ext) % q * inv % q
+
+    def digits(self, basis) -> list[tuple[int, ...]]:
+        """Row positions in ``basis`` of each digit's primes: the
+        top-level primes in consecutive groups of ``self.group``, each
+        cut to the primes the basis holds, empty groups left out."""
+        pos = {q: i for i, q in enumerate(basis)}
+        groups = (self.qs[j:j + self.group]
+                  for j in range(0, len(self.qs), self.group))
+        return [g for g in (tuple(pos[q] for q in grp if q in pos)
+                            for grp in groups) if g]
 
     def key_noise(self, evk_b: np.ndarray, evk_a: np.ndarray, s_coeffs,
                   basis, g: int | None = None) -> float:
         """Largest noise of a key-switching key (evk_b, evk_a), each
-        (k, k+1, n) evaluation rows over basis + (P,), against the secret
-        key with integer coefficients ``s_coeffs``.  Digit i must satisfy
-        b_i + a_i*s = e_i + P*T_i*s' with T_i = 1 mod q_i and 0 mod the
-        other primes of the basis, and s' = s^2 (``g`` None: the
-        relinearisation key) or sigma_g(s).  The noise of a digit is its
-        largest centred coefficient where every prime holds the same
-        integer; where two primes disagree the integer is at least the
-        smallest prime less that, which is what is returned.  A key made
-        for another s', g or gadget reads of the order of the primes."""
-        full = tuple(basis) + (self.special,)
-        k = len(basis)
+        (digits, len(basis) + alpha, n) evaluation rows over basis +
+        specials, against the secret key with integer coefficients
+        ``s_coeffs``.  Digit j must satisfy b_j + a_j*s = e_j + P*T_j*s'
+        with T_j = 1 mod the primes of group j and 0 mod the other primes
+        of the basis, and s' = s^2 (``g`` None: the relinearisation key)
+        or sigma_g(s).  The noise of a digit is its largest centred
+        coefficient where every prime holds the same integer; where two
+        primes disagree the integer is at least the smallest prime less
+        that, which is what is returned; a key of another shape reads the
+        smallest prime.  A key made for another s', g, gadget or P reads
+        of the order of the primes."""
+        full = tuple(basis) + self.specials
+        groups = self.digits(basis)
+        shape = (len(groups), len(full), self.n)
+        if evk_b.shape != shape or evk_a.shape != shape:
+            return float(min(full))
         q = self._col(full)
         s = np.asarray(s_coeffs, dtype=np.int64)
         s_ev = self.ntt(np.stack([(s % p).astype(np.uint64) for p in full]),
@@ -221,12 +288,13 @@ class CkksRef:
         s_from = (s_ev * s_ev % q if g is None
                   else s_ev[:, self.galois_perm(g)])
         worst = 0.0
-        for i in range(k):
-            b = evk_b[i].astype(np.uint64)
-            a = evk_a[i].astype(np.uint64)
+        for j, grp in enumerate(groups):
+            b = evk_b[j].astype(np.uint64)
+            a = evk_a[j].astype(np.uint64)
             e = (b + a * s_ev % q) % q
-            e[i] = (e[i] + q[i] - s_from[i] * np.uint64(self.special % full[i])
-                    % q[i]) % q[i]
+            for i in grp:
+                e[i] = (e[i] + q[i] - s_from[i] * np.uint64(self.P % full[i])
+                        % q[i]) % q[i]
             ec = self.intt(e, full).astype(np.int64)
             ec = np.where(ec > q.astype(np.int64) // 2,
                           ec - q.astype(np.int64), ec)
@@ -238,21 +306,28 @@ class CkksRef:
 
     def keyswitch(self, d: np.ndarray, basis, evk_b: np.ndarray,
                   evk_a: np.ndarray):
-        """Switch the key under evaluation rows ``d`` (k, n) over basis;
-        evk_b/evk_a: (k, k+1, n) digit keys over basis + (P,)."""
-        full = tuple(basis) + (self.special,)
-        k = len(basis)
+        """Switch the key under evaluation rows ``d`` (len(basis), n) over
+        basis; evk_b/evk_a: (digits, len(basis) + alpha, n) digit keys
+        over basis + specials (``digits(basis)`` gives the digits)."""
+        full = tuple(basis) + self.specials
+        groups = self.digits(basis)
+        shape = (len(groups), len(full), self.n)
+        if evk_b.shape != shape or evk_a.shape != shape:
+            raise ValueError(f"keys of shape {evk_b.shape}/{evk_a.shape}, "
+                             f"where the basis takes {shape}")
         dc = self.intt(d, basis)
-        ext = np.concatenate([self._lift(dc[i], basis[i], full)
-                              for i in range(k)])            # (k*(k+1), n)
-        ext = self.ntt(ext, full * k).reshape(k, k + 1, self.n)
+        ext = np.concatenate([self._convert(dc[list(g)],
+                                            [basis[i] for i in g], full)
+                              for g in groups])
+        ext = self.ntt(ext, full * len(groups)).reshape(shape)
         q = self._col(full)
-        acc0 = np.zeros((k + 1, self.n), dtype=np.uint64)
-        acc1 = np.zeros((k + 1, self.n), dtype=np.uint64)
-        for i in range(k):
-            acc0 = (acc0 + ext[i] * evk_b[i].astype(np.uint64) % q) % q
-            acc1 = (acc1 + ext[i] * evk_a[i].astype(np.uint64) % q) % q
-        return self._divide_last(acc0, full), self._divide_last(acc1, full)
+        acc0 = np.zeros((len(full), self.n), dtype=np.uint64)
+        acc1 = np.zeros((len(full), self.n), dtype=np.uint64)
+        for j in range(len(groups)):
+            acc0 = (acc0 + ext[j] * evk_b[j].astype(np.uint64) % q) % q
+            acc1 = (acc1 + ext[j] * evk_a[j].astype(np.uint64) % q) % q
+        return (self._mod_down(acc0, basis, self.specials),
+                self._mod_down(acc1, basis, self.specials))
 
     # ------------------------------------------------------ scheme ops
 
@@ -268,8 +343,10 @@ class CkksRef:
         return (d0 + k0) % q, (d1 + k1) % q
 
     def rescale(self, c, basis):
-        return (self._divide_last(c[0].astype(np.uint64), basis),
-                self._divide_last(c[1].astype(np.uint64), basis))
+        return (self._mod_down(c[0].astype(np.uint64), basis[:-1],
+                               basis[-1:]),
+                self._mod_down(c[1].astype(np.uint64), basis[:-1],
+                               basis[-1:]))
 
     def galois_perm(self, g: int) -> np.ndarray:
         n = self.n

@@ -39,9 +39,11 @@ def small(config: str, traffic: str, rate: float | None = None):
 def run_small():
     import run
 
-    def go(cell, seed=2**31 + 5, seconds=1.0, traffic=None, **kw):
+    def go(cell, seed=2**31 + 5, seconds=1.0, traffic=None, config=None,
+           **kw):
         bench, cell, cfg, tr = small(*cell)
         tr.update(traffic or {})
+        cfg.update(config or {})
         return run.run_cell(bench, cell, seed, seconds, False, device=None,
                             config=cfg, traffic=tr, **kw)
     return go
