@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +41,7 @@ from repro.core.modmath import (addmod, submod, mulmod_shoup, mulmod_barrett,
                                 shoup_precompute, barrett_precompute)
 from repro.core.ntt import cg_ntt, cg_intt
 from repro.core.params import make_ntt_params
-from repro.kernels import ops
+from repro.kernels import base_conv_kernel, ops
 
 
 @dataclasses.dataclass
@@ -107,16 +108,53 @@ def pack_from_ntt_params(params: list) -> dict:
     return {k: jnp.asarray(np.stack(v)) for k, v in rows.items()}
 
 
-def _pinv_rows(primes) -> tuple[np.ndarray, np.ndarray]:
-    """P^-1 mod q_j rows (last prime = the special P) + Shoup companions
+def _pinv_rows(primes, drop: int = 1) -> tuple[np.ndarray, np.ndarray]:
+    """P^-1 mod q_j rows + Shoup companions, P the product of the last
+    ``drop`` primes (the special primes, or the one prime rescale drops)
     — the mod-down convention, shared by every pack builder."""
-    P = primes[-1]
-    src = primes[:-1] if len(primes) > 1 else primes
-    pinv = np.array([pow(P, -1, q) if q != P else 1 for q in src],
+    P = math.prod(primes[-drop:])
+    src = primes[:-drop] if len(primes) > drop else primes
+    pinv = np.array([pow(P, -1, q) if P % q else 1 for q in src],
                     dtype=np.uint32)
     pinv_p = np.array([shoup_precompute(int(v), q)
                        for v, q in zip(pinv, src)], dtype=np.uint32)
     return pinv, pinv_p
+
+
+def pinv_pack(primes, drop: int) -> dict:
+    """The ``pinv``/``pinv_p`` entries of a pack whose last ``drop``
+    primes are divided out by the mod-down."""
+    pinv, pinv_p = _pinv_rows(list(primes), drop)
+    return {"pinv": jnp.asarray(pinv), "pinv_p": jnp.asarray(pinv_p)}
+
+
+def base_conv_tables(groups, dst) -> dict:
+    """Constants of the centred fast base conversion from each prime
+    group of ``groups`` to the primes ``dst`` (``ops.base_conv_banks``):
+    for group j of product M and its prime m_i, the Shoup pairs of
+    (M/m_i)^-1 mod m_i and of M/m_i mod p for each p of dst, and the
+    offset 2p - m_i that lifts a negative centred term into [0, 2p).  A
+    group shorter than the longest is padded with zero weights (its
+    padded source rows are zero, so they add nothing)."""
+    m, t = max(len(g) for g in groups), len(dst)
+    z = lambda *shape: np.zeros(shape, dtype=np.uint32)
+    src, inv, inv_p = z(len(groups), m), z(len(groups), m), z(len(groups), m)
+    f, f_p, off = (z(len(groups), m, t) for _ in range(3))
+    for j, grp in enumerate(groups):
+        M = math.prod(grp)
+        src[j] = grp[0]
+        for i, mi in enumerate(grp):
+            Mi = M // mi
+            src[j, i] = mi
+            inv[j, i] = pow(Mi % mi, -1, mi)
+            inv_p[j, i] = shoup_precompute(int(inv[j, i]), mi)
+            for c, p in enumerate(dst):
+                f[j, i, c] = Mi % p
+                f_p[j, i, c] = shoup_precompute(int(f[j, i, c]), p)
+                off[j, i, c] = 2 * p - mi
+    return {k: jnp.asarray(v) for k, v in dict(
+        src=src, inv=inv, inv_p=inv_p, dst=np.array(dst, dtype=np.uint32),
+        f=f, f_p=f_p, off=off).items()}
 
 
 def build_scalar_pack(primes: list[int]) -> dict:
@@ -209,8 +247,9 @@ def extend_centered(coeffs, src_q, dst_qs):
 
 def slice_pack(t: dict, rows) -> dict:
     """View of a TablePack restricted to prime rows ``rows`` (a slice).
-    The pinv rows are basis-relative (P^-1 mod q_j) and left intact."""
-    basis_relative = ("pinv", "pinv_p")
+    The pinv rows (P^-1 mod q_j) and a key-switch pack's base-conversion
+    tables (``up``, ``down``) are basis-relative and left intact."""
+    basis_relative = ("pinv", "pinv_p", "up", "down")
     return {k: (v if k in basis_relative else v[rows]) for k, v in t.items()}
 
 
@@ -227,26 +266,36 @@ def _inv_banks(x, pack, fpk, kw):
 def mod_down_banks(acc, t: dict, *, fsp: dict | None = None,
                    use_pallas: bool | None = None, tile: int | None = None,
                    lazy: bool = True):
-    """RNS floor by the *last* prime of ``t``'s basis, fully batched —
+    """RNS floor by the *last* primes of ``t``'s basis, fully batched —
     the paper's Fig 22 stage 4 (INTT + base-ext + NTT + MS) as one fused
     device program.
 
-    acc: (k+1, B, n) u32 NTT form over t's k+1 primes; returns
-    (k, B, n) over the first k.  The last row runs one banks iNTT, the
-    centered lift broadcasts it back over the basis (``extend_centered``),
-    one banks NTT returns it to evaluation form, and the subtract +
-    per-prime scalar multiply by last^-1 (the precomputed ``pinv``
-    columns) finish the floor.  This single routine serves both the
-    key-switch mod-down by the special prime P (``batched_keyswitch``)
-    and ciphertext rescale by q_l (``evalplan.rescale_banks``) — pass a
-    pack whose basis ends with the prime being dropped.  ``fsp`` routes
-    every transform through the large-N four-step pipeline, exactly as in
+    acc: (k+alpha, B, n) u32 NTT form over t's k+alpha primes; returns
+    (k, B, n) over the first k.  alpha is 1, or the number of special
+    primes where ``t`` carries their conversion tables (``down``, see
+    ``base_conv_tables``).  The last rows run one banks iNTT, the centred
+    conversion carries them over the basis (the plain centred lift of one
+    prime, ``extend_centered``, or ``ops.base_conv_banks`` from alpha
+    primes), one banks NTT returns it to evaluation form, and the
+    subtract + per-prime scalar multiply by P^-1 (the precomputed
+    ``pinv`` columns) finish the rounded division.  This single routine
+    serves both the key-switch mod-down by P (``batched_keyswitch``) and
+    ciphertext rescale by q_l (``evalplan.rescale_banks``) — pass a pack
+    whose basis ends with the primes being dropped.  ``fsp`` routes every
+    transform through the large-N four-step pipeline, exactly as in
     ``batched_keyswitch``."""
-    k = acc.shape[0] - 1
+    alpha = t["down"]["inv"].shape[1] if "down" in t else 1
+    k = acc.shape[0] - alpha
     kw = dict(use_pallas=use_pallas, tile=tile, lazy=lazy)
-    fs_last = slice_fourstep_pack(fsp, slice(k, k + 1)) if fsp is not None else None
-    lastc = _inv_banks(acc[k:], slice_pack(t, slice(k, k + 1)), fs_last, kw)
-    ext = extend_centered(lastc[0], t["qs"][k], t["qs"][:k])
+    top = slice(k, k + alpha)
+    fs_last = slice_fourstep_pack(fsp, top) if fsp is not None else None
+    lastc = _inv_banks(acc[k:], slice_pack(t, top), fs_last, kw)
+    if "down" in t:
+        ext = ops.base_conv_banks(lastc[None], t["down"],
+                                  name=base_conv_kernel.MODDOWN,
+                                  use_pallas=use_pallas, tile=tile)[:, 0]
+    else:
+        ext = extend_centered(lastc[0], t["qs"][k], t["qs"][:k])
     extn = _fwd_banks(ext, slice_pack(t, slice(0, k)), fsp, kw)
     qcol = t["qs"][:k, None, None]
     d = submod(acc[:k], extn, qcol)
@@ -262,31 +311,45 @@ def decompose_banks(d2, t: dict, *, fsp: dict | None = None,
     banks), extracted so callers can pay it ONCE and reuse the digits.
 
     d2: (k, B, n) u32, NTT form over the k-prime basis; t: TablePack for
-    k+1 primes (row k = the special prime P); fsp as in
-    ``batched_keyswitch``.  Returns (k, k+1, B, n): NTT-domain digit
+    k+alpha primes (rows k.. the special primes); fsp as in
+    ``batched_keyswitch``.  Returns (d, k+alpha, B, n): NTT-domain digit
     extensions (digit axis first), ready for ``ops.dyadic_inner_banks``.
 
+    Digits: one per prime (the plain centred lift), or, where ``t``
+    carries the mod-up tables (``up``, see ``base_conv_tables``), groups
+    of m consecutive basis primes (the last one possibly shorter), each
+    extended by the centred fast base conversion ``ops.base_conv_banks``.
+
     This is the hoisting primitive: a Galois automorphism commutes with
-    per-prime digit decomposition (sigma_g permutes integer coefficients
-    with sign flips, which survives the centered lift and every modular
-    reduction), so R rotations of one ciphertext can share a single
-    decomposition — gather these digits R ways in the evaluation domain
-    instead of decomposing R times (``evalplan.hoisted_rotations_banks``).
+    the digit decomposition (sigma_g permutes integer coefficients with
+    sign flips, which survives the centered lift, the centred
+    conversion, whose terms are odd functions of their residues, and
+    every modular reduction), so R rotations of one ciphertext can share
+    a single decomposition — gather these digits R ways in the
+    evaluation domain instead of decomposing R times
+    (``evalplan.hoisted_rotations_banks``).
 
     Every stage is one multi-prime dispatch: the digit INTTs run as k
-    bank rows, the mod-up is a vmap over digits, and all k*(k+1) forward
-    NTTs run as one (prime, batch) grid with the digit axis folded into
-    the batch.  No Python loop over primes or digits."""
+    bank rows, the mod-up is one vmap or one conversion call over all
+    digits, and all d*(k+alpha) forward NTTs run as one (prime, batch)
+    grid with the digit axis folded into the batch.  No Python loop over
+    primes or digits."""
     k, B, n = d2.shape
     kw = dict(use_pallas=use_pallas, tile=tile, lazy=lazy)
     tb = slice_pack(t, slice(0, k))
 
     ci = _inv_banks(d2, tb, fsp, kw)                          # INTT units
-    ext = jax.vmap(lambda c, q: extend_centered(c, q, t["qs"])
-                   )(ci, t["qs"][:k])                         # mod-up: (k, k+1, B, n)
-    # NTT banks: fold the digit axis into the batch so all k*(k+1)
+    if "up" in t:                                             # mod-up:
+        d, m = t["up"]["inv"].shape                           # (k+a, d, B, n)
+        x = jnp.pad(ci, ((0, d * m - k), (0, 0), (0, 0))).reshape(d, m, B, n)
+        ext = ops.base_conv_banks(x, t["up"], name=base_conv_kernel.MODUP,
+                                  use_pallas=use_pallas, tile=tile)
+    else:
+        ext = jax.vmap(lambda c, q: extend_centered(c, q, t["qs"])
+                       )(ci, t["qs"][:k]).transpose(1, 0, 2, 3)  # (k+1, k, ..)
+    # NTT banks: fold the digit axis into the batch so all d*(k+alpha)
     # transforms run in ONE (prime, batch_tile) grid.
-    y = _fwd_banks(ext.transpose(1, 0, 2, 3), t, fsp, kw)     # (k+1, k, B, n)
+    y = _fwd_banks(ext, t, fsp, kw)                           # (k+a, d, B, n)
     return y.transpose(1, 0, 2, 3)                            # (digit, prime, B, n)
 
 
@@ -299,11 +362,13 @@ def batched_keyswitch(d2, evk_b, evk_a, t: dict, *, fsp: dict | None = None,
     d2:      (k, B, n) u32, NTT form over the k-prime basis (digit rows);
              a ciphertext batch folds into the B axis (the batched
              EvalPlan programs dispatch B independent ciphertexts here)
-    evk_b/a: (k, k+1, n) key-switch key digits over basis+special,
-             shared by the whole batch — or (k, k+1, B, n) per-batch
+    evk_b/a: (d, k+alpha, n) key-switch key digits over basis+specials,
+             shared by the whole batch — or (d, k+alpha, B, n) per-batch
              digits, for a Galois batch mixing rotation keys
-    t:       TablePack for k+1 primes (row k = the special prime P)
-    fsp:     optional FourStepPack for the same k+1 primes — when given,
+    t:       TablePack for k+alpha primes (rows k.. the special primes,
+             with the ``up``/``down`` conversion tables of grouped
+             digits and alpha > 1; see ``decompose_banks``)
+    fsp:     optional FourStepPack for the same k+alpha primes — when given,
              every NTT/iNTT stage dispatches through the large-N
              four-step banks pipeline (``ops.ntt_fourstep_banks``)
              instead of the single fused kernel.  Required for rings

@@ -10,13 +10,14 @@ lower to a single device dispatch; the host-orchestrated digit loop of
 ``fhe.keyswitch`` survives only as the bit-exact test oracle.
 
 Supported: encode/decode (complex slots), sk/pk encryption, add/sub,
-multiply + relinearization (digit keyswitch), rescale, slot rotation
-and conjugation via Galois automorphisms.  Scale is tracked exactly per
+multiply + relinearization (hybrid key switching: ``dnum`` digits of one
+or more primes, ``special_primes`` special primes), rescale, slot
+rotation and conjugation via Galois automorphisms.  Scale is tracked exactly per
 ciphertext, so prime-vs-scale drift cancels in decode.
 """
 from __future__ import annotations
 
-import functools
+import math
 
 import numpy as np
 import jax.numpy as jnp
@@ -31,15 +32,37 @@ __all__ = ["Ciphertext", "CkksContext", "galois_int_coeffs", "galois_poly"]
 
 
 class CkksContext:
+    """One CKKS key set over one prime chain.
+
+    The chain is the ``levels + 1 + special_primes`` largest primes
+    = 1 (mod 2n) below 2^30, descending: the ``special_primes`` (alpha)
+    largest are the special primes p_1..p_alpha (``specials``, P their
+    product), the rest q_0..q_L (``qs``).  Hybrid key switching cuts a
+    basis into digits: the top-level primes in consecutive groups of
+    ``group`` = ceil((L+1)/dnum), each cut to the primes the basis still
+    holds (``digit_groups``).  ``dnum=None`` (L+1 digits) and one special
+    prime give one single-prime digit per limb."""
+
     def __init__(self, n: int = 1024, levels: int = 3, scale_bits: int = 28,
-                 sigma: float = 3.2, seed: int = 0):
+                 sigma: float = 3.2, seed: int = 0, dnum: int | None = None,
+                 special_primes: int = 1):
+        limbs = levels + 1
+        self.dnum = limbs if dnum is None else dnum
+        if not 1 <= self.dnum <= limbs:
+            raise ValueError(f"dnum {self.dnum} outside 1..levels+1 = "
+                             f"1..{limbs}")
+        self.group = -(-limbs // self.dnum)               # primes per digit
+        if special_primes < self.group:
+            raise ValueError(
+                f"special_primes {special_primes} under the {self.group} "
+                "primes of a digit: P would lie below a digit's modulus")
         self.n = n
         self.slots = n // 2
         self.scale = float(1 << scale_bits)
         self.sigma = sigma
-        primes = rns.make_primes(n, levels + 2)           # L+1 chain + special
-        self.special = primes[0]                          # largest -> P
-        self.qs = tuple(primes[1:])                       # q_0 .. q_L
+        primes = rns.make_primes(n, limbs + special_primes)
+        self.specials = tuple(primes[:special_primes])    # largest -> P
+        self.qs = tuple(primes[special_primes:])          # q_0 .. q_L
         self.rng = np.random.default_rng(seed)
         # canonical embedding index table: e_j = 5^j mod 2n
         self._ejs = np.array([pow(5, j, 2 * n) for j in range(n // 2)])
@@ -51,7 +74,25 @@ class CkksContext:
         e = self._noise_poly(full)
         s = self._secret_poly(full)
         self.pk = (e.sub(a.mul(s)), a)                    # (b, a) = (-as + e, a)
+        self._ksk: dict = {}     # None (relin) | g -> top-level digit keys
         self._plan: EvalPlan | None = None
+
+    @property
+    def special(self) -> int:
+        """The special prime P of a one-prime gadget."""
+        if len(self.specials) != 1:
+            raise ValueError(f"{len(self.specials)} special primes: use "
+                             "specials")
+        return self.specials[0]
+
+    def digit_groups(self, basis) -> list[tuple[int, ...]]:
+        """The primes of each key-switch digit at ``basis``: the top-level
+        primes in consecutive groups of ``group``, each cut to the primes
+        ``basis`` holds, empty groups left out."""
+        held = set(basis)
+        cut = (tuple(q for q in self.qs[j:j + self.group] if q in held)
+               for j in range(0, len(self.qs), self.group))
+        return [g for g in cut if g]
 
     def plan(self) -> EvalPlan:
         """The device-resident evaluation plan for this context (built
@@ -71,38 +112,58 @@ class CkksContext:
                                    tuple(primes), self.n).to_ntt()
 
     def _make_ksk(self, from_key_coeffs_ntt: RnsPoly, primes: tuple[int, ...]):
-        """Digit keys: evk_i = (-a_i s + e_i + P*T_i*from_key, a_i) over
-        basis (primes..., P), T_i the CRT interpolation coefficient."""
-        full = primes + (self.special,)
+        """Digit keys over (primes..., specials), one per digit of
+        ``digit_groups(primes)``: evk_j = (-a_j s + e_j + P*T_j*from_key,
+        a_j), P the product of the special primes and T_j the CRT gadget
+        over Q = prod(primes): 1 mod the primes of digit j, 0 mod the
+        others."""
+        full = primes + self.specials
         s_full = self._secret_poly(full)
-        Q = 1
-        for q in primes:
-            Q *= q
+        Q, P = math.prod(primes), math.prod(self.specials)
         evk = []
         # from_key over full basis
         fk = from_key_coeffs_ntt
-        for i, qi in enumerate(primes):
-            Qi = Q // qi
-            Ti = Qi * pow(Qi % qi, -1, qi) % Q
-            PTi = self.special * Ti
+        for grp in self.digit_groups(primes):
+            Qj = math.prod(grp)
+            rest = Q // Qj
+            PTj = P * (rest * pow(rest % Qj, -1, Qj) % Q)
             a = rns.uniform_ntt(self.rng, full, self.n)
             e = self._noise_poly(full)
             b = e.sub(a.mul(s_full))
-            gadget = fk.mul_scalar_per_prime({q: PTi % q for q in full})
+            gadget = fk.mul_scalar_per_prime({q: PTj % q for q in full})
             evk.append((b.add(gadget), a))
         return evk
 
-    @functools.lru_cache(maxsize=None)
-    def relin_keys(self, primes: tuple[int, ...]):
-        full = primes + (self.special,)
-        s = self._secret_poly(full)
-        return self._make_ksk(s.mul(s), primes)
+    def _keys_at(self, g: int | None, basis) -> list:
+        """Digit keys of s^2 (``g`` None) or sigma_g(s) at ``basis``: made
+        once over the top-level basis; a lower basis takes the top-level
+        keys of its digits, cut to its own rows and the special rows."""
+        if g not in self._ksk:
+            full = self.qs + self.specials
+            s = self._secret_poly(full)
+            fk = (s.mul(s) if g is None else self._secret_poly(
+                full, coeffs=galois_int_coeffs(self._s_coeffs, g, self.n)))
+            self._ksk[g] = self._make_ksk(fk, self.qs)
+        top = self._ksk[g]
+        basis = tuple(basis)
+        if basis == self.qs:
+            return top
+        if not set(basis) <= set(self.qs):
+            raise ValueError(f"basis {basis} is not within the chain {self.qs}")
+        rows = jnp.asarray([self.qs.index(q) for q in basis]
+                           + list(range(len(self.qs), len(self.qs)
+                                        + len(self.specials))))
+        full = basis + self.specials
+        return [(RnsPoly(b.data[rows], full, True),
+                 RnsPoly(a.data[rows], full, True))
+                for (b, a), grp in zip(top, self.digit_groups(self.qs))
+                if set(grp) & set(basis)]
 
-    @functools.lru_cache(maxsize=None)
+    def relin_keys(self, primes: tuple[int, ...]):
+        return self._keys_at(None, primes)
+
     def galois_keys(self, g: int, primes: tuple[int, ...]):
-        full = primes + (self.special,)
-        sg = self._secret_poly(full, coeffs=galois_int_coeffs(self._s_coeffs, g, self.n))
-        return self._make_ksk(sg, primes)
+        return self._keys_at(g, primes)
 
     # -------------------------------------------------- encode / decode
 

@@ -11,8 +11,11 @@ pipelined dataflow rather than per-stage host round trips.  An
   * the stacked twiddle tables the bank kernels consume (TablePack for
     single-kernel rings, FourStepPack + scalar pack past
     ``ops.FOURSTEP_MIN_N``),
-  * stacked evaluation / Galois key tensors — ``(k_digits, k+1, n)``
+  * stacked evaluation / Galois key tensors — ``(digits, k+alpha, n)``
     device arrays instead of Python lists of RnsPoly pairs,
+  * for grouped digits (several primes a digit) and alpha > 1 special
+    primes, the centred base-conversion tables of the mod-up and the
+    mod-down (``keyswitch_tables``),
   * NTT-domain Galois gather rows (``core.params.galois_eval_perm``)
     plus the coefficient-domain index/sign tables, and
   * the per-prime ``pinv`` scalar columns of every mod-down,
@@ -62,8 +65,10 @@ modular arithmetic has no association-order effects), pinned by
 tests/test_sharded_eval.py.
 
 Key generation is host-side by design (the CMOS coprocessor role): the
-plan asks its ``CkksContext`` for key material once per basis and keeps
-only the stacked device tensors.
+``CkksContext`` makes each key once over the top-level basis, a lower
+basis takes its rows, and the plan keeps only the stacked device tensors
+per basis.  The plan takes every key-switch shape the context has;
+meshes take single-prime digits and one special prime only.
 """
 from __future__ import annotations
 
@@ -504,6 +509,11 @@ _JITTED_PROGRAMS = (multiply_banks, rescale_banks, galois_ks_banks,
 _SHARDED_PROGRAMS: list = []
 
 
+_GROUPED_MESH = ("grouped-digit key switching (dnum below the limb count, or "
+                 "more than one special prime) has no sharded programs: "
+                 "run it on an EvalPlan without a mesh")
+
+
 @functools.lru_cache(maxsize=None)
 def sharded_many_programs(mesh, use_pallas: bool | None = None,
                           tile: int | None = None) -> dict:
@@ -525,7 +535,9 @@ def sharded_many_programs(mesh, use_pallas: bool | None = None,
     for a collective-free program).
 
     Cached per (mesh, use_pallas, tile) — ``Mesh`` is hashable — and
-    appended to ``_SHARDED_PROGRAMS`` for ``trace_count``."""
+    appended to ``_SHARDED_PROGRAMS`` for ``trace_count``.  Tables of a
+    grouped-digit key switch (``up``/``down`` conversion tables) raise
+    ``NotImplementedError``: the twins take single-prime digits only."""
     ct = PartitionSpec("b")                    # leading batch axis sharded
     rep = PartitionSpec()                      # replicated tables/keys
     key_b = PartitionSpec(None, None, "b")     # (k, k+1, B, n) key stacks
@@ -541,7 +553,13 @@ def sharded_many_programs(mesh, use_pallas: bool | None = None,
                                       mesh=mesh, in_specs=in_specs,
                                       out_specs=out_specs, check_vma=False))
         _SHARDED_PROGRAMS.append(fn)
-        return fn
+
+        def call(*args):
+            if any(isinstance(a, dict) and ("up" in a or "down" in a)
+                   for a in args):
+                raise NotImplementedError(_GROUPED_MESH)
+            return fn(*args)
+        return call
 
     return {
         "multiply": build(_multiply_many_impl,
@@ -585,6 +603,8 @@ class EvalPlan:
         self.mesh = mesh
         self._sharded = None
         self._kmesh = False
+        if mesh is not None and (ctx.group > 1 or len(ctx.specials) > 1):
+            raise NotImplementedError(_GROUPED_MESH)
         if mesh is not None:
             bad = set(mesh.axis_names) - {"b", "k"}
             if bad:
@@ -602,6 +622,7 @@ class EvalPlan:
         self._keys: dict = {}        # ('relin', basis) | ('galois', g, basis)
         self._batch_keys: dict = {}  # (gs tuple, basis) -> stacked, bounded
         self._idx: dict[int, jnp.ndarray] = {}
+        self._ks_tables: dict = {}           # basis -> (t, fsp)
         self._rescale_tables: dict = {}      # basis -> (t, fsp) views
         self.reset_stats()
 
@@ -670,17 +691,20 @@ class EvalPlan:
         return sum(getattr(p, "_cache_size", lambda: 0)()
                    for p in _JITTED_PROGRAMS + tuple(_SHARDED_PROGRAMS))
 
-    def _count(self, dispatches=1, key_switches=0, decomposes=0):
+    def _count(self, dispatches=1, key_switches=0, decomposes=0, digits=0):
         self.stats["dispatches"] += dispatches
         self.stats["key_switches"] += key_switches
         self.stats["decomposes"] += decomposes
         if obs.enabled():
             # mirror into the obs metrics registry: the stats dict stays
             # the per-plan source of truth (tests pin its exact values),
-            # the registry accumulates process-wide for the snapshot
+            # the registry accumulates process-wide for the snapshot;
+            # plan.digits counts the digits mod-upped (decomposes times
+            # the digits of the basis) and has no stats entry
             obs.counter_add("plan.dispatches", dispatches)
             obs.counter_add("plan.key_switches", key_switches)
             obs.counter_add("plan.decomposes", decomposes)
+            obs.counter_add("plan.digits", digits)
 
     # ------------------------------------------------------------ tables
 
@@ -693,7 +717,33 @@ class EvalPlan:
         return rns.basis_pack(full, self.n), None
 
     def keyswitch_tables(self, basis: tuple[int, ...]):
-        return self._packs(basis + (self.ctx.special,))
+        """(t, fsp) of a key switch at ``basis``: the packs of basis +
+        specials.  Digits of more than one prime add the mod-up's
+        conversion tables (``t["up"]``), and more than one special prime
+        the mod-down's (``t["down"]``) with P^-1 rows for their product;
+        single-prime digits and one special prime add nothing, so their
+        programs are the plain centred lift and a one-row mod-down."""
+        if basis not in self._ks_tables:
+            ctx = self.ctx
+            full = basis + ctx.specials
+            t, fsp = self._packs(full)
+            groups = ctx.digit_groups(basis)
+            if max(map(len, groups)) > 1:
+                if basis != ctx.qs[:len(basis)]:
+                    raise ValueError(f"basis {basis} is not a prefix of the "
+                                     "chain: its digits are not consecutive")
+                t = dict(t, up=FB.base_conv_tables(groups, full))
+            if len(ctx.specials) > 1:
+                t = dict(t, **FB.pinv_pack(full, len(ctx.specials)),
+                         down=FB.base_conv_tables([ctx.specials], basis))
+            self._ks_tables[basis] = (t, fsp)
+        return self._ks_tables[basis]
+
+    def _ks_span(self, basis) -> dict:
+        """Span args of a key-switching program at ``basis``: its digits
+        and special primes."""
+        return {"digits": len(self.ctx.digit_groups(basis)),
+                "alpha": len(self.ctx.specials)}
 
     def rescale_tables(self, basis: tuple[int, ...]):
         if basis not in self._rescale_tables:
@@ -721,7 +771,7 @@ class EvalPlan:
         return self._keys[key]
 
     def relin_key(self, basis: tuple[int, ...]):
-        """(k, k+1, n) stacked relinearization key digit tensors."""
+        """(digits, k+alpha, n) stacked relinearization key tensors."""
         return self._stacked(("relin", basis),
                              lambda: self.ctx.relin_keys(basis))
 
@@ -878,13 +928,14 @@ class EvalPlan:
         basis = a.primes
         t, fsp = self.keyswitch_tables(basis)
         eb, ea = self.relin_key(basis)
-        with obs.span("plan.program", program="multiply"):
+        ks = self._ks_span(basis)
+        with obs.span("plan.program", program="multiply", **ks):
             c0, c1 = multiply_banks(self._shard_k(a.c0.data),
                                     self._shard_k(a.c1.data),
                                     self._shard_k(b.c0.data),
                                     self._shard_k(b.c1.data),
                                     eb, ea, t, fsp, **self._kw)
-        self._count(1, key_switches=1, decomposes=1)
+        self._count(1, key_switches=1, decomposes=1, digits=ks["digits"])
         return Ciphertext(RnsPoly(c0, basis, True), RnsPoly(c1, basis, True),
                           a.scale * b.scale)
 
@@ -906,12 +957,13 @@ class EvalPlan:
         basis = a.primes
         t, fsp = self.keyswitch_tables(basis)
         eb, ea = self.galois_key(g, basis)
-        with obs.span("plan.program", program="galois_ks"):
+        ks = self._ks_span(basis)
+        with obs.span("plan.program", program="galois_ks", **ks):
             c0, c1 = galois_ks_banks(self._shard_k(a.c0.data),
                                      self._shard_k(a.c1.data),
                                      self.eval_idx(g),
                                      eb, ea, t, fsp, **self._kw)
-        self._count(1, key_switches=1, decomposes=1)
+        self._count(1, key_switches=1, decomposes=1, digits=ks["digits"])
         return Ciphertext(RnsPoly(c0, basis, True), RnsPoly(c1, basis, True),
                           a.scale)
 
@@ -955,8 +1007,9 @@ class EvalPlan:
         t, fsp = self.keyswitch_tables(basis)
         eb, ea = self.relin_key(basis)
         stack = lambda ps: _stack_ct_banks([p.data for p in ps])
+        ks = self._ks_span(basis)
         with obs.span("plan.program", program="multiply_many", n=len(As),
-                      sharded=self._sharded is not None):
+                      sharded=self._sharded is not None, **ks):
             if self._sharded is not None:
                 Ap, Bp = self._pad_batch(list(As)), self._pad_batch(list(Bs))
                 c0, c1 = self._sharded["multiply"](
@@ -971,7 +1024,8 @@ class EvalPlan:
                     self._shard_k(stack([b.c1 for b in Bs])),
                     eb, ea, t, fsp, **self._kw)
                 retire_donated(c0, a0s, a1s)
-        self._count(1, key_switches=len(As), decomposes=len(As))
+        self._count(1, key_switches=len(As), decomposes=len(As),
+                    digits=len(As) * ks["digits"])
         return [Ciphertext(RnsPoly(r0, basis, True),
                            RnsPoly(r1, basis, True), a.scale * b.scale)
                 for r0, r1, a, b in zip(_unstack_banks(c0),
@@ -1021,8 +1075,9 @@ class EvalPlan:
             check_level("galois_ks_many", ct)
         basis = self._common_basis("galois_ks_many", cts)
         t, fsp = self.keyswitch_tables(basis)
+        ks = self._ks_span(basis)
         with obs.span("plan.program", program="galois_ks_many", n=len(cts),
-                      sharded=self._sharded is not None):
+                      sharded=self._sharded is not None, **ks):
             if self._sharded is not None:
                 pad_cts = self._pad_batch(list(cts))
                 pad_gs = self._pad_batch(list(gs))
@@ -1047,7 +1102,8 @@ class EvalPlan:
                 c0, c1 = galois_ks_many_banks(s0, s1, idx, eb, ea, t, fsp,
                                               **self._kw)
                 retire_donated(c0, s0, s1)
-        self._count(1, key_switches=len(cts), decomposes=len(cts))
+        self._count(1, key_switches=len(cts), decomposes=len(cts),
+                    digits=len(cts) * ks["digits"])
         return [Ciphertext(RnsPoly(r0, basis, True),
                            RnsPoly(r1, basis, True), ct.scale)
                 for r0, r1, ct in zip(_unstack_banks(c0),
@@ -1072,8 +1128,9 @@ class EvalPlan:
         check_level("hoisted_galois", a)
         basis = a.primes
         t, fsp = self.keyswitch_tables(basis)
+        ks = self._ks_span(basis)
         with obs.span("plan.program", program="hoisted_galois", n=len(gs),
-                      sharded=self._sharded is not None):
+                      sharded=self._sharded is not None, **ks):
             if self._sharded is not None:
                 # shard the rotation axis: pad gs to the mesh width and
                 # drop the pad columns on unpack (each shard re-runs the
@@ -1087,7 +1144,8 @@ class EvalPlan:
                 c0, c1 = hoisted_rotations_banks(self._shard_k(a.c0.data),
                                                  self._shard_k(a.c1.data), idx,
                                                  eb, ea, t, fsp, **self._kw)
-        self._count(1, key_switches=len(gs), decomposes=1)
+        self._count(1, key_switches=len(gs), decomposes=1,
+                    digits=ks["digits"])
         return [Ciphertext(RnsPoly(r0, basis, True),
                            RnsPoly(r1, basis, True), a.scale)
                 for r0, r1 in zip(_unstack_banks(c0, axis=1)[:len(gs)],

@@ -10,9 +10,11 @@ NTT/iNTT go through the multi-prime "banks" entry points
 (``kernels.ops.ntt_banks``), so k residue rows transform in one fused
 (prime, batch_tile) dispatch instead of a Python per-row loop.
 
-Base conversions here are *exact* because our digit decomposition uses
-single-prime digits (alpha=1): lifting a centered residue from one
-30-bit prime to another basis involves no approximation.
+The host base conversion here lifts a centered residue of ONE 30-bit
+prime to another basis, which is exact (the single-prime digits and the
+one-prime mod-down of the test oracle ``fhe.keyswitch``); digits of
+several primes and several special primes take the device's centred fast
+base conversion (``fhe.batched.decompose_banks`` / ``mod_down_banks``).
 """
 from __future__ import annotations
 

@@ -48,7 +48,8 @@ import numpy as np
 from repro import obs
 from repro.core.modmath import dtype_bits
 from repro.core.params import NTTParams, bitrev_perm
-from repro.kernels import autotune, ntt_kernel, dyadic_kernel, ref
+from repro.kernels import (autotune, base_conv_kernel, dyadic_kernel,
+                           ntt_kernel, ref)
 
 # Single-kernel tile budget: below this ring size the whole log2(n)-stage
 # transform runs as ONE fused banks kernel; at or above it the large-N
@@ -499,6 +500,31 @@ def dyadic_inner_banks(ext, evk, t: dict, *, use_pallas: bool | None = None,
     out = dyadic_kernel.dyadic_inner_banks(
         ext, evk, t["qs"], t["mu"], digits=d, tile=tile, lazy=lazy)
     return out[:, :b]
+
+
+@_spanned
+def base_conv_banks(x, tables: dict, *, name: str = "base_conv_banks",
+                    use_pallas: bool | None = None, tile: int | None = None):
+    """Centred fast base conversion of digit groups (grouped-digit key
+    switching): x (d, m, B, n) u32 coefficient rows, group j's slot i
+    reduced mod its source prime (a short group padded with zero rows);
+    tables: ``fhe.batched.base_conv_tables``.  Returns (t, d, B, n), the
+    conversion of each group to the t target primes, prime-major.
+    ``name`` names the kernel's op in a profile."""
+    use_pallas = _on_tpu() if use_pallas is None else use_pallas
+    x = jnp.asarray(x)
+    args = tuple(tables[k] for k in ("src", "inv", "inv_p", "dst", "f",
+                                     "f_p", "off"))
+    if not use_pallas:
+        return ref.base_conv_banks_ref(x, *args)
+    d, m, b, n = x.shape
+    tile = autotune.resolve_tile("base_conv_banks", d * m, n, b, tile)
+    pad = (-b) % tile
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros((d, m, pad, n), x.dtype)], axis=2)
+    out = base_conv_kernel.base_conv_banks_pallas(x, *args, tile=tile,
+                                                  name=name)
+    return out[:, :, :b]
 
 
 @_spanned

@@ -185,3 +185,20 @@ def dyadic_inner_banks_ref(ext, evk, qs, mus, lazy: bool = False):
 
     acc, _ = jax.lax.scan(body, prods[0], prods[1:])
     return acc
+
+
+def base_conv_banks_ref(x, src, inv, inv_p, dst, f, f_p, off):
+    """Centred fast base conversion (``kernels.base_conv_kernel``): x
+    (d, m, B, n) source rows, slot (j, i) mod src[j, i]; src/inv/inv_p
+    (d, m); dst (t,); f/f_p/off (d, m, t).  Returns (t, d, B, n), every
+    value canonical, as the kernel's."""
+    q = dst[:, None, None, None]
+    y = mulmod_shoup(x, inv[..., None, None], inv_p[..., None, None],
+                     src[..., None, None])
+    neg = y > (src >> 1)[..., None, None]
+    acc = jnp.zeros((dst.shape[0],) + y[:, 0].shape, y.dtype)
+    for i in range(x.shape[1]):
+        col = lambda a: a[:, i].T[..., None, None]             # (t, d, 1, 1)
+        yt = jnp.where(neg[None, :, i], y[None, :, i] + col(off), y[None, :, i])
+        acc = addmod(acc, mulmod_shoup(yt, col(f), col(f_p), q), q)
+    return acc

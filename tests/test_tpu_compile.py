@@ -16,7 +16,12 @@ is needed, and nothing runs.  The widths:
     special prime), shared-key and per-batch-key layouts;
   * the Galois automorphism at 2^14 (one XLA gather, no kernel);
   * ML-KEM's u16 ring (n=256, q=3329) NTT/iNTT and basemul, computing
-    in u32 lanes.
+    in u32 lanes;
+  * the grouped-digit key switch of the 2^15 ring (21 limbs, 3 digits of
+    7 primes, 7 special primes, B = 32): the base-conversion kernel's
+    mod-up (7 primes to 28, three digits) and mod-down (7 to 21, both
+    halves), and the four-step passes of its 256 x 128 split over the
+    28-prime digit extensions.
 
 The topology is described inside a module fixture (never at import
 time) and the persistent compilation cache is off around the compiles:
@@ -34,7 +39,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.params import fourstep_split
 from repro.core.ringspec import MLKEM_RING, ring_table_pack
-from repro.kernels import dyadic_kernel, ntt_kernel, ops
+from repro.kernels import base_conv_kernel, dyadic_kernel, ntt_kernel, ops
 
 N_RING = 1 << 14
 N_PASS = fourstep_split(N_RING)[0]          # 128
@@ -77,9 +82,8 @@ def _banks_tables(s, k, n, stages):
         ninv=_sds(s, (k,)))
 
 
-def _compile_pass(s, k, rows, inverse):
-    """Compile one lazy 128-point banks NTT pass over (k, rows, 128)."""
-    n = N_PASS
+def _compile_pass(s, k, rows, inverse, n=N_PASS):
+    """Compile one lazy n-point banks NTT pass over (k, rows, n)."""
     stages = n.bit_length() - 1
     t = _banks_tables(s, k, n, stages)
     x = _sds(s, (k, rows, n))
@@ -183,4 +187,35 @@ def test_mlkem_basemul_u16_ring(one_chip):
     c = _compile(dyadic_kernel.dyadic_basemul_banks, x, x, t["qs"], t["mu"],
                  t["gamma"], t["gammap"], tile=TILE, lazy=True, bits=16,
                  interpret=False)
+    assert "tpu_custom_call" in c.as_text()
+
+
+# The grouped-digit key switch at N = 2^15: 21 limbs in 3 digits of 7
+# primes, 7 special primes, B = 32 ciphertexts.
+N15, K15, ALPHA15, DIGITS15 = 1 << 15, 21, 7, 3
+
+
+@pytest.mark.parametrize("d,targets,b", [(DIGITS15, K15 + ALPHA15, B),
+                                         (1, K15, 2 * B)],
+                         ids=["mod_up", "mod_down"])
+def test_base_conv_banks_2_15(one_chip, d, targets, b):
+    s, m = one_chip, ALPHA15
+    c = _compile(base_conv_kernel.base_conv_banks_pallas,
+                 _sds(s, (d, m, b, N15)), _sds(s, (d, m)), _sds(s, (d, m)),
+                 _sds(s, (d, m)), _sds(s, (targets,)),
+                 _sds(s, (d, m, targets)), _sds(s, (d, m, targets)),
+                 _sds(s, (d, m, targets)), tile=TILE,
+                 name=base_conv_kernel.MODUP, interpret=False)
+    assert "tpu_custom_call" in c.as_text()
+    assert base_conv_kernel.MODUP in c.as_text()
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+@pytest.mark.parametrize("n", list(fourstep_split(N15)), ids=["n1", "n2"])
+def test_ntt_banks_fourstep_pass_2_15(one_chip, n, inverse):
+    """The 256 x 128 split of the 2^15 ring over the key switch's digit
+    extensions: 28 primes, 3 digits x B ciphertexts x the other factor."""
+    assert fourstep_split(N15) == (256, 128)
+    rows = DIGITS15 * B * (N15 // n)
+    c = _compile_pass(one_chip, K15 + ALPHA15, rows, inverse, n=n)
     assert "tpu_custom_call" in c.as_text()
