@@ -11,13 +11,12 @@ the four-step (Bailey) decomposition with N = N1*N2:
   4. NTT_N2 along rows (root w^N1)               -> D[k1, k2]
   and A_hat[k2*N1 + k1] = D[k1, k2].
 
-On one device, ``fourstep_ntt``/``fourstep_intt`` dispatch both NTT
-passes to the fused multi-prime banks kernels (``kernels.ops``): the N2
-columns (then N1 rows) fold into the kernel batch so each pass is one
-(prime, batch_tile) grid, and the step-3 twiddle correction runs as the
-fused ``twiddle_mul_banks`` kernel — the software form of the paper's
-"K NTT-128 units + reorder network".  Off-TPU the same entry points
-fall back to the vmap reference path (see ``kernels.ops`` policy).
+On one device, ``fourstep_ntt``/``fourstep_intt`` dispatch to
+``kernels.ops.ntt_fourstep_banks``: on a TPU one kernel runs both
+passes, the step-3 twiddle and the reorder on polynomials held in VMEM
+— the software form of the paper's "K NTT-128 units + reorder
+network".  Off-TPU the same entry points fall back to the multi-pass
+reference (see ``kernels.ops`` policy).
 
 On a TPU mesh the reorder network becomes a collective: columns sharded
 across chips -> local column NTTs + local twiddle -> **all-to-all** (one
@@ -132,9 +131,8 @@ def fourstep_ntt(a, fsp: FourStepParams, negacyclic: bool = False, *,
     """a: (..., n) u32 -> natural-order NTT via the four-step path.
 
     The functional model of the paper's Fig 21 schedule, dispatched to
-    the fused banks kernels: both passes and the step-3 twiddle run
-    through ``kernels.ops.{ntt_banks,twiddle_mul_banks}`` as a k=1 bank
-    row (vmap reference off-TPU, Pallas grid on TPU)."""
+    ``kernels.ops.ntt_fourstep_banks`` as a k=1 bank row (the one-kernel
+    transform on TPU, the multi-pass reference elsewhere)."""
     fp = _banks_pack(fsp.n1, fsp.n2, fsp.q)
     return ops.ntt_fourstep_banks(jnp.asarray(a)[None], fp,
                                   negacyclic=negacyclic,

@@ -26,6 +26,17 @@ factor tables the §IX four-step banks pipeline consumes
   psi/psip  (k, n)       negacyclic psi^i pre-weights (natural order)
   ipsi/ipsip(k, n)       psi^-i post-weights (NO n^-1 fold: the two
                          sub-iNTT passes already contribute 1/N1 * 1/N2)
+and the tables of the one-kernel transform (``ntt_kernel.fourstep_*``),
+each with its Shoup companion (suffix ``p``):
+  pre       (k, n1, n2)      psi^i, rows in bit-reversed order
+  post      (k, n1, n2)      psi^-i * n^-1, rows in bit-reversed order
+  fwd_a     (k, n1 + 16, n2) ``row_twiddles`` of the n1-point root,
+  inv_a                      and of its inverse, broadcast over lanes
+  fwd_c     (k, n2 + 16, n1) the same for the n2-point root
+  inv_c
+  ninv/ninv_p (k,)           n^-1 (the cyclic inverse)
+The multi-pass entries feed the reference pipeline
+(``ref.ntt_fourstep_banks_ref``), the one-kernel entries the kernel.
 """
 from __future__ import annotations
 
@@ -40,8 +51,9 @@ import numpy as np
 from repro.core.modmath import (addmod, submod, mulmod_shoup, mulmod_barrett,
                                 shoup_precompute, barrett_precompute)
 from repro.core.ntt import cg_ntt, cg_intt
-from repro.core.params import make_ntt_params
+from repro.core.params import bitrev_perm, make_ntt_params
 from repro.kernels import base_conv_kernel, ops
+from repro.kernels.ntt_kernel import row_twiddles
 
 
 @dataclasses.dataclass
@@ -177,7 +189,7 @@ def fourstep_pack_from_params(fsps: list) -> dict:
         return jnp.asarray(np.stack(
             [np.asarray(getattr(f, name)).reshape(-1) for f in fsps]))
 
-    return {
+    pack = {
         "qs": jnp.asarray(np.array([f.q for f in fsps], dtype=np.uint32)),
         "pack1": pack_from_ntt_params([f.p1 for f in fsps]),
         "pack2": pack_from_ntt_params([f.p2 for f in fsps]),
@@ -186,6 +198,45 @@ def fourstep_pack_from_params(fsps: list) -> dict:
         "psi": flat("psi_mat"), "psip": flat("psi_mat_p"),
         "ipsi": flat("ipsi_mat"), "ipsip": flat("ipsi_mat_p"),
     }
+    rows = {name: [] for name in _KERNEL_TABLES}
+    for f in fsps:
+        for name, v in _kernel_tables(f).items():
+            rows[name].append(v)
+    pack.update({name: jnp.asarray(np.stack(v)) for name, v in rows.items()})
+    return pack
+
+
+_KERNEL_TABLES = ("pre", "prep", "post", "postp", "fwd_a", "fwd_ap",
+                  "inv_a", "inv_ap", "fwd_c", "fwd_cp", "inv_c", "inv_cp",
+                  "ninv", "ninv_p")
+
+
+def _shoup_np(w: np.ndarray, q: int) -> np.ndarray:
+    return ((w.astype(np.uint64) << np.uint64(32)) // np.uint64(q)
+            ).astype(np.uint32)
+
+
+def _kernel_tables(f) -> dict:
+    """One prime's tables of the one-kernel four-step transform (see the
+    module docstring), from its ``core.fourstep.FourStepParams``."""
+    q, n1, n2 = f.q, f.n1, f.n2
+    brev = bitrev_perm(n1)
+    ninv = pow(n1 * n2, -1, q)
+    post = (f.ipsi_mat.astype(np.uint64) * ninv % q).astype(np.uint32)[brev]
+
+    def lanes(root, r, width):
+        col = row_twiddles(root, q, r)
+        return np.broadcast_to(col[:, None], (col.size, width))
+
+    out = {"pre": f.psi_mat[brev], "post": post,
+           "fwd_a": lanes(f.p1.omega, n1, n2),
+           "inv_a": lanes(pow(f.p1.omega, -1, q), n1, n2),
+           "fwd_c": lanes(f.p2.omega, n2, n1),
+           "inv_c": lanes(pow(f.p2.omega, -1, q), n2, n1)}
+    out.update({f"{name}p": _shoup_np(v, q) for name, v in list(out.items())})
+    out["ninv"] = np.uint32(ninv)
+    out["ninv_p"] = np.uint32(shoup_precompute(ninv, q))
+    return out
 
 
 def build_fourstep_pack(primes: list[int], n: int, n1: int | None = None,
@@ -205,7 +256,8 @@ def build_fourstep_pack(primes: list[int], n: int, n1: int | None = None,
 
 def slice_fourstep_pack(fp: dict, rows) -> dict:
     """View of a FourStepPack restricted to prime rows ``rows``."""
-    flat = ("qs", "tw", "twp", "itw", "itwp", "psi", "psip", "ipsi", "ipsip")
+    flat = ("qs", "tw", "twp", "itw", "itwp", "psi", "psip", "ipsi",
+            "ipsip") + _KERNEL_TABLES
     return {"pack1": slice_pack(fp["pack1"], rows),
             "pack2": slice_pack(fp["pack2"], rows),
             **{k: fp[k][rows] for k in flat}}

@@ -375,17 +375,6 @@ def _run_dyadic(k, n, b, mac=False):
     return lambda tile: ops.dyadic_mul(a, c, p, use_pallas=True, tile=tile)
 
 
-def _run_twiddle_mul_banks(k, n, b):
-    from repro.kernels import ops
-    t = _pack(k, n)
-    x = np.stack([_rng_rows((b, n), q) for q in np.asarray(t["qs"])])
-    w = np.asarray(t["psi"])
-    wp = np.asarray(t["psip"])
-    qs = np.asarray(t["qs"])
-    return lambda tile: ops.twiddle_mul_banks(x, w, wp, qs, use_pallas=True,
-                                              tile=tile)
-
-
 def _run_dyadic_inner_banks(k, n, b):
     from repro.kernels import ops
     t = _pack(k, n)
@@ -406,7 +395,6 @@ _RUNNERS = {
     "dyadic_mac": functools.partial(_run_dyadic, mac=True),
     "ntt_banks": _run_ntt_banks,
     "intt_banks": functools.partial(_run_ntt_banks, inverse=True),
-    "twiddle_mul_banks": _run_twiddle_mul_banks,
     "dyadic_inner_banks": _run_dyadic_inner_banks,
     "serve_batch": _run_ntt_banks,              # batch-shaped proxy
 }

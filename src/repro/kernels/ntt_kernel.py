@@ -29,14 +29,13 @@ rings such as ML-KEM's q=3329 (a 16x16 product is exact in u32, so the
 Shoup high part is a plain shift).  Callers narrow to u16 only at their
 byte boundary.
 
-Two kernel families live here:
+Three kernel families live here:
 
 * Single-prime (``ntt_fwd_pallas`` / ``ntt_inv_pallas``): grid over
   batch tiles only; the modulus and all derived constants are static.
 
 * Multi-prime "NTT banks" (``ntt_fwd_banks_pallas`` /
-  ``ntt_inv_banks_pallas``, plus the four-step step-3 companion
-  ``twiddle_mul_banks_pallas``): the paper's Fig 22 bank array, where 8
+  ``ntt_inv_banks_pallas``): the paper's Fig 22 bank array, where 8
   NTT units process the RNS prime rows in parallel.  The grid is
   ``(prime, row_block)`` over the TablePack layout of
   ``fhe.batched.build_table_pack``:
@@ -60,10 +59,15 @@ Two kernel families live here:
   masks and the broadcast twiddle rows are built once per program at
   (8, n) and broadcast over the stack.
 
-VMEM per program (n=128 pass of the 2^14 four-step): a 512-row block
-of 256 KiB in and 256 KiB out, each double-buffered (1 MiB), plus lane
-twiddles 2*7*128*4 = 7 KiB; a [9, 32768, 128] pass is a (9, 64) grid
-instead of (9, 4096) steps of one 4 KiB vreg.
+  VMEM per program at n = 128: a 512-row block of 256 KiB in and
+  256 KiB out, each double-buffered (1 MiB), plus lane twiddles
+  2*7*128*4 = 7 KiB.
+
+* The large-N four-step transform in one kernel per direction
+  (``fourstep_fwd_banks_pallas`` / ``fourstep_inv_banks_pallas``, see
+  that section): grid (prime, block of whole polynomials), both passes,
+  the twiddle correction, the transposition and the bit reversals in
+  VMEM.  A 2^14 block is 4 polynomials (256 KiB), a 2^15 block 2.
 """
 from __future__ import annotations
 
@@ -78,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.core.modmath import _shoup16_lazy_u32, mulhi_u32
+from repro.core.params import bitrev
 from repro.kernels import resolve_interpret
 
 U32 = jnp.uint32
@@ -152,33 +157,40 @@ def _partner(x, m, a_is_partner):
 # values ride in [0, 2q) (see core.modmath's lazy contract) and the
 # caller owns the epilogue reduction.
 
+def _ct(a, b, w, wp, q, lazy, bits):
+    """Cooley-Tukey butterfly: (a + w*b, a - w*b)."""
+    if lazy:
+        q2 = q + q
+        t = _shoup_lazy(b, w, wp, q, bits)
+        return _lazy_add(a, t, q2), _lazy_sub(a, t, q2)
+    t = _shoup(b, w, wp, q, bits)
+    return _addmod(a, t, q), _submod(a, t, q)
+
+
+def _gs(a, b, w, wp, q, lazy, bits):
+    """Gentleman-Sande butterfly: (a + b, (a - b)*w)."""
+    if lazy:
+        q2 = q + q
+        return (_lazy_add(a, b, q2),
+                _shoup_lazy(_lazy_sub(a, b, q2), w, wp, q, bits))
+    return _addmod(a, b, q), _shoup(_submod(a, b, q), w, wp, q, bits)
+
+
 def _fwd_stages(x, consts, q, lazy, bits):
-    q2 = q + q
     for m, w, wp, is_lo, a_is_partner in consts:
         y = _partner(x, m, a_is_partner)
         hi = jnp.where(is_lo, y, x)
         lo = jnp.where(is_lo, x, y)
-        if lazy:
-            tt = _shoup_lazy(hi, w, wp, q, bits)
-            x = jnp.where(is_lo, _lazy_add(lo, tt, q2), _lazy_sub(lo, tt, q2))
-        else:
-            tt = _shoup(hi, w, wp, q, bits)
-            x = jnp.where(is_lo, _addmod(lo, tt, q), _submod(lo, tt, q))
+        x = jnp.where(is_lo, *_ct(lo, hi, w, wp, q, lazy, bits))
     return x
 
 
 def _inv_stages(x, consts, q, lazy, bits):
-    q2 = q + q
     for m, w, wp, is_lo, a_is_partner in reversed(consts):
         y = _partner(x, m, a_is_partner)
         e = jnp.where(is_lo, x, y)
         o = jnp.where(is_lo, y, x)
-        if lazy:
-            x = jnp.where(is_lo, _lazy_add(e, o, q2),
-                          _shoup_lazy(_lazy_sub(e, o, q2), w, wp, q, bits))
-        else:
-            x = jnp.where(is_lo, _addmod(e, o, q),
-                          _shoup(_submod(e, o, q), w, wp, q, bits))
+        x = jnp.where(is_lo, *_gs(e, o, w, wp, q, lazy, bits))
     return x
 
 
@@ -440,7 +452,7 @@ def ntt_inv_banks_pallas(x, qs, ninv, ninvp, itw, itwp, post, postp, *,
                             [post, postp], tile=tile, interpret=interpret)
 
 
-# ------------------------------------------- four-step twiddle multiply
+# ---------------------------------------------- elementwise lane blocks
 
 # Lane block of the elementwise banks kernels: a 2^14 ring row is split
 # into 2048-lane chunks so a program's double-buffered blocks stay far
@@ -452,35 +464,267 @@ def lane_block(n: int) -> int:
     return min(n, LANE_BLOCK)
 
 
-def _twiddle_mul_banks_kernel(x_ref, q_ref, w_ref, wp_ref, o_ref, *,
-                              lazy: bool):
-    """Step 3 of the four-step schedule (paper §IX): the pointwise
-    w^(j2*k1) correction between the column and row NTT passes, fused as
-    one (prime, batch_tile, lane_chunk) Shoup multiply.  The same kernel
-    applies the negacyclic psi^i pre-weights / psi^-i post-weights.
-    lazy=True emits the [0, 2q) Shoup representative; either way any
-    u32 input representative is accepted."""
+# ---------------------------------------- four-step NTT in one kernel
+#
+# The large-N transform (paper §IX) runs whole polynomials through ONE
+# program: a block of polynomials is read once from HBM in its natural
+# (n1, n2) layout (x[j1*n2 + j2] at row j1, lane j2), transformed in
+# VMEM, and written once in the natural frequency order
+# A_hat[k2*n1 + k1], which is the (n2, n1) layout.  Between the two
+# passes the block is transposed in VMEM, so both passes run along the
+# row (sublane) axis:
+#
+#   rows in bit-reversed order -> psi pre-weight -> DIT network along
+#   the n1 rows (natural k1 out) -> step-3 twiddle w^(j2*k1) ->
+#   transpose -> DIF network along the n2 rows (bit-reversed k2 out) ->
+#   rows back in natural order
+#
+# and the inverse mirrors it (DIT with the inverse root along n2, the
+# inverse twiddle, DIF along n1, then psi^-i * n^-1).  A row network
+# pairs whole vregs at distances of 8 rows or more, so each of those
+# stages multiplies n/2 values and needs no rotation; below 8 rows it
+# pairs sublanes by rolls.  The bit reversal of the rows is a strided
+# load or store of 8-row groups plus one fixed sublane permutation.
+# Values (the lazy [0, 2q) band between stages included) never leave
+# VMEM, and the reduced output equals the multi-pass reference
+# (``ref.ntt_fourstep_banks_ref``) bit for bit.
+
+def _row_stage_offsets(r: int) -> list[tuple[int, int, int]]:
+    """(m, first table row, table rows) of each stage of an r-row
+    network, pair distance m = 1, 2, ..., r/2 (the DIT order; DIF runs
+    them reversed).  A stage with m >= 8 has one table row per pair
+    index i < m; a stage inside a vreg has one per sublane s (pair
+    index s mod m).  r + 16 rows in all."""
+    assert r >= SUBLANES and r & (r - 1) == 0, r
+    out, off, m = [], 0, 1
+    while m < r:
+        rows = max(m, SUBLANES)
+        out.append((m, off, rows))
+        off, m = off + rows, 2 * m
+    return out
+
+
+def row_twiddles(root: int, q: int, r: int) -> np.ndarray:
+    """(r + 16,) twiddle column of the r-row networks for a primitive
+    r-th root of unity mod q: stage m's row for pair index i holds
+    root^(i * r / (2m)).  The DIT and the DIF network of one root share
+    it."""
+    col = []
+    for m, _, rows in _row_stage_offsets(r):
+        step = pow(root, r // (2 * m), q)
+        col += [pow(step, i % m, q) for i in range(rows)]
+    return np.array(col, dtype=np.uint32)
+
+
+def _sublane_iota(lanes: int):
+    return lax.broadcasted_iota(jnp.int32, (SUBLANES, lanes), 0)
+
+
+def _brev8_masks(lanes: int):
+    """Masks of the fixed sublane permutation s -> brev3(s) (it swaps
+    sublanes 1 and 4, 3 and 6): where a roll by 3, or by 5, brings the
+    row that belongs there (read off the rolled iota, so they hold
+    whichever direction the hardware rotate takes)."""
+    s = _sublane_iota(lanes)
+    want = ((s & 1) << 2) | (s & 2) | ((s >> 2) & 1)
+    return pltpu.roll(s, 3, 0) == want, pltpu.roll(s, 5, 0) == want
+
+
+def _brev8(x, masks):
+    """x (..., 8, L): out[..., s, :] = x[..., brev3(s), :]."""
+    by3, by5 = masks
+    ax = x.ndim - 2
+    return jnp.where(by3, pltpu.roll(x, 3, ax),
+                     jnp.where(by5, pltpu.roll(x, 5, ax), x))
+
+
+def _brev_load(x_ref, r: int, lanes: int):
+    """The (1, g, r*c, lanes/c) block, c = lanes/128 chunks per row
+    (strided access needs 128-lane rows; c = 1 up to 128 lanes), as a
+    (g, r, lanes) value whose row p is row brev(p) of the block: 8-row
+    group a is the strided load of rows brev(a) + (r/8)*u, u < 8, with
+    its sublanes bit-reversed."""
+    g, c = x_ref.shape[1], x_ref.shape[2] // r
+    v = r // SUBLANES
+    lg = v.bit_length() - 1
+
+    def group(a):
+        parts = [x_ref[0, :, pl.ds(c * bitrev(a, lg) + h, SUBLANES,
+                                   stride=c * v), :] for h in range(c)]
+        return jnp.concatenate(parts, axis=-1) if c > 1 else parts[0]
+
+    x = jnp.stack([group(a) for a in range(v)], axis=1)
+    return _brev8(x, _brev8_masks(lanes)).reshape(g, r, lanes)
+
+
+def _brev_store(o_ref, x):
+    """Inverse of ``_brev_load``: row p of x (g, r, lanes) goes to row
+    brev(p) of the (1, g, r*c, lanes/c) block."""
+    g, r, lanes = x.shape
+    c = o_ref.shape[2] // r
+    w = lanes // c
+    v = r // SUBLANES
+    lg = v.bit_length() - 1
+    x = _brev8(x.reshape(g, v, SUBLANES, lanes), _brev8_masks(lanes))
+    for a in range(v):
+        for h in range(c):
+            o_ref[0, :, pl.ds(c * bitrev(a, lg) + h, SUBLANES,
+                              stride=c * v), :] = x[:, a, :, h * w:(h + 1) * w]
+
+
+def _row_stages(x, t_ref, tp_ref, q, lazy: bool, dit: bool):
+    """In-place radix-2 network along the rows of x (g, r, L), one
+    transform per lane.  dit: Cooley-Tukey from bit-reversed to natural
+    row order (m = 1 ... r/2); else Gentleman-Sande from natural to
+    bit-reversed (m = r/2 ... 1).  The (1, r + 16, L) table refs hold
+    ``row_twiddles`` broadcast over the lanes."""
+    g, r, lanes = x.shape
+    bfly = _ct if dit else _gs
+    stages = _row_stage_offsets(r)
+    s = _sublane_iota(lanes)
+    for m, off, rows in (stages if dit else stages[::-1]):
+        w = t_ref[0, off:off + rows, :]
+        wp = tp_ref[0, off:off + rows, :]
+        if m >= SUBLANES:            # pairs of whole vregs
+            xs = x.reshape(g, r // (2 * m), 2, m, lanes)
+            a, b = bfly(xs[:, :, 0], xs[:, :, 1], w, wp, q, lazy, 32)
+            x = jnp.stack([a, b], axis=2).reshape(g, r, lanes)
+            continue
+        # pairs inside a vreg: sublane s with bit m clear holds a, s ^ m
+        # holds b; the partner comes by sublane rolls
+        x4 = x.reshape(g, r // SUBLANES, SUBLANES, lanes)
+        is_lo = (s & m) == 0
+        if 2 * m == SUBLANES:
+            y = pltpu.roll(x4, m, 2)
+        else:
+            y = jnp.where(pltpu.roll(s, m, 0) == (s ^ m), pltpu.roll(x4, m, 2),
+                          pltpu.roll(x4, SUBLANES - m, 2))
+        a = jnp.where(is_lo, x4, y)
+        b = jnp.where(is_lo, y, x4)
+        x = jnp.where(is_lo, *bfly(a, b, w, wp, q, lazy, 32)).reshape(g, r, lanes)
+    return x
+
+
+def _fourstep_fwd_kernel(x_ref, q_ref, *refs, negacyclic: bool, lazy: bool):
+    if negacyclic:
+        pre_ref, prep_ref, *refs = refs
+    a_ref, ap_ref, tw_ref, twp_ref, c_ref, cp_ref, o_ref = refs
+    qc = q_ref[pl.program_id(0)]
     mul = _shoup_lazy if lazy else _shoup
-    o_ref[0] = mul(x_ref[0], w_ref[0], wp_ref[0], q_ref[pl.program_id(0)], 32)
+    n1, n2 = tw_ref.shape[1:]
+    x = _brev_load(x_ref, n1, n2)                           # (g, n1, n2)
+    if negacyclic:
+        x = mul(x, pre_ref[0], prep_ref[0], qc, 32)
+    x = _row_stages(x, a_ref, ap_ref, qc, lazy, dit=True)
+    x = mul(x, tw_ref[0], twp_ref[0], qc, 32)
+    x = _row_stages(jnp.swapaxes(x, 1, 2), c_ref, cp_ref, qc, lazy, dit=False)
+    if lazy:
+        x = jnp.where(x >= qc, x - qc, x)
+    _brev_store(o_ref, x)                                   # (g, n2, n1)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "lazy", "interpret"))
-def twiddle_mul_banks_pallas(x, qs, w, wp, *, tile: int = 8,
-                             lazy: bool = False,
-                             interpret: bool | None = None):
-    """x: (k, batch, n) u32; qs: (k,); w/wp: (k, n) weight rows + Shoup
-    companions.  out[p, i, :] = x[p, i, :] * w[p, :] mod qs[p]."""
+def _fourstep_inv_kernel(x_ref, q_ref, ninv_ref, ninvp_ref, *refs,
+                         negacyclic: bool, lazy: bool):
+    if negacyclic:
+        post_ref, postp_ref, *refs = refs
+    c_ref, cp_ref, tw_ref, twp_ref, a_ref, ap_ref, o_ref = refs
+    p = pl.program_id(0)
+    qc = q_ref[p]
+    n1, n2 = tw_ref.shape[1:]
+    x = _row_stages(_brev_load(x_ref, n2, n1), c_ref, cp_ref, qc, lazy,
+                    dit=True)
+    x = (_shoup_lazy if lazy else _shoup)(jnp.swapaxes(x, 1, 2), tw_ref[0],
+                                          twp_ref[0], qc, 32)
+    x = _row_stages(x, a_ref, ap_ref, qc, lazy, dit=False)
+    # the last multiply fully reduces any u32 representative
+    if negacyclic:
+        x = _shoup(x, post_ref[0], postp_ref[0], qc, 32)    # psi^-i * n^-1
+    else:
+        x = _shoup(x, ninv_ref[p], ninvp_ref[p], qc, 32)
+    _brev_store(o_ref, x)                                   # (g, n1, n2)
+
+
+def fourstep_block(b: int, n: int) -> int:
+    """Polynomials per program of the four-step kernels: as many as fit
+    ``BLOCK_BYTES`` (at least one), at most the batch."""
+    return max(1, min(b, BLOCK_BYTES // (4 * n)))
+
+
+def ref_lanes(width: int) -> int:
+    """Lane width of the HBM view in which the four-step kernels read or
+    write rows of ``width`` values: 128-lane rows (a wider row spans
+    several), or the whole row when it is narrower."""
+    return min(width, 128)
+
+
+def _fourstep_call(kernel, x, scalars, tables, *, dims: tuple[int, int],
+                   direction: str, interpret: bool | None):
+    """Grid (prime, block of polynomials) over x (k, b, n/w, w), a view
+    of (k, b, r, L) rows with w = ``ref_lanes(L)``, written as the
+    (k, b, n/w', w') view of (k, b, L, r).  ``dims`` = (r, L).
+    ``tables`` are (k', ...) per-prime stacks (k' >= k; program p reads
+    row p), ``scalars`` (k',) SMEM arrays.  A last block that the batch
+    does not fill reads rows past it and its writes there are dropped.
+    When tracing is on, building the program is a
+    ``kernels.fourstep.block`` span carrying the geometry."""
     interpret = resolve_interpret(interpret)
-    k, b, n = x.shape
-    assert b % tile == 0
-    nl = lane_block(n)
-    row = pl.BlockSpec((1, 1, nl), lambda p, i, c: (p, 0, c))
-    blk = pl.BlockSpec((1, tile, nl), lambda p, i, c: (p, i, c))
-    return pl.pallas_call(
-        functools.partial(_twiddle_mul_banks_kernel, lazy=lazy),
-        grid=(k, b // tile, n // nl),
-        in_specs=[blk, _smem(), row, row],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((k, b, n), U32),
-        interpret=interpret,
-    )(x, qs, w[:, None, :], wp[:, None, :])
+    k, b, rows_in, w_in = x.shape
+    r, lanes = dims
+    n = r * lanes
+    w_out = ref_lanes(r)
+    g = fourstep_block(b, n)
+    n1, n2 = dims if direction == "fwd" else dims[::-1]
+    in_specs = [pl.BlockSpec((1, g, rows_in, w_in), lambda p, i: (p, i, 0, 0))]
+    in_specs += [_smem() for _ in scalars]
+    in_specs += [pl.BlockSpec((1,) + t.shape[1:], lambda p, i: (p, 0, 0))
+                 for t in tables]
+    out_block = (1, g, n // w_out, w_out)
+    with obs.span("kernels.fourstep.block", cat="kernel", n1=n1, n2=n2,
+                  polys=g, direction=direction, k=k, b=b):
+        return pl.pallas_call(
+            kernel,
+            grid=(k, pl.cdiv(b, g)),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(out_block, lambda p, i: (p, i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((k, b) + out_block[2:], U32),
+            interpret=interpret,
+        )(x, *scalars, *tables)
+
+
+@functools.partial(jax.jit, static_argnames=("negacyclic", "lazy", "interpret"))
+def fourstep_fwd_banks_pallas(x, qs, pre, prep, ta, tap, tw, twp, tc, tcp, *,
+                              negacyclic: bool, lazy: bool = True,
+                              interpret: bool | None = None):
+    """x: (k, b, n) u32 in natural order, row i of axis 0 reduced mod
+    qs[i], viewed as (k, b, n/w, w) with w = ``ref_lanes(n2)`` ->
+    A_hat[k2*n1 + k1] in natural order, viewed as (k, b, n/w', w') with
+    w' = ``ref_lanes(n1)``.  Per-prime tables (FourStepPack): pre/prep
+    (k, n1, n2) psi weights in bit-reversed row order; ta/tap
+    (k, n1 + 16, n2) and tc/tcp (k, n2 + 16, n1) ``row_twiddles`` of the
+    n1- and n2-point roots; tw/twp (k, n1, n2) the step-3 twiddles
+    w^(j2*k1) at [k1, j2]."""
+    kern = functools.partial(_fourstep_fwd_kernel, negacyclic=negacyclic,
+                             lazy=lazy)
+    pre_rows = [pre, prep] if negacyclic else []
+    return _fourstep_call(kern, x, [qs], pre_rows + [ta, tap, tw, twp, tc, tcp],
+                          dims=(tw.shape[1], tw.shape[2]), direction="fwd",
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("negacyclic", "lazy", "interpret"))
+def fourstep_inv_banks_pallas(x, qs, ninv, ninvp, post, postp, tc, tcp, tw,
+                              twp, ta, tap, *, negacyclic: bool,
+                              lazy: bool = True,
+                              interpret: bool | None = None):
+    """Inverse of ``fourstep_fwd_banks_pallas``: the (k, b, n/w', w')
+    view in, the (k, b, n/w, w) view out, canonical.  tc/tcp and ta/tap
+    hold the inverse roots' ``row_twiddles``, tw/twp w^-(j2*k1),
+    post/postp psi^-i * n^-1 in bit-reversed row order (negacyclic),
+    ninv/ninvp (k,) n^-1."""
+    kern = functools.partial(_fourstep_inv_kernel, negacyclic=negacyclic,
+                             lazy=lazy)
+    post_rows = [post, postp] if negacyclic else []
+    return _fourstep_call(kern, x, [qs, ninv, ninvp],
+                          post_rows + [tc, tcp, tw, twp, ta, tap],
+                          dims=(tw.shape[2], tw.shape[1]), direction="inv",
+                          interpret=interpret)

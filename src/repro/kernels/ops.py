@@ -47,15 +47,15 @@ import numpy as np
 
 from repro import obs
 from repro.core.modmath import dtype_bits
-from repro.core.params import NTTParams, bitrev_perm
+from repro.core.params import NTTParams
 from repro.kernels import (autotune, base_conv_kernel, dyadic_kernel,
                            ntt_kernel, ref)
 
 # Single-kernel tile budget: below this ring size the whole log2(n)-stage
 # transform runs as ONE fused banks kernel; at or above it the large-N
-# four-step pipeline (``ntt_fourstep_banks``) takes over — two batched
-# banks passes + the fused twiddle-correction kernel (paper §IX, and the
-# ROADMAP "every FHE workload with N >= 2^13" north star).
+# four-step transform (``ntt_fourstep_banks``) takes over — one kernel
+# that runs both passes and the twiddle correction in VMEM (paper §IX,
+# and the ROADMAP "every FHE workload with N >= 2^13" north star).
 FOURSTEP_MIN_N = 1 << 13
 
 
@@ -284,33 +284,6 @@ def intt_banks(x, t: dict, *, negacyclic: bool = True,
 
 @_spanned
 @_ct_batch_axis
-def twiddle_mul_banks(x, w, wp, qs, *, use_pallas: bool | None = None,
-                      tile: int | None = None, lazy: bool = False):
-    """Fused per-prime weight-row multiply: x (k, ..., n) u32, w/wp (k, n)
-    weight rows + Shoup companions, qs (k,).  This is the four-step step-3
-    twiddle correction (and the negacyclic psi pre/post-weights) as one
-    (prime, batch_tile) kernel on the Pallas path.
-
-    Accepts any u32 input representatives (the Shoup product reduces them
-    exactly); ``lazy=True`` emits the [0, 2q) representative for a
-    lazy-aware consumer instead of the canonical value."""
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
-    x = jnp.asarray(x)
-    if not use_pallas:
-        return ref.twiddle_mul_banks_ref(x, qs, w, wp, lazy=lazy)
-    k, n = x.shape[0], x.shape[-1]
-    shape = x.shape
-    x3 = x.reshape(k, -1, n)
-    tile = autotune.resolve_tile("twiddle_mul_banks", k, n, x3.shape[1], tile,
-                                 dtype=x.dtype.name)
-    x3, b = _pad_mid(x3, tile)
-    out = ntt_kernel.twiddle_mul_banks_pallas(x3, qs, w, wp,
-                                              tile=tile, lazy=lazy)
-    return out[:, :b].reshape(shape)
-
-
-@_spanned
-@_ct_batch_axis
 def galois_banks(x, idx):
     """Galois automorphism in the NTT domain: out[..., j] = x[..., idx[j]].
 
@@ -361,19 +334,9 @@ def galois_digits_banks(ext, idx):
     return ref.galois_digits_banks_ref(ext, idx)
 
 
-# ------------------------------------------- large-N four-step pipeline
+# ----------------------------------------------- large-N four-step NTT
 
-@functools.lru_cache(maxsize=None)
-def _brev(n: int) -> np.ndarray:
-    """Bit-reversal permutation — an involution, so the same gather
-    converts bitrev->natural and natural->bitrev."""
-    return bitrev_perm(n)
-
-
-def fourstep_dims(fp: dict) -> tuple[int, int]:
-    """(n1, n2) of a four-step pack, read from static table shapes (the
-    pack holds no Python ints so it can ride through jit as a pytree)."""
-    return fp["pack1"]["tw"].shape[-1] * 2, fp["pack2"]["tw"].shape[-1] * 2
+fourstep_dims = ref.fourstep_dims
 
 
 @_spanned
@@ -381,52 +344,40 @@ def fourstep_dims(fp: dict) -> tuple[int, int]:
 def ntt_fourstep_banks(x, fp: dict, *, negacyclic: bool = True,
                        use_pallas: bool | None = None, tile: int | None = None,
                        lazy: bool = True):
-    """Large-N forward NTT via the four-step (Bailey) decomposition with
-    every pass on the banks kernels — the paper's §IX schedule (two
-    passes of batched NTT-N1/NTT-N2 units with a reorder in between).
+    """Large-N forward NTT by the four-step (Bailey) decomposition n =
+    n1*n2 — the paper's §IX schedule of NTT-N1 and NTT-N2 units with the
+    step-3 twiddle between them — as ONE kernel per call on the Pallas
+    path (``ntt_kernel.fourstep_fwd_banks_pallas``): each polynomial is
+    read and written once, and the passes, twiddles, transposition and
+    bit reversals all happen in VMEM.
 
     x: (k, ..., n) u32 with row i reduced mod fp["qs"][i] (or a
     (b, k, ..., n) ciphertext-batch stack with ``batch_leading=True``);
     fp: a FourStepPack from ``fhe.batched.build_fourstep_pack`` for at
     least those k primes (extra rows are ignored, like ``ntt_banks``).
-
-    Pipeline:  [psi pre-weight] -> column NTT-N1 bank pass (batch folds
-    the N2 columns) -> fused w^(j2*k1) twiddle kernel -> row NTT-N2 bank
-    pass -> transpose readout.  Output is in *natural* frequency order
-    (A_hat[k2*n1 + k1]), unlike the bitrev order of the single-kernel
-    path; ``intt_fourstep_banks`` consumes the same convention, so any
-    NTT-domain data stays internally consistent per ring size.
-
-    In lazy mode the inter-pass values ride in [0, 2q): the psi
-    pre-weight and pass 1 emit unreduced representatives, the step-3
-    Shoup twiddle absorbs them exactly (it accepts any u32), and pass 2's
-    epilogue restores [0, q) — the output is bit-identical to eager."""
+    Output is in *natural* frequency order (A_hat[k2*n1 + k1]), unlike
+    the bitrev order of the single-kernel path; ``intt_fourstep_banks``
+    consumes the same convention, so any NTT-domain data stays
+    internally consistent per ring size.  ``lazy`` keeps values in
+    [0, 2q) between stages; the output is canonical either way and the
+    same as the multi-pass reference (``ref.ntt_fourstep_banks_ref``,
+    the non-TPU default), bit for bit.  ``tile`` is accepted with the
+    banks entry points' signature; the kernel takes its block of
+    polynomials from the shape (``ntt_kernel.fourstep_block``)."""
+    use_pallas = _on_tpu() if use_pallas is None else use_pallas
     x = jnp.asarray(x)
     k = x.shape[0]
     n1, n2 = fourstep_dims(fp)
-    n = n1 * n2
-    assert x.shape[-1] == n, (x.shape, n1, n2)
-    kw = dict(use_pallas=use_pallas, tile=tile, lazy=lazy)
-    qs = fp["qs"][:k]
-    shape = x.shape
-    x = x.reshape(k, -1, n)
-    b = x.shape[1]
-    if negacyclic:
-        x = twiddle_mul_banks(x, fp["psi"][:k], fp["psip"][:k], qs, **kw)
-    # pass 1: column NTT-N1 units; the N2 columns fold into the kernel
-    # batch so all k*b*n2 transforms run in one (prime, tile) grid
-    xt = x.reshape(k, b, n1, n2).swapaxes(-1, -2).reshape(k, b * n2, n1)
-    xt = ntt_banks(xt, fp["pack1"], negacyclic=False, reduce_out=False,
-                   **kw)[..., _brev(n1)]
-    x = xt.reshape(k, b, n2, n1).swapaxes(-1, -2).reshape(k, b, n)
-    # step 3: fused twiddle correction (the inter-pass reorder weights)
-    x = twiddle_mul_banks(x, fp["tw"][:k], fp["twp"][:k], qs, **kw)
-    # pass 2: row NTT-N2 units (epilogue restores the canonical band)
-    xr = x.reshape(k, b * n1, n2)
-    xr = ntt_banks(xr, fp["pack2"], negacyclic=False,
-                   **kw)[..., _brev(n2)]
-    # readout: A_hat[k2*n1 + k1] = D[k1, k2]
-    return xr.reshape(k, b, n1, n2).swapaxes(-1, -2).reshape(shape)
+    assert x.shape[-1] == n1 * n2, (x.shape, n1, n2)
+    if not use_pallas:
+        return ref.ntt_fourstep_banks_ref(x, fp, negacyclic, lazy=lazy)
+    w = ntt_kernel.ref_lanes(n2)
+    out = ntt_kernel.fourstep_fwd_banks_pallas(
+        x.reshape(k, -1, n1 * n2 // w, w), fp["qs"], fp["pre"], fp["prep"],
+        fp["fwd_a"], fp["fwd_ap"], fp["tw"].reshape(-1, n1, n2),
+        fp["twp"].reshape(-1, n1, n2), fp["fwd_c"], fp["fwd_cp"],
+        negacyclic=negacyclic, lazy=lazy)
+    return out.reshape(x.shape)
 
 
 @_spanned
@@ -434,40 +385,24 @@ def ntt_fourstep_banks(x, fp: dict, *, negacyclic: bool = True,
 def intt_fourstep_banks(x, fp: dict, *, negacyclic: bool = True,
                         use_pallas: bool | None = None, tile: int | None = None,
                         lazy: bool = True):
-    """Inverse of ``ntt_fourstep_banks`` (natural-order input).  The two
-    sub-iNTT bank passes each contribute 1/Ni, so no separate n^-1; the
-    negacyclic psi^-i post-weight is the plain inverse-psi row.  Lazy
-    handoff mirrors the forward pipeline: unreduced between passes, the
-    final multiply (psi^-i, or pass 1's ninv epilogue) fully reduces."""
+    """Inverse of ``ntt_fourstep_banks`` (natural-order input), one
+    kernel per call on the Pallas path
+    (``ntt_kernel.fourstep_inv_banks_pallas``); the last multiply
+    (psi^-i * n^-1, or n^-1 when cyclic) fully reduces."""
+    use_pallas = _on_tpu() if use_pallas is None else use_pallas
     x = jnp.asarray(x)
     k = x.shape[0]
     n1, n2 = fourstep_dims(fp)
-    n = n1 * n2
-    assert x.shape[-1] == n, (x.shape, n1, n2)
-    kw = dict(use_pallas=use_pallas, tile=tile, lazy=lazy)
-    qs = fp["qs"][:k]
-    shape = x.shape
-    x = x.reshape(k, -1, n)
-    b = x.shape[1]
-    # undo the readout: D[k1, k2] from A_hat[k2*n1 + k1]
-    x = x.reshape(k, b, n2, n1).swapaxes(-1, -2)            # (k, b, n1, n2)
-    # inverse pass 2: row iNTT-N2 banks (bitrev input order)
-    xr = x.reshape(k, b * n1, n2)[..., _brev(n2)]
-    xr = intt_banks(xr, fp["pack2"], negacyclic=False, reduce_out=False, **kw)
-    # undo the twiddle correction
-    x = twiddle_mul_banks(xr.reshape(k, b, n), fp["itw"][:k], fp["itwp"][:k],
-                          qs, **kw)
-    # inverse pass 1: column iNTT-N1 banks; when a psi post-weight
-    # follows it absorbs the reduction, else the ninv epilogue reduces
-    xt = (x.reshape(k, b, n1, n2).swapaxes(-1, -2)
-          .reshape(k, b * n2, n1)[..., _brev(n1)])
-    xt = intt_banks(xt, fp["pack1"], negacyclic=False,
-                    reduce_out=not negacyclic, **kw)
-    x = xt.reshape(k, b, n2, n1).swapaxes(-1, -2).reshape(k, b, n)
-    if negacyclic:
-        x = twiddle_mul_banks(x, fp["ipsi"][:k], fp["ipsip"][:k], qs,
-                              use_pallas=use_pallas, tile=tile)  # full reduce
-    return x.reshape(shape)
+    assert x.shape[-1] == n1 * n2, (x.shape, n1, n2)
+    if not use_pallas:
+        return ref.intt_fourstep_banks_ref(x, fp, negacyclic, lazy=lazy)
+    w = ntt_kernel.ref_lanes(n1)
+    out = ntt_kernel.fourstep_inv_banks_pallas(
+        x.reshape(k, -1, n1 * n2 // w, w), fp["qs"], fp["ninv"], fp["ninv_p"],
+        fp["post"], fp["postp"], fp["inv_c"], fp["inv_cp"],
+        fp["itw"].reshape(-1, n1, n2), fp["itwp"].reshape(-1, n1, n2),
+        fp["inv_a"], fp["inv_ap"], negacyclic=negacyclic, lazy=lazy)
+    return out.reshape(x.shape)
 
 
 @_spanned

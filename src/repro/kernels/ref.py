@@ -5,6 +5,8 @@ kernel tests sweep shapes/dtypes and assert exact equality (integer
 arithmetic — no tolerance needed)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -17,7 +19,7 @@ from repro.core.modmath import (
     mulmod_shoup,
     mulmod_shoup_lazy,
 )
-from repro.core.params import NTTParams
+from repro.core.params import NTTParams, bitrev_perm
 
 
 def ntt_fwd_ref(x, p: NTTParams, negacyclic: bool, lazy: bool = False):
@@ -90,14 +92,107 @@ def ntt_inv_banks_ref(x, qs, ninv, ninv_p, itw, itwp, post, postp,
 
 
 def twiddle_mul_banks_ref(x, qs, w, wp, lazy: bool = False):
-    """Four-step twiddle correction: x (k, ..., n) times per-prime weight
-    rows w/wp (k, n) mod qs (k,) — same math as the fused kernel."""
+    """Per-prime weight-row multiply (the four-step twiddle correction
+    and psi weights): x (k, ..., n) times rows w/wp (k, n) mod qs (k,);
+    lazy emits the [0, 2q) Shoup representative."""
     ex = (1,) * (x.ndim - 2)
     k, n = w.shape
     mul = mulmod_shoup_lazy if lazy else mulmod_shoup
     return mul(x, w.reshape((k,) + ex + (n,)),
                wp.reshape((k,) + ex + (n,)),
                qs.reshape((k,) + ex + (1,)))
+
+
+@functools.lru_cache(maxsize=None)
+def _brev(n: int):
+    """Bit-reversal permutation — an involution, so the same gather
+    converts bitrev->natural and natural->bitrev."""
+    return bitrev_perm(n)
+
+
+def fourstep_dims(fp: dict) -> tuple[int, int]:
+    """(n1, n2) of a four-step pack, read from static table shapes (the
+    pack holds no Python ints so it can ride through jit as a pytree)."""
+    return fp["pack1"]["tw"].shape[-1] * 2, fp["pack2"]["tw"].shape[-1] * 2
+
+
+def _fourstep_rows(fp: dict, k: int):
+    """The first k prime rows of a FourStepPack's multi-pass tables."""
+    rows = lambda t, *names: tuple(t[name][:k] for name in names)
+    return (rows(fp, "qs")[0], rows(fp["pack1"], *_PASS_KEYS),
+            rows(fp["pack2"], *_PASS_KEYS))
+
+
+_PASS_KEYS = ("qs", "tw", "twp", "itw", "itwp", "ninv", "ninv_p", "psi",
+              "psip", "ipsin", "ipsinp")
+
+
+def _fwd_pass(x, t, lazy, reduce_out):
+    qs, tw, twp, _, _, _, _, psi, psip, _, _ = t
+    return ntt_fwd_banks_ref(x, qs, tw, twp, psi, psip, False, lazy=lazy,
+                             reduce_out=reduce_out)
+
+
+def _inv_pass(x, t, lazy, reduce_out):
+    qs, _, _, itw, itwp, ninv, ninv_p, _, _, ipsin, ipsinp = t
+    return ntt_inv_banks_ref(x, qs, ninv, ninv_p, itw, itwp, ipsin, ipsinp,
+                             False, lazy=lazy, reduce_out=reduce_out)
+
+
+def ntt_fourstep_banks_ref(x, fp: dict, negacyclic: bool, lazy: bool = False):
+    """The four-step forward transform as separate passes (the plain
+    reference of ``ntt_kernel.fourstep_fwd_banks_pallas``): [psi
+    pre-weight] -> NTT-N1 over the columns (the N2 columns fold into the
+    batch) -> bit-reversal gather -> w^(j2*k1) twiddle -> NTT-N2 over
+    the rows -> gather -> transposed readout A_hat[k2*n1 + k1].  x:
+    (k, ..., n) u32, fp a FourStepPack for at least k primes.  In lazy
+    mode the inter-pass values ride in [0, 2q) and the step-3 Shoup
+    product absorbs them; the output is canonical either way."""
+    x = jnp.asarray(x)
+    k = x.shape[0]
+    n1, n2 = fourstep_dims(fp)
+    n = n1 * n2
+    qs, t1, t2 = _fourstep_rows(fp, k)
+    shape = x.shape
+    x = x.reshape(k, -1, n)
+    b = x.shape[1]
+    if negacyclic:
+        x = twiddle_mul_banks_ref(x, qs, fp["psi"][:k], fp["psip"][:k],
+                                  lazy=lazy)
+    xt = x.reshape(k, b, n1, n2).swapaxes(-1, -2).reshape(k, b * n2, n1)
+    xt = _fwd_pass(xt, t1, lazy, reduce_out=False)[..., _brev(n1)]
+    x = xt.reshape(k, b, n2, n1).swapaxes(-1, -2).reshape(k, b, n)
+    x = twiddle_mul_banks_ref(x, qs, fp["tw"][:k], fp["twp"][:k], lazy=lazy)
+    xr = _fwd_pass(x.reshape(k, b * n1, n2), t2, lazy,
+                   reduce_out=True)[..., _brev(n2)]
+    return xr.reshape(k, b, n1, n2).swapaxes(-1, -2).reshape(shape)
+
+
+def intt_fourstep_banks_ref(x, fp: dict, negacyclic: bool,
+                            lazy: bool = False):
+    """Inverse of ``ntt_fourstep_banks_ref`` (natural-order input), as
+    separate passes: each sub-iNTT contributes 1/Ni, the negacyclic
+    psi^-i post-weight (or pass 1's ninv epilogue) fully reduces."""
+    x = jnp.asarray(x)
+    k = x.shape[0]
+    n1, n2 = fourstep_dims(fp)
+    n = n1 * n2
+    qs, t1, t2 = _fourstep_rows(fp, k)
+    shape = x.shape
+    x = x.reshape(k, -1, n)
+    b = x.shape[1]
+    x = x.reshape(k, b, n2, n1).swapaxes(-1, -2)            # (k, b, n1, n2)
+    xr = _inv_pass(x.reshape(k, b * n1, n2)[..., _brev(n2)], t2, lazy,
+                   reduce_out=False)
+    x = twiddle_mul_banks_ref(xr.reshape(k, b, n), qs, fp["itw"][:k],
+                              fp["itwp"][:k], lazy=lazy)
+    xt = (x.reshape(k, b, n1, n2).swapaxes(-1, -2)
+          .reshape(k, b * n2, n1)[..., _brev(n1)])
+    xt = _inv_pass(xt, t1, lazy, reduce_out=not negacyclic)
+    x = xt.reshape(k, b, n2, n1).swapaxes(-1, -2).reshape(k, b, n)
+    if negacyclic:
+        x = twiddle_mul_banks_ref(x, qs, fp["ipsi"][:k], fp["ipsip"][:k])
+    return x.reshape(shape)
 
 
 def galois_banks_ref(x, idx):

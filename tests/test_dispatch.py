@@ -68,8 +68,7 @@ def test_all_ops_pallas_paths_compile_on_tpu(monkeypatch):
                                   dtype=np.uint32))
     ext = jnp.asarray(np.stack([np.asarray(xk)] * k))        # (d, k, 4, n)
     evk3 = jnp.asarray(np.stack([np.asarray(xk)[:, 0]] * k))  # (d, k, n)
-    w = t["psi"][:k]
-    wp = t["psip"][:k]
+    fp = FB.build_fourstep_pack(primes, n)
 
     calls = [
         lambda: ops.ntt(x1, p),
@@ -78,7 +77,8 @@ def test_all_ops_pallas_paths_compile_on_tpu(monkeypatch):
         lambda: ops.dyadic_mac(x1, x1, x1, p),
         lambda: ops.ntt_banks(xk, t),
         lambda: ops.intt_banks(xk, t),
-        lambda: ops.twiddle_mul_banks(xk, w, wp, t["qs"][:k]),
+        lambda: ops.ntt_fourstep_banks(xk, fp),
+        lambda: ops.intt_fourstep_banks(xk, fp),
         lambda: ops.dyadic_inner_banks(ext, evk3, t),
         lambda: ops.dyadic_inner_banks(ext, ext, t),      # per-batch evk
         lambda: ops.ntt_banks(xr, rp, negacyclic=False),  # u16 ring

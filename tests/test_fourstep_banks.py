@@ -2,13 +2,14 @@
 (paper §IX on the PR-1 fused kernels; see ``kernels.ops``).
 
 Oracle chain: the single-kernel CG path is pinned to the O(n^2) golden
-model in test_ntt_banks / test_ntt; here the four-step pipeline is
-pinned bit-exact to that cg oracle (natural order) for every prime of a
-three-prime basis at N in {2^10, 2^12, 2^14}, the Pallas path (interpret
-mode, incl. the fused step-3 twiddle kernel) is pinned to the vmap
-reference, and negacyclic polymul closes the loop against the schoolbook
-convolution.  Property tests run under hypothesis when installed and the
-hypcompat deterministic sweep otherwise.
+model in test_ntt_banks / test_ntt; here the multi-pass reference
+(``ref.ntt_fourstep_banks_ref``, the CPU default) is pinned bit-exact to
+that cg oracle (natural order) for every prime of a three-prime basis at
+N in {2^10, 2^12, 2^14}, the one-kernel Pallas transform (interpret
+mode) is pinned to the reference at 2^8 and 2^13-2^15, and negacyclic
+polymul closes the loop against the schoolbook convolution.  Property
+tests run under hypothesis when installed and the hypcompat
+deterministic sweep otherwise.
 """
 import functools
 
@@ -27,6 +28,7 @@ from repro.core.params import (bitrev_perm, fourstep_split, gen_ntt_primes,
 from repro.fhe import batched as FB
 from repro.fhe import rns
 from repro.kernels import ops
+from repro.kernels.ntt_kernel import fourstep_block
 
 RNG = np.random.default_rng(1404)
 SIZES = [1 << 10, 1 << 12, 1 << 14]
@@ -99,41 +101,67 @@ def test_fourstep_banks_vs_cg_oracle(n):
             assert np.array_equal(got[i], want), (n, i, negacyclic)
 
 
-def test_fourstep_pallas_equals_ref():
-    """The Pallas path (interpret mode on CPU; includes the fused
-    twiddle-multiply kernel) and the vmap reference are the same
-    function.  Small N keeps interpret-mode cost down — the kernels are
-    identical code for every N."""
-    n = 1 << 8
-    fp = _fp(n)
-    x = jnp.asarray(_stack(n, batch=3))
-    # negacyclic only: the cyclic flag difference is a static branch
-    # already swept by test_ntt_banks for the underlying kernels
-    a = np.asarray(ops.ntt_fourstep_banks(x, fp, use_pallas=True))
-    b = np.asarray(ops.ntt_fourstep_banks(x, fp, use_pallas=False))
-    assert np.array_equal(a, b)
-    ia = np.asarray(ops.intt_fourstep_banks(x, fp, use_pallas=True))
-    ib = np.asarray(ops.intt_fourstep_banks(x, fp, use_pallas=False))
-    assert np.array_equal(ia, ib)
+# Bit identity of the one-kernel transform (interpret mode on the CPU)
+# with the multi-pass reference: at the three ring sizes the repo runs
+# (2^13 and 2^15 split unevenly, 2^14 = 128 x 128) and one small ring,
+# lazy and eager, over a batch that leaves the last block of polynomials
+# part-filled (``fourstep_block`` + 1 polynomials).
+@pytest.mark.parametrize("lazy", [True, False], ids=["lazy", "eager"])
+@pytest.mark.parametrize("n", [1 << 8, 1 << 13, 1 << 14, 1 << 15])
+def test_fourstep_kernel_equals_reference(n, lazy):
+    fp = FB.slice_fourstep_pack(_fp(n), slice(0, 2))
+    b = fourstep_block(1 << 20, n) + 1
+    x = jnp.asarray(_stack(n, batch=b)[:2])
+    for fn in (ops.ntt_fourstep_banks, ops.intt_fourstep_banks):
+        got = np.asarray(fn(x, fp, use_pallas=True, lazy=lazy))
+        want = np.asarray(fn(x, fp, use_pallas=False))
+        assert np.array_equal(got, want), (fn.__name__, n, lazy)
 
 
-def test_twiddle_mul_banks_kernel():
-    """The step-3 kernel alone: == the Shoup-multiply reference, odd
-    batch sizes pad/unpad transparently."""
-    n = 256
-    primes = _basis(1 << 10)
-    t = FB.build_table_pack(list(primes), n)
-    x = np.stack([RNG.integers(0, q, (3, n), dtype=np.uint32)
-                  for q in primes])
-    got = np.asarray(ops.twiddle_mul_banks(jnp.asarray(x), t["psi"], t["psip"],
-                                           t["qs"], use_pallas=True))
-    want = np.asarray(ops.twiddle_mul_banks(jnp.asarray(x), t["psi"], t["psip"],
-                                            t["qs"], use_pallas=False))
-    assert np.array_equal(got, want)
-    for i, q in enumerate(primes):
-        exp = (x[i].astype(np.uint64)
-               * np.asarray(t["psi"])[i].astype(np.uint64)) % q
-        assert np.array_equal(got[i], exp.astype(np.uint32))
+@pytest.mark.parametrize("layout", ["cyclic", "batch_leading"])
+def test_fourstep_kernel_layouts(layout):
+    """The cyclic transform and a (b, k, ..., n) ciphertext-batch stack
+    through the kernel == the reference, at the 2^13 threshold."""
+    n = ops.FOURSTEP_MIN_N
+    fp = FB.slice_fourstep_pack(_fp(n), slice(0, 2))
+    x = _stack(n, batch=6)[:2].reshape(2, 3, 2, n)
+    kw = {"negacyclic": False} if layout == "cyclic" else {}
+    if layout == "batch_leading":
+        x, kw = np.swapaxes(x, 0, 1), {"batch_leading": True}
+    x = jnp.asarray(x)
+    for fn in (ops.ntt_fourstep_banks, ops.intt_fourstep_banks):
+        got = np.asarray(fn(x, fp, use_pallas=True, **kw))
+        assert np.array_equal(got, np.asarray(fn(x, fp, use_pallas=False,
+                                                 **kw))), fn.__name__
+
+
+def test_fourstep_block_spans_cover_keyswitch():
+    """Every four-step transform of a batched key switch takes the
+    one-kernel path: building its programs records
+    ``kernels.fourstep.block`` spans (both directions, with the block
+    geometry) and no banks pass (``kernels.ntt_banks.block``)."""
+    from repro import obs
+    n, B = 1 << 8, 3
+    full = tuple(gen_ntt_primes(3, n, bits=30))
+    d2, evk_b, evk_a = _random_ks_inputs(full, n, B=B)
+    t = FB.build_scalar_pack(list(full))
+    fsp = FB.build_fourstep_pack(list(full), n)
+    n1, n2 = fourstep_split(n)
+    jax.clear_caches()
+    obs.enable(); obs.clear()
+    try:
+        FB.batched_keyswitch(jnp.asarray(d2), jnp.asarray(evk_b),
+                             jnp.asarray(evk_a), t, fsp=fsp, use_pallas=True)
+    finally:
+        obs.disable()
+    names = [e["name"] for e in obs.events()]
+    blocks = [e["args"] for e in obs.events()
+              if e["name"] == "kernels.fourstep.block"]
+    assert "kernels.ntt_banks.block" not in names
+    assert {b["direction"] for b in blocks} == {"fwd", "inv"}
+    for b in blocks:
+        assert (b["n1"], b["n2"]) == (n1, n2)
+        assert b["polys"] == fourstep_block(b["b"], n)
 
 
 # ------------------------------------------------------------ properties

@@ -148,11 +148,11 @@ def test_banks_batch_leading_matches_prime_major():
     from repro.fhe import batched as FB
     t = FB.build_table_pack(list(_GAL_PRIMES), _GAL_N)
     x = jnp.asarray(np.swapaxes(_gal_x((5,)), 0, 1))           # (b, k, n)
-    qs = t["qs"][:_GAL_K]
-    w, wp = t["psi"][:_GAL_K], t["psip"][:_GAL_K]
+    fp = FB.build_fourstep_pack(list(_GAL_PRIMES), _GAL_N)
     fns = [lambda v, kw: ops.ntt_banks(v, t, **kw),
            lambda v, kw: ops.intt_banks(v, t, **kw),
-           lambda v, kw: ops.twiddle_mul_banks(v, w, wp, qs, **kw)]
+           lambda v, kw: ops.ntt_fourstep_banks(v, fp, **kw),
+           lambda v, kw: ops.intt_fourstep_banks(v, fp, **kw)]
     for up in (False, True):
         for fn in fns:
             got = np.asarray(fn(x, dict(batch_leading=True, use_pallas=up)))

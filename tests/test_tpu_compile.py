@@ -11,7 +11,10 @@ is needed, and nothing runs.  The widths:
     (n = 128 = ``fourstep_split(2^14)[0]``), 8 RNS limbs, batch 128*B,
     and at the key switch's digit-extended passes (9 primes, 32,768 and
     8,192 rows), whose blocks span many vregs;
-  * ``twiddle_mul_banks`` on the 2^14 ring, 8 limbs;
+  * the one-kernel four-step transform (forward and inverse) at the
+    cells' shapes (2^14 over 9 primes, 2^15 over 28 and 21), and the
+    2^14 batched key switch around it, whose XLA ops outside the
+    kernels must stay within a byte budget;
   * ``dyadic_inner_banks``: 8 digits over 9 primes (8 limbs + the
     special prime), shared-key and per-batch-key layouts;
   * the Galois automorphism at 2^14 (one XLA gather, no kernel);
@@ -121,12 +124,94 @@ def test_ntt_banks_pass_block_geometry(one_chip, shape, inverse):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_twiddle_mul_banks_2_14(one_chip):
-    s, k, n = one_chip, LIMBS, N_RING
-    c = _compile(ntt_kernel.twiddle_mul_banks_pallas, _sds(s, (k, B, n)),
-                 _sds(s, (k,)), _sds(s, (k, n)), _sds(s, (k, n)),
-                 tile=TILE, lazy=True, interpret=False)
+# (ring, primes, polynomials per prime) of the one-kernel four-step
+# transform in the cells: the 2^14 key switch's digit extensions (9
+# primes x 8 digits x B = 32) and a serve dispatch (B = 8); the 2^15
+# key switch's mod-up extension (28 primes x 3 digits x B) and its
+# mod-down over the 21 limbs.
+FOURSTEP_GEOMETRY = {"keyswitch_2_14": (N_RING, LIMBS + 1, LIMBS * B),
+                     "serve_2_14": (N_RING, LIMBS + 1, LIMBS * 8),
+                     "modup_2_15": (1 << 15, 28, 3 * B),
+                     "moddown_2_15": (1 << 15, 21, B)}
+
+
+def _fourstep_args(s, n, k, b, inverse):
+    n1, n2 = fourstep_split(n)
+    t = lambda *shape: _sds(s, (k,) + shape)
+    rows_a, rows_c = t(n1 + 16, n2), t(n2 + 16, n1)
+    w = ntt_kernel.ref_lanes(n1 if inverse else n2)
+    x = _sds(s, (k, b, n // w, w))
+    if inverse:
+        return (x, t(), t(), t(), t(n1, n2), t(n1, n2), rows_c, rows_c,
+                t(n1, n2), t(n1, n2), rows_a, rows_a)
+    return (x, t(), t(n1, n2), t(n1, n2), rows_a, rows_a, t(n1, n2),
+            t(n1, n2), rows_c, rows_c)
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+@pytest.mark.parametrize("shape", list(FOURSTEP_GEOMETRY))
+def test_fourstep_kernel_cell_shapes(one_chip, shape, inverse):
+    """The one-kernel four-step transform at the cells' shapes: its
+    blocks of whole polynomials and its tables fit the chip's scoped
+    VMEM (the compile refuses a kernel that does not)."""
+    n, k, b = FOURSTEP_GEOMETRY[shape]
+    fn = (ntt_kernel.fourstep_inv_banks_pallas if inverse
+          else ntt_kernel.fourstep_fwd_banks_pallas)
+    c = _compile(fn, *_fourstep_args(one_chip, n, k, b, inverse),
+                 negacyclic=True, lazy=True, interpret=False)
     assert "tpu_custom_call" in c.as_text()
+
+
+def _entry_writes(hlo: str, min_elems: int = 1 << 20) -> list[tuple]:
+    """(name, opcode, bytes) of the ENTRY ops of an optimised HLO module
+    that are not custom calls and whose output holds an array of at
+    least ``min_elems`` elements; an async op counts once (its -done)."""
+    import re
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    width = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2}
+    out = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                  "constant", "custom-call") or op.endswith("-start"):
+            continue
+        arrays = [(dt, int(np.prod([int(d) for d in dims.split(",") if d])))
+                  for dt, dims in re.findall(r"([a-z]+\d*)\[([\d,]*)\]",
+                                             shape)]
+        if arrays and max(e for _, e in arrays) >= min_elems:
+            out.append((name, op, sum(e * width.get(dt, 4)
+                                      for dt, e in arrays)))
+    return out
+
+
+def test_keyswitch_2_14_layout_traffic(one_chip, monkeypatch):
+    """The batched key switch at 2^14 (8 limbs + 1 special prime,
+    B = 32) keeps its four-step transforms in their kernels: the XLA ops
+    outside the kernels (copies, transposing fusions, re-tiling
+    reshapes, the mod-up and the key slices) write at most 0.75 GB a
+    call, where the multi-pass pipeline's layout ops wrote ~3 GB."""
+    from repro.core.params import gen_ntt_primes
+    from repro.fhe import batched as FB
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    full = gen_ntt_primes(LIMBS + 1, N_RING, bits=30)
+    sds = lambda a: _sds(one_chip, np.shape(a), np.asarray(a).dtype)
+    t = jax.tree_util.tree_map(sds, FB.build_scalar_pack(list(full)))
+    fsp = jax.tree_util.tree_map(sds, FB.build_fourstep_pack(list(full),
+                                                             N_RING))
+    key = _sds(one_chip, (LIMBS, LIMBS + 1, N_RING))
+    fn = jax.jit(lambda d, eb, ea, t, f: FB.batched_keyswitch(
+        d, eb, ea, t, fsp=f, tile=TILE))
+    hlo = fn.lower(_sds(one_chip, (LIMBS, B, N_RING)), key, key, t,
+                   fsp).compile().as_text()
+    assert "fourstep_fwd_banks_pallas" in hlo
+    assert "fourstep_inv_banks_pallas" in hlo
+    assert "twiddle_mul" not in hlo and "ntt_fwd_banks" not in hlo
+    written = sum(nbytes for _, _, nbytes in _entry_writes(hlo))
+    assert written <= 0.75e9, _entry_writes(hlo)
 
 
 @pytest.mark.parametrize("per_batch", [False, True],
